@@ -57,7 +57,7 @@ def _child(out_path: str, budget: float) -> None:
 
     from rafiki_tpu.utils.platform import apply_platform_env
 
-    apply_platform_env()  # parent sets RAFIKI_JAX_PLATFORM=cpu on fallback
+    apply_platform_env()  # parent sets JAX_PLATFORMS=cpu on fallback
 
     import jax
     import jax.numpy as jnp

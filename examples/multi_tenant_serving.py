@@ -18,7 +18,7 @@ with ``client.create_inference_job(job_id, budget={"MULTI_ADAPTER": 1})``,
 route with ``client.predict(url, qs, sampling={"adapter_id": i})``, and
 stream with ``client.predict_stream(url, qs)``.
 
-    RAFIKI_JAX_PLATFORM=cpu python examples/multi_tenant_serving.py
+    JAX_PLATFORMS=cpu python examples/multi_tenant_serving.py
 """
 
 from __future__ import annotations

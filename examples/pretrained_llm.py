@@ -19,7 +19,7 @@ exporting a freshly initialized base with
 ``export_llama_safetensors`` — byte-for-byte the layout conversion a
 real HF download takes.
 
-    RAFIKI_JAX_PLATFORM=cpu python examples/pretrained_llm.py
+    JAX_PLATFORMS=cpu python examples/pretrained_llm.py
 """
 
 from __future__ import annotations

@@ -4,10 +4,10 @@ Mirrors the reference's examples/ quickstart scripts (SURVEY.md §4: the
 quickstart doubles as the integration flow). Run a stack first:
 
     rafiki-tpu stack start --workdir ./rafiki_stack
-    RAFIKI_JAX_PLATFORM=cpu python examples/quickstart.py \
+    JAX_PLATFORMS=cpu python examples/quickstart.py \
         --admin http://127.0.0.1:3000
 
-On a CPU-only host keep RAFIKI_JAX_PLATFORM=cpu; on a TPU VM drop it.
+On a CPU-only host keep JAX_PLATFORMS=cpu; on a TPU VM drop it.
 """
 
 from __future__ import annotations

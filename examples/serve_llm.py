@@ -5,7 +5,7 @@ overlapping generation requests — the inference worker serves them
 through the slot-based continuous-batching decode loop.
 
     rafiki-tpu stack start --workdir ./rafiki_stack
-    RAFIKI_JAX_PLATFORM=cpu python examples/serve_llm.py \
+    JAX_PLATFORMS=cpu python examples/serve_llm.py \
         --admin http://127.0.0.1:3000
 """
 

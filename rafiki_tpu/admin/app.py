@@ -192,6 +192,11 @@ class AdminApp:
         return 200, {"ok": True,
                      "n_services": len(svc.services),
                      "free_slots": svc.allocator.free_count(),
+                     # what the device probe saw at boot: the platform
+                     # every slot's worker is pinned to, and how many
+                     # slots the devices were cut into
+                     "platform": svc.platform,
+                     "n_slots": svc.allocator.n_slots,
                      **svc.respawn_stats(),
                      "degraded_jobs": len(degraded),
                      "degraded": degraded,

@@ -841,8 +841,7 @@ class ServicesManager:
         else:
             # control-plane children (advisor/predictor) must never claim
             # accelerator chips — pin them to host CPU
-            env.update({"JAX_PLATFORMS": "cpu",
-                        "RAFIKI_JAX_PLATFORM": "cpu"})
+            env["JAX_PLATFORMS"] = "cpu"
         log = open(self.workdir / f"{tag}.log", "ab")
         proc = subprocess.Popen(
             [sys.executable, "-m", module, "--config", str(cfg_path)],

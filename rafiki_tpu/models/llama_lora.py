@@ -792,10 +792,8 @@ def _chunked_ce_sum(hidden: jnp.ndarray, targets: jnp.ndarray,
     targets globally before partitioning).
 
     ``unroll`` replaces the ``lax.scan`` with a Python loop over the
-    (static) chunk count: required when this runs INSIDE a ``shard_map``
-    — transposing a scan through shard_map mis-specs the scalar carry
-    on older jax (0.4.x), and the sp variant differentiates through
-    exactly that composition. Same math, unrolled HLO."""
+    (static) chunk count, which is how the sp variant runs it INSIDE
+    its ``shard_map``. Same math, unrolled HLO."""
     b, length, d = hidden.shape
     chunk = max(1, min(int(chunk), length))
     pad = (-length) % chunk
@@ -2858,7 +2856,7 @@ if __name__ == "__main__":  # reference-style self-test block
 
     from rafiki_tpu.utils.platform import apply_platform_env
 
-    apply_platform_env()  # honor RAFIKI_JAX_PLATFORM=cpu for dev runs
+    apply_platform_env()  # the shared compile cache
 
     from rafiki_tpu.data import generate_text_classification_dataset
     from rafiki_tpu.model import test_model_class

@@ -95,7 +95,7 @@ if __name__ == "__main__":  # reference-style self-test block
 
     from rafiki_tpu.utils.platform import apply_platform_env
 
-    apply_platform_env()  # honor RAFIKI_JAX_PLATFORM=cpu for dev runs
+    apply_platform_env()  # the shared compile cache
 
     from rafiki_tpu.data import generate_image_classification_dataset
     from rafiki_tpu.model import test_model_class

@@ -23,15 +23,10 @@ def use_xla_fallback(interpret: Optional[bool]) -> bool:
 
 
 def shard_map_checked(f, mesh, in_specs, out_specs):
-    """``shard_map`` with the replication/varying checker ON — for pure
-    XLA bodies (no ``pallas_call``). Besides the safety net, the checker
-    is load-bearing on older jax: transposing a ``psum`` (grad through a
-    replicated ``P()`` output) mis-specs under ``check_rep=False``."""
-    try:
-        smap = jax.shard_map
-    except AttributeError:  # pre-promotion jax: experimental namespace
-        from jax.experimental.shard_map import shard_map as smap
-    return smap(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+    """``jax.shard_map`` with the varying-manual-axes checker ON — for
+    pure XLA bodies (no ``pallas_call``)."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs)
 
 
 def shard_map_kernels(f, mesh, in_specs, out_specs):
@@ -40,18 +35,9 @@ def shard_map_kernels(f, mesh, in_specs, out_specs):
     outputs (jax requires an explicit ``vma`` on every out ShapeDtypeStruct
     it cannot infer), so kernel-bearing maps disable it; correctness of
     the replication/varying structure is covered by the oracle-equivalence
-    tests instead. Falls back to the pre-vma ``check_rep`` keyword on
-    older jax."""
-    try:
-        smap = jax.shard_map
-    except AttributeError:  # pre-promotion jax: experimental namespace
-        from jax.experimental.shard_map import shard_map as smap
-    try:
-        return smap(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_vma=False)
-    except TypeError:  # older jax spells it check_rep
-        return smap(f, mesh=mesh, in_specs=in_specs,
-                    out_specs=out_specs, check_rep=False)
+    tests instead."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def gqa_repeat_factor(n_heads: int, n_kv_heads: int) -> int:

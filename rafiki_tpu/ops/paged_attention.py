@@ -32,8 +32,9 @@ generated token. This kernel consumes the pool **directly**:
   (``rep = n_heads / n_kv_heads`` query rows share one K/V page
   block), so the ``jnp.repeat`` the gather path pays per step never
   happens. ``block_h`` tiles kv heads per program exactly like
-  ``flash_attention``'s head tiling (env default via
-  ``_env_block_h``, same divisibility fallback).
+  ``flash_attention``'s head tiling — but defaulting to the WHOLE kv
+  axis, the one tile Mosaic accepts at every head count (see
+  ``_resolve_block_h``).
 
 The single-token step above was PR 10; ``paged_window_attention``
 generalizes it to an (s >= 1) **query window** so chunked prefill and
@@ -71,8 +72,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from rafiki_tpu.ops.attention import NEG_INF, _env_block_h, \
-    _resolve_interpret
+from rafiki_tpu.ops.attention import NEG_INF, _resolve_interpret
 from rafiki_tpu.ops.common import gqa_repeat_factor
 
 
@@ -123,23 +123,24 @@ def _partitioner_shield(call, *operands):
     an opaque custom call and partitions as it always has.
     """
     import numpy as np
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import Mesh, NamedSharding, PartitionSpec
+
+    from rafiki_tpu.ops.common import shard_map_kernels
 
     mesh = Mesh(np.asarray(jax.devices()), ("_pk_replica",))
     spec = PartitionSpec()
     # materialize TRUE replicas first: an operand may reach this point
     # as a pending partial-sum (the partitioner splitting an upstream
-    # contraction), and ``check_rep=False`` would hand each device its
+    # contraction), and the unchecked map would hand each device its
     # partial as if it were the whole value. The explicit constraint
     # forces the all-reduce BEFORE the manual region.
     replicated = NamedSharding(mesh, spec)
     operands = tuple(
         jax.lax.with_sharding_constraint(o, replicated)
         for o in operands)
-    return shard_map(
+    return shard_map_kernels(
         call, mesh=mesh, in_specs=(spec,) * len(operands),
-        out_specs=spec, check_rep=False)(*operands)
+        out_specs=spec)(*operands)
 
 
 def kv_cache_write(cache, idx0, idx1, values,
@@ -174,6 +175,55 @@ def kv_cache_write(cache, idx0, idx1, values,
     return write(cache, idx0, idx1, values)
 
 
+def _resolve_block_h(block_h: Optional[int], n_kv: int) -> int:
+    """The kv-head tile of both paged kernels. The pool keeps heads in
+    its second-to-last dim, and Mosaic takes a block there only when it
+    is the whole axis or a multiple of 8 ("the last two dimensions of
+    your block shape [must be] divisible by 8 and 128 … or equal to the
+    respective dimensions of the overall array"). So the default is the
+    whole kv axis — legal at every head count — and never the
+    ``flash_attention`` fleet default, whose per-head tile (1) is
+    exactly what the compiler refuses here. An explicit ``block_h``
+    must divide the kv head count; whether it is a legal tile is the
+    compiler's call, made loudly at lowering."""
+    if block_h is None:
+        return n_kv
+    if block_h < 1 or n_kv % block_h:
+        raise ValueError(f"block_h={block_h} must be >= 1 and divide "
+                         f"the kv head count ({n_kv})")
+    return block_h
+
+
+def _tile_first_head(block_h: int, s_ref):
+    """First kv head of this program's tile, as a column of the scale
+    block ``s_ref`` (``None`` for an unquantized pool). Scale blocks
+    always span the WHOLE kv axis: heads are their LAST dim, where
+    Mosaic takes only the full axis or a multiple of 128 as a block.
+    Static 0 when the tile is the whole axis (the default); off the
+    head-tile program id otherwise — read here, at the kernel's top
+    level, because the interpreter has no ``program_id`` inside a
+    ``pl.when`` body."""
+    from jax.experimental import pallas as pl
+
+    if s_ref is None or block_h == s_ref.shape[-1]:
+        return 0
+    return pl.program_id(1) * block_h
+
+
+def _head_scale(s_ref, head):
+    """The (page_size, 1) dequant scales of kv head ``head``. A static
+    head is a static column. A traced one (a tile narrower than the kv
+    axis) is picked with a lane mask — Mosaic indexes lanes only
+    statically ("cannot statically prove that index in dimension 2 is
+    a multiple of 128") — which is exact: every other term of the sum
+    is 0."""
+    if isinstance(head, int):
+        return s_ref[0, :, head][:, None]
+    scales = s_ref[0]  # (page_size, n_kv)
+    lane = jax.lax.broadcasted_iota(jnp.int32, scales.shape, 1)
+    return jnp.sum(jnp.where(lane == head, scales, 0.0), -1, keepdims=True)
+
+
 def _paged_decode_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
                          sm_scale: float, page_size: int, block_h: int,
                          n_tables: int, quantized: bool):
@@ -185,6 +235,7 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
         ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     bi = pl.program_id(0)
+    h0 = _tile_first_head(block_h, ks_ref)
     pg = pl.program_id(2)
     t = t_ref[bi]  # this slot's query position (keys k_pos <= t live)
     n_live = t // page_size + 1
@@ -207,8 +258,8 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
             k = k_ref[0, :, hh, :].astype(jnp.float32)  # (page_size, dh)
             v = v_ref[0, :, hh, :].astype(jnp.float32)
             if quantized:  # dequant in registers, fused into the math
-                k = k * ks_ref[0, :, hh][:, None]
-                v = v * vs_ref[0, :, hh][:, None]
+                k = k * _head_scale(ks_ref, h0 + hh)
+                v = v * _head_scale(vs_ref, h0 + hh)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)  # (rep, page_size)
@@ -254,8 +305,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
     Returns (b, n_heads, dh) in ``q``'s dtype. GQA queries are grouped
     per kv head internally (``jnp.repeat`` convention: q head h ↔ kv
     head ``h // rep``). ``block_h`` tiles kv heads per program
-    (default: the ``RAFIKI_ATTN_BLOCK_H`` fleet default through the
-    same divisibility fallback as ``flash_attention``).
+    (default: the whole kv axis — see ``_resolve_block_h``).
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
@@ -266,11 +316,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         raise ValueError(f"head_dim mismatch: q has {dh}, pool {dh_k}")
     rep = gqa_repeat_factor(n_heads, n_kv)
     n_tables = page_tables.shape[1]
-    if block_h is None:
-        block_h = _env_block_h(n_kv)
-    if block_h < 1 or n_kv % block_h:
-        raise ValueError(f"block_h={block_h} must be >= 1 and divide "
-                         f"the kv head count ({n_kv})")
+    block_h = _resolve_block_h(block_h, n_kv)
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be passed together")
@@ -292,8 +338,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         return (jnp.where(live, tab_ref[bi, pg], 0), 0, kh, 0)
 
     def sc_map(bi, kh, pg, t_ref, tab_ref):
+        # whole kv axis per block: see ``_tile_first_head``
         live = pg <= t_ref[bi] // page_size
-        return (jnp.where(live, tab_ref[bi, pg], 0), 0, kh)
+        return (jnp.where(live, tab_ref[bi, pg], 0), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, block_h, rep, dh), q_map),
@@ -302,8 +349,8 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
     ]
     operands = [qh, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, block_h), sc_map),
-                     pl.BlockSpec((1, page_size, block_h), sc_map)]
+        in_specs += [pl.BlockSpec((1, page_size, n_kv), sc_map),
+                     pl.BlockSpec((1, page_size, n_kv), sc_map)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
@@ -334,9 +381,9 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
     return out.reshape(b, n_heads, dh)
 
 
-def _paged_window_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref,
+def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
                          *rest, sm_scale: float, page_size: int,
-                         block_h: int, block_q: int, rep: int,
+                         block_h: int, block_q: int,
                          n_tables: int, quantized: bool):
     from jax.experimental import pallas as pl
 
@@ -346,17 +393,17 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref,
         ks_ref = vs_ref = None
         o_ref, m_scr, l_scr, acc_scr = rest
     bi = pl.program_id(0)
+    h0 = _tile_first_head(block_h, ks_ref)
     qt = pl.program_id(2)
     pg = pl.program_id(3)
-    # this query tile's absolute positions, straight off the scalar
-    # prefetch (SMEM) — the same array the index maps walk, so masks
-    # and fetches can never disagree
-    tile_t = t_ref[bi, pl.ds(qt * block_q, block_q)]  # (block_q,)
     # positions are NONDECREASING along the window (the engine repeats
     # the last real entry into idle/overhang rows), so this tile's last
     # row bounds its live pages — the per-tile twin of the step
-    # kernel's n_live
-    n_live = tile_t[block_q - 1] // page_size + 1
+    # kernel's n_live. A SCALAR read off the prefetch ref, the same one
+    # the index maps make: Mosaic loads nothing wider from SMEM ("Can
+    # only load scalars from SMEM"), so the tile's per-row positions
+    # arrive as the blocked VMEM operand ``trow_ref`` instead
+    n_live = t_ref[bi, qt * block_q + block_q - 1] // page_size + 1
 
     @pl.when(pg == 0)
     def _init():  # fresh (batch, head-tile, query-tile) row
@@ -372,16 +419,14 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref,
         # per-ROW causal horizon: query row r is window token r // rep
         # and sees keys k_pos <= its own absolute position — inside the
         # window, earlier tokens do NOT see later tokens' keys
-        t_rows = jnp.repeat(tile_t, rep)[:, None]  # (bq*rep, 1)
-        mask = k_pos <= t_rows  # (block_q*rep, page_size)
+        mask = k_pos <= trow_ref[0, 0]  # (block_q*rep, page_size)
         for hh in range(block_h):  # static unroll over the head tile
-            q = (q_ref[0, hh].reshape(block_q * rep, -1)
-                 .astype(jnp.float32) * sm_scale)  # (bq*rep, dh)
+            q = q_ref[0, hh, 0].astype(jnp.float32) * sm_scale  # (bq*rep, dh)
             k = k_ref[0, :, hh, :].astype(jnp.float32)  # (page_size, dh)
             v = v_ref[0, :, hh, :].astype(jnp.float32)
             if quantized:  # dequant in registers, fused into the math
-                k = k * ks_ref[0, :, hh][:, None]
-                v = v * vs_ref[0, :, hh][:, None]
+                k = k * _head_scale(ks_ref, h0 + hh)
+                v = v * _head_scale(vs_ref, h0 + hh)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32)  # (bq*rep, psz)
@@ -399,14 +444,12 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref,
 
     @pl.when(pg == n_tables - 1)
     def _finish():  # k_pos 0 <= any position, so l > 0 on every row
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l_scr[...], 1e-30)).reshape(
-                        block_h, block_q, rep, -1).astype(o_ref.dtype)
+        o_ref[0, :, 0] = (acc_scr[...] / jnp.maximum(
+            l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def _default_block_q(s: int) -> int:
-    """Largest window-tile width <= 16 that divides the window — the
-    same divisibility-fallback spirit as ``_env_block_h``."""
+    """Largest window-tile width <= 16 that divides the window."""
     for d in range(min(s, 16), 0, -1):
         if s % d == 0:
             return d
@@ -455,11 +498,7 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
         raise ValueError(f"head_dim mismatch: q has {dh}, pool {dh_k}")
     rep = gqa_repeat_factor(n_heads, n_kv)
     n_tables = page_tables.shape[1]
-    if block_h is None:
-        block_h = _env_block_h(n_kv)
-    if block_h < 1 or n_kv % block_h:
-        raise ValueError(f"block_h={block_h} must be >= 1 and divide "
-                         f"the kv head count ({n_kv})")
+    block_h = _resolve_block_h(block_h, n_kv)
     if block_q is None:
         block_q = _default_block_q(s)
     if block_q < 1 or s % block_q:
@@ -475,12 +514,24 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
         raise ValueError(f"positions must be (b, s)=({b}, {s}), got "
                          f"{t.shape}")
     # group GQA query rows per kv head, window-major inside the head
-    # tile: (b, n_kv, s, rep, dh) — rep rows of one token stay adjacent
-    qw = q.reshape(b, s, n_kv, rep, dh).transpose(0, 2, 1, 3, 4)
+    # tile, one (block_q * rep, dh) slab per query tile: (b, n_kv, n_qt,
+    # block_q * rep, dh) — rep rows of one token stay adjacent. Tiling
+    # the window HERE keeps every block's last two dims equal to the
+    # array's (legal at any block_q and rep) and leaves the kernel no
+    # sublane-merging reshape to do
+    n_qt, rows = s // block_q, block_q * rep
+    qw = (q.reshape(b, s, n_kv, rep, dh).transpose(0, 2, 1, 3, 4)
+          .reshape(b, n_kv, n_qt, rows, dh))
+    # per-ROW causal horizon: query row r of a tile is window token
+    # r // rep and sees keys k_pos <= its own absolute position
+    t_rows = jnp.repeat(t, rep, axis=1).reshape(b, n_qt, rows, 1)
     tabs = jnp.asarray(page_tables, jnp.int32)
 
     def q_map(bi, kh, qt, pg, t_ref, tab_ref):
         return (bi, kh, qt, 0, 0)
+
+    def trow_map(bi, kh, qt, pg, t_ref, tab_ref):
+        return (bi, qt, 0, 0)
 
     def kv_map(bi, kh, qt, pg, t_ref, tab_ref):
         # the block-table walk, bounded per QUERY TILE: nondecreasing
@@ -492,45 +543,47 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
 
     def sc_map(bi, kh, qt, pg, t_ref, tab_ref):
         live = pg <= t_ref[bi, qt * block_q + block_q - 1] // page_size
-        return (jnp.where(live, tab_ref[bi, pg], 0), 0, kh)
+        return (jnp.where(live, tab_ref[bi, pg], 0), 0, 0)
 
     in_specs = [
-        pl.BlockSpec((1, block_h, block_q, rep, dh), q_map),
+        pl.BlockSpec((1, block_h, 1, rows, dh), q_map),
+        pl.BlockSpec((1, 1, rows, 1), trow_map),
         pl.BlockSpec((1, page_size, block_h, dh), kv_map),
         pl.BlockSpec((1, page_size, block_h, dh), kv_map),
     ]
-    operands = [qw, k_pool, v_pool]
+    operands = [qw, t_rows, k_pool, v_pool]
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, block_h), sc_map),
-                     pl.BlockSpec((1, page_size, block_h), sc_map)]
+        in_specs += [pl.BlockSpec((1, page_size, n_kv), sc_map),
+                     pl.BlockSpec((1, page_size, n_kv), sc_map)]
         operands += [k_scale, v_scale]
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_kv // block_h, s // block_q, n_tables),
+        grid=(b, n_kv // block_h, n_qt, n_tables),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_h, block_q, rep, dh), q_map),
+        out_specs=pl.BlockSpec((1, block_h, 1, rows, dh), q_map),
         scratch_shapes=[
-            pltpu.VMEM((block_h, block_q * rep, 1), jnp.float32),
-            pltpu.VMEM((block_h, block_q * rep, 1), jnp.float32),
-            pltpu.VMEM((block_h, block_q * rep, dh), jnp.float32),
+            pltpu.VMEM((block_h, rows, 1), jnp.float32),
+            pltpu.VMEM((block_h, rows, 1), jnp.float32),
+            pltpu.VMEM((block_h, rows, dh), jnp.float32),
         ],
     )
     kernel = functools.partial(
         _paged_window_kernel, sm_scale=float(sm_scale),
-        page_size=page_size, block_h=block_h, block_q=block_q, rep=rep,
+        page_size=page_size, block_h=block_h, block_q=block_q,
         n_tables=n_tables, quantized=quantized)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, s, rep, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_kv, n_qt, rows, dh), q.dtype),
         interpret=interpret,
     )
     if interpret and jax.device_count() > 1:
         out = _partitioner_shield(call, t, tabs, *operands)
     else:
         out = call(t, tabs, *operands)
-    return out.transpose(0, 2, 1, 3, 4).reshape(b, s, n_heads, dh)
+    return (out.reshape(b, n_kv, s, rep, dh).transpose(0, 2, 1, 3, 4)
+            .reshape(b, s, n_heads, dh))
 
 
 def _paged_attention_reference(q, k_pool, v_pool, page_tables, positions,

@@ -768,10 +768,11 @@ def main(argv: Optional[list] = None) -> int:
     import json
 
     from ..parallel.multihost import initialize_from_env
-    from ..utils.platform import apply_platform_env
+    from ..utils.platform import apply_platform_env, log_devices
 
     apply_platform_env()  # before any jax backend initializes
     initialize_from_env()  # multi-host rendezvous (no-op if unconfigured)
+    log_devices("train worker")
 
     from ..advisor.service import AdvisorClient
     from ..model.base import load_model_class

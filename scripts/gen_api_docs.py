@@ -3,7 +3,7 @@
 Walks the public modules, pulls classes/functions with their signatures
 and first docstring paragraph. Regenerate after API changes:
 
-    RAFIKI_JAX_PLATFORM=cpu python scripts/gen_api_docs.py
+    JAX_PLATFORMS=cpu python scripts/gen_api_docs.py
 """
 
 from __future__ import annotations
@@ -14,11 +14,11 @@ import sys
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-os.environ.setdefault("RAFIKI_JAX_PLATFORM", "cpu")
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
 
 from rafiki_tpu.utils.platform import apply_platform_env
 
-apply_platform_env()  # shared helper: platform pin + compile cache
+apply_platform_env()  # shared helper: the compile cache
 
 MODULES = [
     ("rafiki_tpu.client.client", "Client SDK"),

@@ -312,8 +312,12 @@ def test_stream_failover_token_exact_on_worker_kill(trained, lm_store):
     w0, t0_ = _boot_lm_worker(trained, lm_store, hub, "w0",
                               steps_per_sync=1, chaos=chaos)
     w1, t1_ = _boot_lm_worker(trained, lm_store, hub, "w1")
+    # the silence window also bounds how long the SURVIVOR may take to
+    # its first delta — a first compile of its resume path, on a host
+    # shared with five other xdist workers — so it is not cut to the
+    # bone: at 1 s a loaded run tripped w1's breaker too
     pred = Predictor(hub, ["w0", "w1"], gather_timeout=120.0,
-                     stream_silence_timeout_s=1.0,
+                     stream_silence_timeout_s=5.0,
                      breaker_fail_threshold=1)
     try:
         events, acc = _collect_stream(

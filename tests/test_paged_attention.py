@@ -10,6 +10,8 @@ engine-level kernel-vs-gather bit-exactness in ``test_paged_kv.py``
 plausible rather than lucky.
 """
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -393,3 +395,37 @@ def test_resolve_paged_window_kernel_rule(monkeypatch):
         assert resolve_paged_window_kernel(True) is False
     monkeypatch.setenv("RAFIKI_PAGED_KERNEL_WINDOWS", "1")
     assert resolve_paged_window_kernel(True) is True
+
+
+# ---------------------------------------------------------------------
+# the head tiles the CHIP runs: Mosaic takes a kv-head tile only when
+# it is the whole kv axis (the default) or a multiple of 8 — never the
+# per-head tile the first tests here were written against
+# (tests/test_tpu_compile.py asks the compiler itself)
+# ---------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["f32", "int8"])
+@pytest.mark.parametrize("kernel", ["step", "window"])
+def test_head_tiles_agree_at_serving_shape(kernel, int8):
+    """GQA 4:1 over 16 kv heads at page 16 (the served page size): the
+    default tile (whole kv axis), the smallest legal narrower tile (8)
+    and the per-head tile give BIT-identical outputs — heads never mix,
+    and the narrow-tile int8 path picks its scale columns with an exact
+    lane mask — and all match the page-gather oracle. The window case
+    runs s=6 over block_q=3, so the per-row position operand spans two
+    query tiles."""
+    n_kv, geom = 16, dict(n_kv=16, rep=4, dh=8, ps=16, n_tables=3,
+                          n_pages=16, int8=int8, seed=3)
+    if kernel == "step":
+        q, kp, vp, tabs, t, scales = _setup([5, 15, 16, 40], **geom)
+        run = _both
+    else:
+        q, kp, vp, tabs, t, scales = _wsetup(
+            [[11, 12, 13, 14, 15, 16], [30, 31, 32, 33, 33, 33]], **geom)
+        run = functools.partial(_wboth, block_q=3)
+    out, ref = run(q, kp, vp, tabs, t, scales=scales)
+    np.testing.assert_allclose(out, ref, atol=1e-5, rtol=1e-4)
+    for block_h in (n_kv, 8, 1):
+        tiled, _ = run(q, kp, vp, tabs, t, scales=scales, block_h=block_h)
+        assert np.array_equal(out, tiled), f"block_h={block_h}"

@@ -103,6 +103,12 @@ def test_submesh_env_vars():
     assert "device_count=2" in env["XLA_FLAGS"]
     tpu_env = submesh_env_vars("tpu", sm)
     assert tpu_env["TPU_VISIBLE_CHIPS"] == "0,1"
+    # a TPU slot pins the platform: a child that cannot open its chip
+    # must die, not train on the CPU
+    assert tpu_env["JAX_PLATFORMS"] == "tpu"
+    # the TPU library's lock stays armed: it is what keeps two workers
+    # off one chip, and disjoint chips do not need it lifted
+    assert "ALLOW_MULTIPLE_LIBTPU_LOAD" not in tpu_env
 
 
 def test_data_parallel_train_step_on_mesh():

@@ -1,0 +1,174 @@
+"""The main path's kernels compile for the v5e — no chip attached.
+
+The TPU compiler is installed beside the CPU backend and compiles for a
+chip that is *described*, not present. The interpret-mode suites prove
+the kernels' math; only Mosaic can say whether it takes their block
+shapes and memory spaces, and it refused both paged kernels for as long
+as nothing here asked it. Each test lowers one kernel at a real width
+through its DEFAULT arguments (what ``_DecoderAttention`` and the ViT
+template call) with ``interpret=False`` and checks the compiled program
+carries the kernel.
+
+The topology is described inside a module-scoped fixture and nowhere
+else: only one process may load the TPU library, xdist imports this
+file in every worker, and only the worker that runs these tests may
+make the call. Everything compiles in the test's own process, and all
+of it lives in this one file, for the same reason.
+"""
+
+import os
+
+import pytest
+
+import jax
+import jax.numpy as jnp
+from jax.sharding import SingleDeviceSharding
+
+from rafiki_tpu.ops.attention import flash_attention
+from rafiki_tpu.ops.paged_attention import (paged_decode_attention,
+                                            paged_window_attention)
+from rafiki_tpu.ops.patch_embed import patch_embed
+
+PAGE = 16
+#: (q heads, kv heads, head dim): Llama-3-8B's attention, an MHA model
+#: at head size 128, and the top of ``LlamaLoRA``'s knob space
+HEADS = [(32, 8, 128), (16, 16, 128), (8, 2, 64)]
+
+
+@pytest.fixture(scope="module")
+def topo():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a described-device executable can be written to the persistent
+    # cache but never read back without a chip: keep the cache off
+    # around these compiles, whatever the session had
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    compilation_cache.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def spec(topo):
+    one_chip = SingleDeviceSharding(topo.devices[0])
+
+    def shape(dims, dtype):
+        return jax.ShapeDtypeStruct(dims, dtype, sharding=one_chip)
+
+    return shape
+
+
+def _compiled_text(fn, *shapes) -> str:
+    return jax.jit(fn).lower(*shapes).compile().as_text()
+
+
+def _pool(spec, n_kv, dh, int8):
+    """Pool + scale shapes (scales empty for a bf16 pool)."""
+    n_pages = 64
+    kv = spec((n_pages, PAGE, n_kv, dh),
+              jnp.int8 if int8 else jnp.bfloat16)
+    scales = ((spec((n_pages, PAGE, n_kv), jnp.float32),) * 2
+              if int8 else ())
+    return kv, scales
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("n_heads,n_kv,dh", HEADS)
+def test_paged_decode_kernel_compiles(spec, n_heads, n_kv, dh, int8):
+    b, n_tables = 4, 8
+    kv, scales = _pool(spec, n_kv, dh, int8)
+
+    def step(q, k, v, tabs, t, *sc):
+        return paged_decode_attention(
+            q, k, v, tabs, t, sm_scale=dh ** -0.5,
+            k_scale=sc[0] if sc else None,
+            v_scale=sc[1] if sc else None, interpret=False)
+
+    text = _compiled_text(
+        step, spec((b, n_heads, dh), jnp.bfloat16), kv, kv,
+        spec((b, n_tables), jnp.int32), spec((b,), jnp.int32), *scales)
+    assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+@pytest.mark.parametrize("n_heads,n_kv,dh", HEADS)
+def test_paged_window_kernel_compiles(spec, n_heads, n_kv, dh, int8):
+    """Chunked prefill (s=64) and a speculative-verify window (s=4)."""
+    b, n_tables = 4, 8
+    kv, scales = _pool(spec, n_kv, dh, int8)
+
+    def window(q, k, v, tabs, t, *sc):
+        return paged_window_attention(
+            q, k, v, tabs, t, sm_scale=dh ** -0.5,
+            k_scale=sc[0] if sc else None,
+            v_scale=sc[1] if sc else None, interpret=False)
+
+    for s in (64, 4):
+        text = _compiled_text(
+            window, spec((b, s, n_heads, dh), jnp.bfloat16), kv, kv,
+            spec((b, n_tables), jnp.int32), spec((b, s), jnp.int32),
+            *scales)
+        assert "tpu_custom_call" in text, f"s={s}"
+
+
+@pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
+def test_paged_kernels_compile_at_a_narrow_head_tile(spec, int8):
+    """An explicit tile of 8 of 16 kv heads — the one narrower tile the
+    compiler takes. On an int8 pool the scale column of each head is
+    then picked with a lane mask (``_head_scale``): lanes cannot be
+    indexed by a program id."""
+    b, n_tables, n_heads, n_kv, dh = 4, 8, 16, 16, 128
+    kv, scales = _pool(spec, n_kv, dh, int8)
+
+    def both(q, qw, k, v, tabs, t, tw, *sc):
+        kw = dict(sm_scale=dh ** -0.5, block_h=8, interpret=False,
+                  k_scale=sc[0] if sc else None,
+                  v_scale=sc[1] if sc else None)
+        return (paged_decode_attention(q, k, v, tabs, t, **kw),
+                paged_window_attention(qw, k, v, tabs, tw, **kw))
+
+    text = _compiled_text(
+        both, spec((b, n_heads, dh), jnp.bfloat16),
+        spec((b, 4, n_heads, dh), jnp.bfloat16), kv, kv,
+        spec((b, n_tables), jnp.int32), spec((b,), jnp.int32),
+        spec((b, 4), jnp.int32), *scales)
+    assert text.count("tpu_custom_call") >= 2
+
+
+@pytest.mark.parametrize("shape,causal", [
+    ((64, 12, 197, 64), False),    # ViT-B/16, batch 64
+    ((4, 16, 2048, 128), True),    # a causal LM at head size 128
+], ids=["vit_b16", "lm_2048_causal"])
+def test_flash_attention_fwd_bwd_compiles(spec, shape, causal):
+    def loss(q, k, v):
+        return flash_attention(q, k, v, causal=causal,
+                               interpret=False).astype(jnp.float32).sum()
+
+    x = spec(shape, jnp.bfloat16)
+    text = _compiled_text(jax.grad(loss, argnums=(0, 1, 2)), x, x, x)
+    # forward, dQ and dK/dV are three separate kernels
+    assert text.count("tpu_custom_call") >= 3
+
+
+def test_patch_embed_fwd_bwd_compiles(spec):
+    """ViT-B/16's patch embedding: 224x224x3 → 196 patches of 768."""
+    def loss(images, w, b):
+        return patch_embed(images, w, b, 16,
+                           False).astype(jnp.float32).sum()
+
+    # value AND grad: the loss is linear in the kernel's output, so a
+    # bare grad would leave the forward kernel dead code
+    text = _compiled_text(
+        jax.value_and_grad(loss, argnums=(1, 2)),
+        spec((64, 224, 224, 3), jnp.bfloat16),
+        spec((16 * 16 * 3, 768), jnp.bfloat16), spec((768,), jnp.bfloat16))
+    assert "tpu_custom_call" in text
