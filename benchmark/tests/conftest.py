@@ -1,0 +1,11 @@
+"""Run by hand: ``pytest benchmark/tests -q`` (tier-1 collects ``tests/``
+only). Everything here runs on the CPU at the tiny configurations."""
+
+import os
+import sys
+
+os.environ.setdefault("JAX_PLATFORMS", "cpu")
+ROOT = os.path.dirname(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
