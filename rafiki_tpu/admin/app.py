@@ -13,7 +13,7 @@ import base64
 from typing import Any, Dict, Optional, Tuple
 
 from ..obs import (PROM_CONTENT_TYPE, MetricsRegistry, TraceBuffer,
-                   mint_trace_id)
+                   debug_spans, mint_trace_id)
 from ..utils.http import JsonHttpService, RawResponse
 from .admin import Admin, AuthError
 
@@ -86,9 +86,12 @@ class AdminApp:
         # /metrics is numeric-only and stays open like /health; the
         # trace ring carries job ids/app names — USER-owned metadata —
         # so unlike the (by-design unauthenticated) worker/predictor
-        # surfaces, the admin's /debug/requests sits behind auth
+        # surfaces, the admin's /debug/requests sits behind auth — and
+        # /debug/spans with it (same rule: one debug surface, one gate)
         r("GET", "/metrics", self._metrics)
         r("GET", "/debug/requests", self._auth(self._debug_requests))
+        r("GET", "/debug/spans",
+          self._auth(lambda m, _b, _user: debug_spans(m)))
         r("POST", "/tokens", self._login)
         r("GET", "/health", self._health)
         r("GET", "/", self._dashboard)

@@ -18,6 +18,7 @@ from typing import (Any, Callable, Dict, Iterator, Optional, Sequence,
 import numpy as np
 
 from ..data.loader import prefetch_to_device
+from ..obs.trace import SPANS
 
 #: steps between jax.block_until_ready syncs: full overlap, bounded
 #: number of in-flight batches resident in HBM
@@ -38,17 +39,33 @@ def train_epoch(step: Callable[[Any, dict], Tuple[Any, Any]],
     """
     import jax
 
-    batches = (prefetch_to_device(host_batches, sharding=sharding)
-               if sharding is not None else host_batches)
-    losses = []
-    for batch in batches:
-        state, loss = step(state, batch)
-        losses.append(loss)
-        if sync_every and len(losses) % sync_every == 0:
-            jax.block_until_ready(loss)
-    if not losses:
-        return state, float("nan")
-    return state, float(np.mean([float(l) for l in losses]))
+    # phase spans (obs.SPANS, docs/observability.md "Phase spans"): the
+    # loop pulls each batch with an explicit next() so the feed's time —
+    # the iterator's slicing plus prefetch_to_device's device_put — is a
+    # span of its own and not hidden in a ``for`` header
+    with SPANS.span("train.epoch") as epoch:
+        batches = iter(prefetch_to_device(host_batches, sharding=sharding)
+                       if sharding is not None else host_batches)
+        losses = []
+        while True:
+            with SPANS.span("train.feed") as feed:
+                batch = next(batches, None)
+                if batch is None:
+                    # the pull that finds the iterator exhausted is the
+                    # epoch's end, not a feed: N batches are N feeds. The
+                    # reduction waits here for the last steps' losses
+                    feed.name = "train.epoch_end"
+                    mean = (float(np.mean([float(l) for l in losses]))
+                            if losses else float("nan"))
+                    break
+            with SPANS.span("train.dispatch"):
+                state, loss = step(state, batch)
+            losses.append(loss)
+            if sync_every and len(losses) % sync_every == 0:
+                with SPANS.span("train.sync"):
+                    jax.block_until_ready(loss)
+        epoch.set(steps=len(losses))
+    return state, mean
 
 
 @dataclass

@@ -1,8 +1,9 @@
-"""HTTP surfacing for the obs plane: ``/metrics`` + ``/debug/requests``.
+"""HTTP surfacing for the obs plane: ``/metrics``, ``/debug/requests``
+and ``/debug/spans``.
 
 Two entry points:
 
-- :func:`mount_obs_routes` adds the two routes to an EXISTING
+- :func:`mount_obs_routes` adds the three routes to an EXISTING
   :class:`~rafiki_tpu.utils.http.JsonHttpService` (admin app, predictor
   service — processes that already listen).
 - :class:`ObsServer` is a standalone single-purpose server for
@@ -17,16 +18,43 @@ from typing import Any, Optional, Tuple
 
 from ..utils.http import JsonHttpService, RawResponse
 from .metrics import PROM_CONTENT_TYPE, MetricsRegistry
-from .trace import TraceBuffer
+from .trace import SPANS, TraceBuffer, span_as_dict
 
 #: default /debug/requests page size (override with ?n=K)
 DEBUG_REQUESTS_DEFAULT_N = 32
+#: default /debug/spans page size: a few decode turns with their phases
+DEBUG_SPANS_DEFAULT_N = 256
+
+
+def _page_size(m: dict, default: int) -> Tuple[Optional[int], Any]:
+    """``?n=K`` of a debug route: ``(n, None)``, or ``(None, reply)``
+    with the 400 to send."""
+    try:
+        n = int(m.get("n", default))
+    except (TypeError, ValueError):
+        return None, (400, {"error": "n must be an integer"})
+    if n < 0:
+        return None, (400, {"error": "n must be >= 0"})
+    return n, None
+
+
+def debug_spans(m: dict) -> Tuple[int, Any]:
+    """``GET /debug/spans?n=K``: the newest ``K`` records of the
+    process's phase-span ring, newest first — "what was the host doing
+    while the chip sat idle just now"."""
+    n, bad = _page_size(m, DEBUG_SPANS_DEFAULT_N)
+    if bad:
+        return bad
+    recs = SPANS.snapshot()[-n:] if n else []
+    return 200, {"spans": [span_as_dict(r) for r in reversed(recs)],
+                 "count": len(recs)}
 
 
 def mount_obs_routes(http: JsonHttpService, registry: MetricsRegistry,
                      traces: Optional[TraceBuffer] = None) -> None:
-    """Mount ``GET /metrics`` (Prometheus text) and
-    ``GET /debug/requests?n=K`` (JSON trace records, newest first)."""
+    """Mount ``GET /metrics`` (Prometheus text),
+    ``GET /debug/requests?n=K`` (JSON trace records, newest first) and
+    ``GET /debug/spans?n=K`` (JSON phase spans, newest first)."""
 
     def _metrics(_m, _b, _h) -> Tuple[int, Any]:
         return 200, RawResponse(
@@ -34,25 +62,23 @@ def mount_obs_routes(http: JsonHttpService, registry: MetricsRegistry,
             PROM_CONTENT_TYPE)
 
     def _debug_requests(m, _b, _h) -> Tuple[int, Any]:
-        try:
-            n = int(m.get("n", DEBUG_REQUESTS_DEFAULT_N))
-        except (TypeError, ValueError):
-            return 400, {"error": "n must be an integer"}
-        if n < 0:
-            return 400, {"error": "n must be >= 0"}
+        n, bad = _page_size(m, DEBUG_REQUESTS_DEFAULT_N)
+        if bad:
+            return bad
         recs = traces.recent(n) if traces is not None else []
         return 200, {"requests": recs, "count": len(recs)}
 
     http.route("GET", "/metrics", _metrics)
     http.route("GET", "/debug/requests", _debug_requests)
+    http.route("GET", "/debug/spans", lambda m, _b, _h: debug_spans(m))
 
 
 class ObsServer:
     """Sidecar observability endpoint for HTTP-less processes.
 
-    Serves exactly ``/metrics``, ``/debug/requests``, and a trivial
-    ``/health`` on a daemon-threaded stdlib server; the owning loop
-    never blocks on it and ``stop()`` is idempotent.
+    Serves exactly ``/metrics``, ``/debug/requests``, ``/debug/spans``
+    and a trivial ``/health`` on a daemon-threaded stdlib server; the
+    owning loop never blocks on it and ``stop()`` is idempotent.
     """
 
     def __init__(self, registry: MetricsRegistry,

@@ -373,6 +373,7 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
         interpret=interpret,
+        name="paged_attn_step",  # what a profile calls the kernel
     )
     if interpret and jax.device_count() > 1:
         out = _partitioner_shield(call, t, tabs, *operands)
@@ -577,6 +578,7 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, n_qt, rows, dh), q.dtype),
         interpret=interpret,
+        name="paged_attn_window",  # what a profile calls the kernel
     )
     if interpret and jax.device_count() > 1:
         out = _partitioner_shield(call, t, tabs, *operands)

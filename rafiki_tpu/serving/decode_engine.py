@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..obs.metrics import StatsMap
+from ..obs.trace import SPANS
 from ..ops.paged_attention import (resolve_paged_kernel,
                                    resolve_paged_window_kernel)
 from .kv_tier import HostPageTier
@@ -152,6 +153,17 @@ class _Parked:
 
     def hbm_ids(self) -> List[int]:
         return [p for loc, p in self.pages if loc == "hbm"]
+
+
+def _spanned(name: str) -> Callable:
+    """Run a method inside one phase span ``name`` (``obs.SPANS``)."""
+    def deco(fn: Callable) -> Callable:
+        @functools.wraps(fn)
+        def wrapper(*args: Any, **kwargs: Any) -> Any:
+            with SPANS.span(name):
+                return fn(*args, **kwargs)
+        return wrapper
+    return deco
 
 
 class DecodeEngine:
@@ -433,7 +445,13 @@ class DecodeEngine:
             # verify-window rows, step tokens count fused-scan rows.
             "paged_kernel_mode": self.paged_kernel_mode,
             "paged_kernel_step_tokens": 0,
-            "paged_kernel_window_tokens": 0})
+            "paged_kernel_window_tokens": 0,
+            # the host's share of the loop, counted where the phase
+            # spans are cut (docs/observability.md "Phase spans"):
+            # step() calls, nanoseconds inside them outside the output
+            # sync (admission, operand building, launches, harvest),
+            # and nanoseconds blocked in that sync waiting on the device
+            "turns": 0, "turn_host_ns": 0, "sync_wait_ns": 0})
         if self.host_pages:
             self.tier = HostPageTier(self.host_pages, self.stats)
         #: finished prefill-only shipments awaiting poll_kv
@@ -442,12 +460,20 @@ class DecodeEngine:
         #: — the inference worker wires it into its trace buffer and
         #: latency histograms (TTFT, time-in-queue). Events: admitted,
         #: prefill, first_token, decode_mark (every
-        #: ``SPAN_DECODE_MARK_EVERY`` generated tokens), done. None
-        #: (the default) costs one attribute read per emission site.
+        #: ``SPAN_DECODE_MARK_EVERY`` generated tokens), done. Every
+        #: event but decode_mark is also an instant ``req.<event>`` in
+        #: the phase-span ring (``obs.SPANS``), sink or no sink, after
+        #: the ``req.submitted`` that ``submit`` writes there.
         self.span_sink: Optional[Callable[[str, Any, Dict[str, Any]],
                                           None]] = None
+        #: the open ``engine.turn`` span's seq (0 between turns): the
+        #: parent of the request instants a turn emits
+        self._turn_seq = 0
+        #: nanoseconds of the open turn spent in ``engine.sync_wait``
+        self._turn_sync_ns = 0
 
     # ---- submission / results (thread-safe: worker loop vs callers) ----
+    @_spanned("engine.submit")
     def submit(self, request_id: Any, prompt_ids: np.ndarray,
                max_new: int, temperature: float = 0.0, top_k: int = 0,
                top_p: float = 1.0, seed: int = 0,
@@ -534,6 +560,9 @@ class DecodeEngine:
                 adapter_id=aid, slo=cls, seq=self._seq,
                 prefill_only=bool(prefill_only),
                 kv_import=kv_import))
+        # ring only: the sink is the step thread's, this is a caller's
+        SPANS.instant("req.submitted", key=request_id,
+                      prompt_tokens=len(prompt), slo=cls)
 
     def _check_adapter_id(self, adapter_id: int) -> int:
         """Validate a request's adapter selection. Out-of-range ids
@@ -909,12 +938,14 @@ class DecodeEngine:
             self._ptab_dirty = False
         return self._ptab_dev
 
+    @_spanned("engine.poll")
     def poll(self) -> List[Tuple[Any, List[int]]]:
         """Completed (request_id, generated ids) since the last poll."""
         with self._lock:
             done, self._done = self._done, []
         return done
 
+    @_spanned("engine.poll")
     def poll_partial(self) -> List[Tuple[Any, List[int]]]:
         """(request_id, generated-so-far) for STILL-LIVE slots that
         produced new tokens since the last ``poll_partial``. Cumulative
@@ -1221,6 +1252,7 @@ class DecodeEngine:
                                     - self.tier.free_pages()),
                 kv_parked_slots=len(self._parked))
         self.stats.reset(keep=keep)
+        SPANS.instant("engine.stats_reset")
 
     def stats_snapshot(self) -> Dict[str, int]:
         """A point-in-time copy of the counters, taken under the stats
@@ -1230,8 +1262,17 @@ class DecodeEngine:
         return self.stats.snapshot()
 
     def _span(self, event: str, request_id: Any, **attrs: Any) -> None:
-        """Emit a request-lifecycle event to the wired sink (no-op —
-        one attribute read — when nothing is wired)."""
+        """Record a request-lifecycle event: an instant ``req.<event>``
+        in the phase-span ring under the open turn, then the wired sink
+        (if any)."""
+        SPANS.instant("req." + event, request_id, self._turn_seq or None,
+                      **attrs)
+        self._to_sink(event, request_id, attrs)
+
+    def _to_sink(self, event: str, request_id: Any,
+                 attrs: Dict[str, Any]) -> None:
+        """Hand an event to the wired sink (one attribute read when
+        nothing is wired)."""
         sink = self.span_sink
         if sink is None:
             return
@@ -1301,91 +1342,113 @@ class DecodeEngine:
                 jax.random.PRNGKey(0), jnp.zeros((self.B, 1), jnp.int32),
                 decode=True)["cache"]
 
-    def _chunked_prefill(self) -> None:
+    def _chunked_prefill(self) -> int:
         """Ingest admitted prompts C tokens per compiled call before they
         join the decode scan (positions 0..plen−2; the scan then starts
         at the LAST prompt token, whose step emits the first generated
         token). Slots not prefilling re-feed their current input — an
         identical rewrite of a cache entry, harmless by construction —
-        so one fixed-shape program serves any admission mix."""
+        so one fixed-shape program serves any admission mix. Returns
+        the number of calls made; each is one ``engine.prefill_prep``
+        span (its operands) and one ``engine.prefill_dispatch`` (the
+        launch, then the call's counters and position advance)."""
         occupied = np.array([s is not None for s in self._slots],
                             bool)
+        calls = 0
         while True:
-            rem = np.where(occupied,
-                           np.maximum(0, (self._prompt_len - 1)
-                                      - self._pos), 0)
-            if rem.max() == 0:
-                break
-            fill_fn, c_use = self._prefill_fn, self.C
-            if (self._prefill_fn_small is not None
-                    and self._draft_cache is None
-                    and rem.max() <= self._small_c):
-                # short remainder: the narrow program ingests it
-                # without the C-wide call's cost (the draft mirror is
-                # compiled at C only, so draft engines stay wide)
-                fill_fn, c_use = self._prefill_fn_small, self._small_c
-            adv = np.minimum(rem, c_use)
-            tok_chunk = np.empty((self.B, c_use), np.int32)
-            pos_chunk = np.empty((self.B, c_use), np.int32)
-            for i in range(self.B):
-                a = int(adv[i])
-                if a > 0:
-                    p0 = int(self._pos[i])
-                    tok_chunk[i, :a] = self._prompt_buf[i, p0:p0 + a]
-                    pos_chunk[i, :a] = np.arange(p0, p0 + a)
-                    # pad by repeating the chunk's last real entry —
-                    # rewrites a just-written cache slot identically
-                    tok_chunk[i, a:] = tok_chunk[i, a - 1]
-                    pos_chunk[i, a:] = pos_chunk[i, a - 1]
-                else:
-                    tok_chunk[i, :] = self._tok[i]
-                    pos_chunk[i, :] = self._pos[i]
-            if self.paged:
-                # lazy allocation tracks the prompt walk: each chunk
-                # only maps the pages it is about to write. The slot
-                # re-check matters on tiered engines: an earlier
-                # lane's growth may have PARKED this one (page
-                # reclaim) inside this very loop — its row is zeroed
-                # and its pos reset, so ensuring pages here would
-                # allocate for an empty lane and leak them
-                for i in range(self.B):
-                    if adv[i] > 0 and self._slots[i] is not None:
-                        self._ensure_pages_to(
-                            i, int(self._pos[i]) + int(adv[i]) - 1)
-            tok_dev = jnp.asarray(tok_chunk)
-            pos_dev = jnp.asarray(pos_chunk)
-            aid_dev = jnp.asarray(self._aid)
-            self._cache = fill_fn(
-                self.params, self._cache, tok_dev, pos_dev, aid_dev,
-                self._ptab_arg())
-            if self._draft_cache is not None and self._draft_synced:
-                # keep the draft's KV in lockstep with the prompt walk
-                # (while desynced, resync rebuilds prompts anyway)
-                self._draft_cache = self._draft_sync_c(
-                    self.draft_params, self._draft_cache, tok_dev,
-                    pos_dev, aid_dev, self._ptab_arg())
-            self.stats.inc("prefill_calls")
-            self.stats.inc("prefill_tokens", int(adv.sum()))
-            if self.paged_kernel_windowed:
-                # these prompt tokens attended through the window
-                # kernel (the chunk call is an s=C window)
-                self.stats.inc("paged_kernel_window_tokens",
-                               int(adv.sum()))
-            if self.prefill_token_cost_s:
-                # outside the engine lock (step releases it before
-                # prefill) so a dilated chunk stalls exactly what real
-                # prompt compute would: this loop thread, nothing else
-                time.sleep(self.prefill_token_cost_s * int(adv.sum()))
+            with SPANS.span("engine.prefill_prep"):
+                prep = self._prefill_operands(occupied)
+            if prep is None:
+                return calls
+            fill_fn, adv, tok_dev, pos_dev, aid_dev, ptab = prep
+            calls += 1
+            with SPANS.span("engine.prefill_dispatch"):
+                self._cache = fill_fn(
+                    self.params, self._cache, tok_dev, pos_dev, aid_dev,
+                    ptab)
+                if self._draft_cache is not None and self._draft_synced:
+                    # keep the draft's KV in lockstep with the prompt
+                    # walk (while desynced, resync rebuilds prompts
+                    # anyway)
+                    self._draft_cache = self._draft_sync_c(
+                        self.draft_params, self._draft_cache, tok_dev,
+                        pos_dev, aid_dev, self._ptab_arg())
+                del prep, tok_dev, pos_dev, aid_dev, ptab  # see _turn()
+                self._book_prefill(adv)
+
+    def _prefill_operands(self, occupied: np.ndarray) -> Optional[Tuple]:
+        """One chunk call's program, advance and device operands, or
+        None when no occupied lane has prompt left to ingest."""
+        rem = np.where(occupied,
+                       np.maximum(0, (self._prompt_len - 1)
+                                  - self._pos), 0)
+        if rem.max() == 0:
+            return None
+        fill_fn, c_use = self._prefill_fn, self.C
+        if (self._prefill_fn_small is not None
+                and self._draft_cache is None
+                and rem.max() <= self._small_c):
+            # short remainder: the narrow program ingests it
+            # without the C-wide call's cost (the draft mirror is
+            # compiled at C only, so draft engines stay wide)
+            fill_fn, c_use = self._prefill_fn_small, self._small_c
+        adv = np.minimum(rem, c_use)
+        tok_chunk = np.empty((self.B, c_use), np.int32)
+        pos_chunk = np.empty((self.B, c_use), np.int32)
+        for i in range(self.B):
+            a = int(adv[i])
+            if a > 0:
+                p0 = int(self._pos[i])
+                tok_chunk[i, :a] = self._prompt_buf[i, p0:p0 + a]
+                pos_chunk[i, :a] = np.arange(p0, p0 + a)
+                # pad by repeating the chunk's last real entry —
+                # rewrites a just-written cache slot identically
+                tok_chunk[i, a:] = tok_chunk[i, a - 1]
+                pos_chunk[i, a:] = pos_chunk[i, a - 1]
+            else:
+                tok_chunk[i, :] = self._tok[i]
+                pos_chunk[i, :] = self._pos[i]
+        if self.paged:
+            # lazy allocation tracks the prompt walk: each chunk
+            # only maps the pages it is about to write. The slot
+            # re-check matters on tiered engines: an earlier
+            # lane's growth may have PARKED this one (page
+            # reclaim) inside this very loop — its row is zeroed
+            # and its pos reset, so ensuring pages here would
+            # allocate for an empty lane and leak them
             for i in range(self.B):
                 if adv[i] > 0 and self._slots[i] is not None:
-                    # a lane parked mid-chunk (page reclaim) skips the
-                    # advance: its record saved the PRE-chunk position,
-                    # so the resume re-prefills this chunk — the
-                    # chunk's writes went to the scratch page (its
-                    # table row was zeroed at park), losing nothing
-                    self._pos[i] += int(adv[i])
-                    self._slots[i].n_consumed += int(adv[i])
-                    self._tok[i] = self._prompt_buf[i, int(self._pos[i])]
+                    self._ensure_pages_to(
+                        i, int(self._pos[i]) + int(adv[i]) - 1)
+        return (fill_fn, adv, jnp.asarray(tok_chunk),
+                jnp.asarray(pos_chunk), jnp.asarray(self._aid),
+                self._ptab_arg())
+
+    def _book_prefill(self, adv: np.ndarray) -> None:
+        """Counters and the host-side position advance of one chunk
+        call that ingested ``adv[i]`` prompt tokens on lane ``i``."""
+        self.stats.inc("prefill_calls")
+        self.stats.inc("prefill_tokens", int(adv.sum()))
+        if self.paged_kernel_windowed:
+            # these prompt tokens attended through the window
+            # kernel (the chunk call is an s=C window)
+            self.stats.inc("paged_kernel_window_tokens",
+                           int(adv.sum()))
+        if self.prefill_token_cost_s:
+            # outside the engine lock (step releases it before
+            # prefill) so a dilated chunk stalls exactly what real
+            # prompt compute would: this loop thread, nothing else
+            time.sleep(self.prefill_token_cost_s * int(adv.sum()))
+        for i in range(self.B):
+            if adv[i] > 0 and self._slots[i] is not None:
+                # a lane parked mid-chunk (page reclaim) skips the
+                # advance: its record saved the PRE-chunk position,
+                # so the resume re-prefills this chunk — the
+                # chunk's writes went to the scratch page (its
+                # table row was zeroed at park), losing nothing
+                self._pos[i] += int(adv[i])
+                self._slots[i].n_consumed += int(adv[i])
+                self._tok[i] = self._prompt_buf[i, int(self._pos[i])]
 
     # ---- SLO preemption (lock held: admission-loop context) ----
     def _occupants(self, live_only: bool = False
@@ -1597,7 +1660,74 @@ class DecodeEngine:
     def step(self) -> int:
         """Admit queued requests into free slots, run K fused compiled
         steps for every live slot, harvest completions. Returns live
-        count (at admission time)."""
+        count (at admission time).
+
+        One ``engine.turn`` span whose body is tiled by the leaf spans
+        ``engine.admit`` / ``prefill_prep`` / ``prefill_dispatch`` /
+        ``decode_prep`` / ``decode_dispatch`` / ``sync_wait`` /
+        ``harvest`` (docs/observability.md "Phase spans"); the counters
+        ``turns``, ``turn_host_ns`` and ``sync_wait_ns`` are cut at the
+        same boundaries."""
+        self._turn_sync_ns = 0
+        with SPANS.span("engine.turn") as turn:
+            self._turn_seq = turn.seq
+            try:
+                n_live = self._turn(turn)
+            finally:
+                self._turn_seq = 0
+        self.stats.inc("turns")
+        self.stats.inc("sync_wait_ns", self._turn_sync_ns)
+        self.stats.inc("turn_host_ns",
+                       turn.t1 - turn.t0 - self._turn_sync_ns)
+        return n_live
+
+    def _turn(self, turn: Any) -> int:
+        """The body of :meth:`step`, phase by phase."""
+        with SPANS.span("engine.admit"):
+            live, admitted, admitted_info = self._admit()
+        prefilling = bool(live and admitted
+                          and self._prefill_fn is not None)
+        n_prefill = self._chunked_prefill() if prefilling else 0
+        with SPANS.span("engine.decode_prep"):
+            if prefilling:
+                for rid, row, plen, cls, resumed in admitted_info:
+                    self._span("prefill", rid, prompt_tokens=plen)
+            call = self._decode_prep(live, admitted)
+        turn.set(live=len(live), admitted=len(admitted_info),
+                 prefill_calls=n_prefill,
+                 path=call[0] if call else "idle")
+        if call is None:
+            return 0
+        path, live, any_sampling, operands = call
+        if path == "spec":
+            return self._speculative_step(live)
+        with SPANS.span("engine.decode_dispatch"):
+            self._cache, emitted = self._step_fns[any_sampling](*operands)
+            # the launch's operand handles die with the launch, as the
+            # temporaries they are (freeing eleven device buffers takes
+            # ~0.1 ms: inside a span, not in the turn's own time)
+            del call, operands
+        # the loop's OUTPUT sync: generated tokens must reach the host
+        # to stream; the fused K-step scan amortizes it
+        emitted, = self._sync_wait(emitted)
+        with SPANS.span("engine.harvest"):
+            self._harvest_scan(live, emitted, any_sampling)
+        return len(live)
+
+    def _sync_wait(self, *outputs: Any) -> List[np.ndarray]:
+        """Pull a launch's outputs to the host: the one place the loop
+        blocks on the device. One ``engine.sync_wait`` span, counted
+        into the turn's ``sync_wait_ns``."""
+        with SPANS.span("engine.sync_wait") as wait:
+            pulled = [np.asarray(x) for x in outputs]  # rafiki: noqa[blocking-transfer-in-decode-loop] — the decode loop's output sync; each caller says why it must pull
+        self._turn_sync_ns += wait.t1 - wait.t0
+        return pulled
+
+    def _admit(self) -> Tuple[List[int], bool, List[Tuple]]:
+        """Unpark, admit queued requests into free lanes (preempting
+        where the SLO policy allows), publish the queue gauges and emit
+        the turn's ``preempted`` / ``admitted`` events. Returns the
+        live lanes, whether occupancy changed, and the admissions."""
         admitted_info: List[Tuple[Any, int, int, str]] = []
         preempted_info: List[Tuple[Any, int, int, str, str]] = []
         with self._lock:
@@ -1697,13 +1827,19 @@ class DecodeEngine:
             # service time, not backlog)
             self._span("admitted", rid, slot=row, prompt_tokens=plen,
                        slo=cls, resumed=resumed)
+        return live, admitted, admitted_info
+
+    def _decode_prep(self, live: List[int], admitted: bool
+                     ) -> Optional[Tuple[str, List[int], bool, Tuple]]:
+        """Everything between prefill and the decode launch: settle the
+        lanes, pick the path, map the pages the call will write and
+        build its operands. Returns ``(path, live, any_sampling,
+        operands)`` with ``path`` ``"scan"`` or ``"spec"`` (operands
+        empty: :meth:`_speculative_step` builds its own), or None when
+        no lane is left to run."""
         if not live:
             self._prefetch_hint()
-            return 0
-        if admitted and self._prefill_fn is not None:
-            self._chunked_prefill()
-            for rid, row, plen, cls, resumed in admitted_info:
-                self._span("prefill", rid, prompt_tokens=plen)
+            return None
         # prefill-only slots that reached their last prompt token are
         # done NOW: extract their KV shipment and free the lane before
         # the decode scan (they never generate)
@@ -1716,7 +1852,7 @@ class DecodeEngine:
             self._prompt_dev = jnp.asarray(self._prompt_buf)
         self._prefetch_hint()
         if not live:
-            return 0
+            return None
 
         any_sampling = bool(any(
             self._slots[i] is not None and self._slots[i].temperature > 0
@@ -1732,7 +1868,7 @@ class DecodeEngine:
                 and all(self._pos[i] >= len(self._slots[i].prompt) - 1
                         and int(self._pos[i]) + self.spec_k <= self.L
                         for i in live)):
-            return self._speculative_step(live)
+            return "spec", live, any_sampling, ()
         if self._verify_fn is not None:
             self._spec_idle += 1
         if self.paged:
@@ -1747,14 +1883,18 @@ class DecodeEngine:
                 self._ensure_pages_to(i, min(
                     int(self._pos[i]) + self.K,
                     int(self._stop_pos[i])) - 1)
-        self._cache, emitted = self._step_fns[any_sampling](
+        return "scan", live, any_sampling, (
             self.params, self._cache, jnp.asarray(self._tok),
             jnp.asarray(self._pos), self._prompt_dev,
             jnp.asarray(self._prompt_len), jnp.asarray(self._stop_pos),
             jnp.asarray(self._temp), jnp.asarray(self._topk),
             jnp.asarray(self._topp), jnp.asarray(self._seed),
             jnp.asarray(self._aid), self._ptab_arg())
-        emitted = np.asarray(emitted)  # rafiki: noqa[blocking-transfer-in-decode-loop] — the loop's OUTPUT sync: generated tokens must reach the host to stream; the fused K-step scan amortizes it
+
+    def _harvest_scan(self, live: List[int], emitted: np.ndarray,
+                      any_sampling: bool) -> None:
+        """Book one fused scan's output: counters, the draft mirror,
+        each lane's new tokens, finished requests and their pages."""
         self.stats.inc("steps", self.K)
         if self.paged_kernel_active:
             # every live lane ran K single-token steps through the
@@ -1832,21 +1972,19 @@ class DecodeEngine:
                 self.stats.inc("requests_done", len(finished))
             for rid, toks in finished:
                 self._span("done", rid, tokens=len(toks))
-        return len(live)
 
     def _mark_progress(self, slot: "_Slot", n0: int, n1: int) -> None:
-        """first_token / periodic decode_mark spans for a slot that
-        grew from ``n0`` to ``n1`` generated tokens this call. Pure
-        integer math when no sink is wired."""
-        if self.span_sink is None:
-            return
+        """first_token / periodic decode_mark events for a slot that
+        grew from ``n0`` to ``n1`` generated tokens this call. The
+        decode_mark is the sink's alone: nothing reads it from the
+        ring."""
         if not slot.first_tokened:
             # flag, not n0 == 0: a preempt-resumed slot restarts its
             # generated list at 0 but its stream already first-tokened
             slot.first_tokened = True
             self._span("first_token", slot.request_id)
         if n0 // SPAN_DECODE_MARK_EVERY != n1 // SPAN_DECODE_MARK_EVERY:
-            self._span("decode_mark", slot.request_id, tokens=n1)
+            self._to_sink("decode_mark", slot.request_id, {"tokens": n1})
 
     def _resync_draft(self) -> None:
         """Rebuild the draft cache from every live slot's ACCEPTED
@@ -1935,58 +2073,84 @@ class DecodeEngine:
         first mismatch (1..spec_k tokens). Rejected drafts leave stale
         KV rows ABOVE the slot's new position — unreachable by the
         position mask, and rewritten in place when generation reaches
-        them (the admission-reuse invariant already relies on this)."""
+        them (the admission-reuse invariant already relies on this).
+        The same four leaf spans as the scan: ``engine.decode_prep`` /
+        ``decode_dispatch`` / ``sync_wait`` / ``harvest`` (a draft
+        model's own scan and pull are a dispatch and a wait too)."""
         k = self.spec_k
         if self._draft_cache is not None:
-            if not self._draft_synced:  # re-probe after a gated-off
-                self._resync_draft()    # stretch with skipped mirrors
+            with SPANS.span("engine.decode_prep"):
+                if not self._draft_synced:  # re-probe after a gated-off
+                    self._resync_draft()    # stretch with skipped mirrors
+                draft_operands = (
+                    self.draft_params, self._draft_cache,
+                    jnp.asarray(self._tok), jnp.asarray(self._pos),
+                    self._prompt_dev, jnp.asarray(self._prompt_len),
+                    jnp.asarray(self._stop_pos), jnp.asarray(self._temp),
+                    jnp.asarray(self._topk), jnp.asarray(self._topp),
+                    jnp.asarray(self._seed), jnp.asarray(self._aid),
+                    self._ptab_arg())
             # draft phase: k-1 fused greedy steps on the DRAFT model
             # (argmax feedback), advancing its synced cache; then the
             # verify mirror writes the window's inputs [tok, drafts]
             # so the final row exists for fully-accepted windows
-            self._draft_cache, d_emit = self._draft_scan(
-                self.draft_params, self._draft_cache,
-                jnp.asarray(self._tok), jnp.asarray(self._pos),
-                self._prompt_dev, jnp.asarray(self._prompt_len),
-                jnp.asarray(self._stop_pos), jnp.asarray(self._temp),
-                jnp.asarray(self._topk), jnp.asarray(self._topp),
-                jnp.asarray(self._seed), jnp.asarray(self._aid),
+            with SPANS.span("engine.decode_dispatch"):
+                self._draft_cache, d_emit = self._draft_scan(
+                    *draft_operands)
+                del draft_operands  # see _turn()
+            # draft tokens feed the host-built verify operands: one
+            # pull per K-token window
+            d_emit, = self._sync_wait(d_emit)
+            drafts = d_emit.T.astype(np.int32)
+        with SPANS.span("engine.decode_prep"):
+            if self._draft_cache is not None:
+                offs = np.arange(k, dtype=np.int32)[None, :]
+                self._draft_cache = self._draft_sync_v(
+                    self.draft_params, self._draft_cache,
+                    jnp.asarray(np.concatenate(
+                        [self._tok[:, None], drafts], axis=1)),
+                    jnp.asarray(self._pos[:, None] + offs),
+                    jnp.asarray(self._aid), self._ptab_arg())
+                self.stats.inc("spec_draft_model_calls")
+            else:
+                drafts = np.zeros((self.B, k - 1), np.int32)
+                for i in live:
+                    s = self._slots[i]
+                    ctx = np.concatenate(
+                        [s.prompt, np.asarray(s.generated, np.int32)])
+                    drafts[i] = _ngram_draft(ctx, k - 1)
+            if self.paged:
+                for i in live:
+                    # the verify window writes positions pos..pos+k-1
+                    # (gated above to fit the cache); its pages must
+                    # exist even for drafts that end up rejected — the
+                    # standard unreachable-then-rewritten rows, inside
+                    # reservation. Slot re-check: a mid-loop park
+                    # (tiered page reclaim) empties a later lane — see
+                    # _chunked_prefill
+                    if self._slots[i] is None:
+                        continue
+                    self._ensure_pages_to(i, min(
+                        int(self._pos[i]) + k - 1, self.L - 1))
+            operands = (
+                self.params, self._cache, jnp.asarray(self._tok),
+                jnp.asarray(self._pos), jnp.asarray(drafts),
+                jnp.asarray(self._stop_pos), jnp.asarray(self._aid),
                 self._ptab_arg())
-            drafts = np.asarray(d_emit).T.astype(np.int32)  # rafiki: noqa[blocking-transfer-in-decode-loop] — draft tokens feed the host-built verify operands; one pull per K-token window
-            offs = np.arange(k, dtype=np.int32)[None, :]
-            self._draft_cache = self._draft_sync_v(
-                self.draft_params, self._draft_cache,
-                jnp.asarray(np.concatenate(
-                    [self._tok[:, None], drafts], axis=1)),
-                jnp.asarray(self._pos[:, None] + offs),
-                jnp.asarray(self._aid), self._ptab_arg())
-            self.stats.inc("spec_draft_model_calls")
-        else:
-            drafts = np.zeros((self.B, k - 1), np.int32)
-            for i in live:
-                s = self._slots[i]
-                ctx = np.concatenate(
-                    [s.prompt, np.asarray(s.generated, np.int32)])
-                drafts[i] = _ngram_draft(ctx, k - 1)
-        if self.paged:
-            for i in live:
-                # the verify window writes positions pos..pos+k-1
-                # (gated above to fit the cache); its pages must exist
-                # even for drafts that end up rejected — the standard
-                # unreachable-then-rewritten rows, inside reservation.
-                # Slot re-check: a mid-loop park (tiered page reclaim)
-                # empties a later lane — see _chunked_prefill
-                if self._slots[i] is None:
-                    continue
-                self._ensure_pages_to(i, min(
-                    int(self._pos[i]) + k - 1, self.L - 1))
-        self._cache, g, n_emit = self._verify_fn(
-            self.params, self._cache, jnp.asarray(self._tok),
-            jnp.asarray(self._pos), jnp.asarray(drafts),
-            jnp.asarray(self._stop_pos), jnp.asarray(self._aid),
-            self._ptab_arg())
-        g = np.asarray(g)            # rafiki: noqa[blocking-transfer-in-decode-loop] — verify OUTPUT sync: accepted tokens must reach the host to stream
-        n_emit = np.asarray(n_emit)  # rafiki: noqa[blocking-transfer-in-decode-loop] — ditto (acceptance counts gate the host-side emit)
+        with SPANS.span("engine.decode_dispatch"):
+            self._cache, g, n_emit = self._verify_fn(*operands)
+            del operands  # see _turn()
+        # verify OUTPUT sync: accepted tokens must reach the host to
+        # stream, and the acceptance counts gate the host-side emit
+        g, n_emit = self._sync_wait(g, n_emit)
+        with SPANS.span("engine.harvest"):
+            self._harvest_verify(live, k, g, n_emit)
+        return len(live)
+
+    def _harvest_verify(self, live: List[int], k: int, g: np.ndarray,
+                        n_emit: np.ndarray) -> None:
+        """Book one verify call's output: counters, the acceptance
+        gate, each lane's accepted tokens, finished requests."""
         self.stats.inc("steps")
         self.stats.inc("spec_calls")
         if self.paged_kernel_windowed:
@@ -2040,7 +2204,6 @@ class DecodeEngine:
                 self.stats.inc("requests_done", len(finished))
             for rid, toks in finished:
                 self._span("done", rid, tokens=len(toks))
-        return len(live)
 
 
 def _ngram_draft(context: np.ndarray, k: int, max_n: int = 3) -> np.ndarray:
