@@ -919,6 +919,46 @@ def quantize_llama_params(params: Any) -> Any:
     return walk(params)
 
 
+def serving_llama_params(params: Any, dtype: Any) -> Any:
+    """Param tree → its SERVING form for compute dtype ``dtype``: every
+    leaf a LoRADense site would cast to ``x.dtype`` before using it —
+    the base ``kernel``, ``lora_a`` / ``lora_b`` (stacked multi-adapter
+    ones too) and a quantized site's ``qscale`` — is held in ``dtype``
+    already; everything else passes through as the very leaf it was
+    (RMSNorm ``scale`` multiplies in f32, ``tok_embed`` is a gather,
+    ``qkernel`` stays int8, the MoE casts its own). ``dtype=None`` (f32
+    compute) returns ``params`` itself.
+
+    Same numbers, cast ONCE: ``LoRADense``'s ``astype(x.dtype)`` rounds
+    the same f32 values to the same ``dtype`` values whether it runs
+    here or at use (take-then-cast equals cast-then-take for the
+    stacked adapters), and on this tree it is a no-op — so a decode
+    dispatch streams the weights it computes with instead of re-deriving
+    them from an f32 tree twice their size. Serving only: training,
+    ``evaluate`` and ``predict`` keep the f32 originals. A cast leaf
+    keeps its input's sharding (an elementwise op).
+    """
+    if dtype is None:
+        return params
+    cast = ("kernel", "lora_a", "lora_b", "qscale")
+
+    def walk(tree: Any) -> Any:
+        if not isinstance(tree, dict):
+            return tree
+        out = {}
+        for name, sub in tree.items():
+            if isinstance(sub, dict) and (
+                    "qkernel" in sub
+                    or getattr(sub.get("kernel"), "ndim", 0) == 2):
+                out[name] = {kk: (jnp.asarray(vv, dtype) if kk in cast
+                                  else vv) for kk, vv in sub.items()}
+            else:
+                out[name] = walk(sub)
+        return out
+
+    return walk(params)
+
+
 def stack_block_params(params: Any, depth: int, n_stages: int) -> Any:
     """Canonical ``block_i`` params → (S, k, …) pipeline stacks (stage
     s owns layers [s·k, (s+1)·k), k = depth/S)."""
@@ -1631,6 +1671,9 @@ class LlamaLoRA(BaseModel):
         - ``params``: EXACT when the model is loaded — byte count of
           the actual serving tree (the int8 tree when ``quantize_int8``
           is set), else the abstract f32 init.
+        - ``engine_params``: the decode engine's compute-dtype copy
+          of the kernels and adapters (:func:`serving_llama_params`;
+          0 at f32 compute, and with ``max_slots=0``: no engine).
         - ``kv_cache``: max_slots x max_len x kv_heads x head_dim x
           2 (K and V) x depth, at int8+f32-scales when
           ``kv_cache_int8`` else the compute dtype. Multi-adapter
@@ -1664,21 +1707,28 @@ class LlamaLoRA(BaseModel):
         L, depth = int(k["max_len"]), int(k["depth"])
         act_bytes = 2 if bool(k.get("bf16", False)) else 4
 
+        def nbytes(leaf: Any) -> int:
+            return int(np.prod(leaf.shape)) * np.dtype(leaf.dtype).itemsize
+
         if self._params is not None:
             module, params = self._serving_module_params()
-            params_dev = sum(
-                int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
-                for l in jax.tree_util.tree_leaves(params))
-            vocab = module.vocab_size
         else:
             module = self._module()
-            abstract = jax.eval_shape(
+            params = jax.eval_shape(
                 lambda: module.init(jax.random.PRNGKey(0),
-                                    jnp.zeros((1, L), jnp.int32)))
-            params_dev = sum(
-                int(np.prod(l.shape)) * np.dtype(l.dtype).itemsize
-                for l in jax.tree_util.tree_leaves(abstract["params"]))
-            vocab = module.vocab_size
+                                    jnp.zeros((1, L), jnp.int32)))["params"]
+        leaves = jax.tree_util.tree_leaves(params)
+        params_dev = sum(nbytes(l) for l in leaves)
+        vocab = module.vocab_size
+        engine_dev = 0
+        if max_slots > 0:
+            # the engine serves from its own compute-dtype copy of the
+            # leaves LoRADense casts (DecodeEngine.params), held BESIDE
+            # the tree above, which predict()/evaluate() keep reading
+            served = jax.tree_util.tree_leaves(jax.eval_shape(
+                lambda p: serving_llama_params(p, module.dtype), params))
+            engine_dev = sum(nbytes(s) for s, l in zip(served, leaves)
+                             if s.dtype != l.dtype)
 
         per_pos = kv_heads * dh
         if int(kv_page_size) > 0:
@@ -1722,12 +1772,12 @@ class LlamaLoRA(BaseModel):
         draft_dev = 0
         if draft is not None:
             d = draft.estimate_serving_device_bytes(max_slots=max_slots)
-            draft_dev = d["params"] + d["kv_cache"]
+            draft_dev = d["params"] + d["engine_params"] + d["kv_cache"]
         working = (max_slots * 32 * hd * act_bytes  # prefill chunk
                    + max_slots * vocab * 4)         # logits buffer
-        out = {"params": params_dev, "kv_cache": kv_dev,
-               "adapters": adapters_dev, "draft": draft_dev,
-               "working": working}
+        out = {"params": params_dev, "engine_params": engine_dev,
+               "kv_cache": kv_dev, "adapters": adapters_dev,
+               "draft": draft_dev, "working": working}
         out["total"] = sum(out.values())
         if int(host_kv_pages):
             # same per-position bytes as the device pool, host side —

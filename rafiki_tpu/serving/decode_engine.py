@@ -166,6 +166,13 @@ def _spanned(name: str) -> Callable:
     return deco
 
 
+def _serving_tree(module: Any, params: Any) -> Any:
+    """``params`` (or ``None``) in ``module``'s serving form."""
+    from ..models.llama_lora import serving_llama_params
+
+    return serving_llama_params(params, getattr(module, "dtype", None))
+
+
 class DecodeEngine:
     """Slot-based continuous batching over one compiled decode step.
 
@@ -187,7 +194,6 @@ class DecodeEngine:
                  host_kv_pages: int = 0,
                  prefill_token_cost_s: float = 0.0) -> None:
         self.module = module
-        self.params = params
         self.B = int(max_slots)
         self.L = int(max_len)
         self.K = max(1, int(steps_per_sync))
@@ -355,7 +361,8 @@ class DecodeEngine:
         #: are the standard unreachable-then-rewritten case. Greedy-
         #: lossless like prompt-lookup: the verify step is target-
         #: authoritative either way.
-        self.draft_module, self.draft_params = draft or (None, None)
+        self.draft_module, draft_params = draft or (None, None)
+        self.draft_params = _serving_tree(self.draft_module, draft_params)
         self._draft_cache = None
         if self.draft_module is not None and self.spec_k:
             self._draft_cache = self.draft_module.init(
@@ -446,12 +453,16 @@ class DecodeEngine:
             "paged_kernel_mode": self.paged_kernel_mode,
             "paged_kernel_step_tokens": 0,
             "paged_kernel_window_tokens": 0,
+            # bytes of the tree the step programs take (``params``: the
+            # compute-dtype serving form, not the tree it was made from)
+            "weight_bytes": 0,
             # the host's share of the loop, counted where the phase
             # spans are cut (docs/observability.md "Phase spans"):
             # step() calls, nanoseconds inside them outside the output
             # sync (admission, operand building, launches, harvest),
             # and nanoseconds blocked in that sync waiting on the device
             "turns": 0, "turn_host_ns": 0, "sync_wait_ns": 0})
+        self.params = params
         if self.host_pages:
             self.tier = HostPageTier(self.host_pages, self.stats)
         #: finished prefill-only shipments awaiting poll_kv
@@ -471,6 +482,24 @@ class DecodeEngine:
         self._turn_seq = 0
         #: nanoseconds of the open turn spent in ``engine.sync_wait``
         self._turn_sync_ns = 0
+
+    @property
+    def params(self) -> Any:
+        """The tree the engine's programs read: the SERVING form of what
+        was assigned (``serving_llama_params`` for the module's compute
+        dtype), cast once at assignment — never inside a dispatch. The
+        engine keeps no reference to the tree it was made from, so a
+        caller that drops its f32 leaves gets their memory back."""
+        return self._params
+
+    @params.setter
+    def params(self, tree: Any) -> None:
+        # drop the held tree BEFORE casting the new one: f32 + two
+        # compute-dtype copies of a model do not fit beside each other
+        self._params = None
+        self._params = _serving_tree(self.module, tree)
+        self.stats.set("weight_bytes", sum(
+            int(x.nbytes) for x in jax.tree_util.tree_leaves(self._params)))
 
     # ---- submission / results (thread-safe: worker loop vs callers) ----
     @_spanned("engine.submit")
@@ -1241,7 +1270,8 @@ class DecodeEngine:
         gauges (``kv_pages_total`` describes the pool, not traffic) —
         what the worker's post-warmup scrub needs."""
         keep = {"paged_kernel_mode": self.paged_kernel_mode,
-                "kv_host_pages_total": self.host_pages}
+                "kv_host_pages_total": self.host_pages,
+                "weight_bytes": self.stats["weight_bytes"]}
         if self.paged:
             keep.update(kv_pages_total=self.n_pages - 1,
                         kv_pages_used=(self.n_pages - 1
