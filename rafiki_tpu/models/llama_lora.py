@@ -926,8 +926,12 @@ def serving_llama_params(params: Any, dtype: Any) -> Any:
     ones too) and a quantized site's ``qscale`` — is held in ``dtype``
     already; everything else passes through as the very leaf it was
     (RMSNorm ``scale`` multiplies in f32, ``tok_embed`` is a gather,
-    ``qkernel`` stays int8, the MoE casts its own). ``dtype=None`` (f32
-    compute) returns ``params`` itself.
+    ``qkernel`` stays int8, the training ``MoEFeedForward`` casts its
+    own). A ``kernel`` may be 2-D or a STACK of expert kernels
+    (``ops/moe.py`` ``ExpertShare``: 3-D, cast where it is used like
+    any other); a leaf already in ``dtype`` is handed on as it is, so a
+    tree stored in the compute dtype costs no second copy.
+    ``dtype=None`` (f32 compute) returns ``params`` itself.
 
     Same numbers, cast ONCE: ``LoRADense``'s ``astype(x.dtype)`` rounds
     the same f32 values to the same ``dtype`` values whether it runs
@@ -942,6 +946,11 @@ def serving_llama_params(params: Any, dtype: Any) -> Any:
         return params
     cast = ("kernel", "lora_a", "lora_b", "qscale")
 
+    def held(leaf: Any) -> Any:
+        # a leaf already in ``dtype`` is the very leaf it was: no copy
+        return leaf if getattr(leaf, "dtype", None) == dtype \
+            else jnp.asarray(leaf, dtype)
+
     def walk(tree: Any) -> Any:
         if not isinstance(tree, dict):
             return tree
@@ -949,9 +958,9 @@ def serving_llama_params(params: Any, dtype: Any) -> Any:
         for name, sub in tree.items():
             if isinstance(sub, dict) and (
                     "qkernel" in sub
-                    or getattr(sub.get("kernel"), "ndim", 0) == 2):
-                out[name] = {kk: (jnp.asarray(vv, dtype) if kk in cast
-                                  else vv) for kk, vv in sub.items()}
+                    or getattr(sub.get("kernel"), "ndim", 0) in (2, 3)):
+                out[name] = {kk: (held(vv) if kk in cast else vv)
+                             for kk, vv in sub.items()}
             else:
                 out[name] = walk(sub)
         return out

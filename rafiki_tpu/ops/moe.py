@@ -175,3 +175,155 @@ def moe_aux_loss(mutated_collections: dict) -> jnp.ndarray:
 
     visit(losses)
     return total
+
+
+# ------------------------------------------------------------ serving
+#: what a serving expert layer counts on the device, in the order of the
+#: int32 vector it sows into the ``"counters"`` collection
+MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
+                "moe_expert_slots", "moe_experts_touched",
+                # of those, in single-token (decode step) calls alone:
+                # what the grouped products of a step stream and compute
+                "moe_step_assignments_held", "moe_step_experts_touched")
+
+
+def book_moe_counters(stats: Any, counts: Any) -> None:
+    """Add one pulled :data:`MOE_COUNTERS` vector to a ``StatsMap``
+    (the engine's ``stats``), each count under its own name — spelled
+    out, not zipped from the tuple: the metric-catalog lint reads the
+    names a program publishes from the literals of its ``inc`` calls."""
+    stats.inc("moe_assignments", int(counts[0]))
+    stats.inc("moe_assignments_held", int(counts[1]))
+    stats.inc("moe_expert_slots", int(counts[2]))
+    stats.inc("moe_experts_touched", int(counts[3]))
+    stats.inc("moe_step_assignments_held", int(counts[4]))
+    stats.inc("moe_step_experts_touched", int(counts[5]))
+
+
+def route_top_k(logits: jnp.ndarray, top_k: int, renormalize: bool = True,
+                scaling: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """``(T, E)`` f32 router logits -> ``(gates, experts)``, both
+    ``(T, k)``: the ``top_k`` largest of ``softmax(logits)`` with their
+    expert ids, the gates divided by their sum (``renormalize``) and
+    multiplied by ``scaling``. No capacity: every choice stands."""
+    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+    gates, experts = jax.lax.top_k(probs, top_k)
+    if renormalize:
+        gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
+    return gates * scaling, experts
+
+
+def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
+                    experts: jnp.ndarray, w_gate: jnp.ndarray,
+                    w_up: jnp.ndarray, w_down: jnp.ndarray,
+                    first: int = 0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The routed part of a SwiGLU expert layer over the experts HELD
+    here, without a dropped token.
+
+    ``x`` (T, d) rows; ``gates`` / ``experts`` (T, k) from
+    :func:`route_top_k` over ALL the router's experts; ``w_*`` the
+    stacked kernels of the ``n`` experts held, ids ``first .. first + n
+    - 1``. The (row, choice) assignments are sorted by expert, those of
+    absent experts last and in no group, and the three products are
+    GROUPED matmuls (``jax.lax.ragged_dot``: on the TPU one Mosaic
+    kernel a product, which computes each group's rows against its own
+    expert's kernel and nothing for rows in no group — no capacity, no
+    one-hot dispatch, no multiplication by zero afterwards).
+
+    Returns ``(y, counts)``: ``y`` (T, d) f32, the sum over each row's
+    HELD choices of ``gate * expert(x)`` (zero for a row with none), and
+    int32 ``[assignments, of them on held experts, experts held, of them
+    with at least one row]`` — the first four of :data:`MOE_COUNTERS`.
+    """
+    t, k = experts.shape
+    n = w_gate.shape[0]
+    local = experts.reshape(t * k) - first
+    held = (local >= 0) & (local < n)
+    group = jnp.where(held, local, n)  # absent experts sort last
+    order = jnp.argsort(group, stable=True)
+    sizes = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
+    xs = jnp.take(x, order // k, axis=0)  # (T k, d), grouped by expert
+    gate = jax.lax.ragged_dot(xs, w_gate.astype(x.dtype), sizes)
+    up = jax.lax.ragged_dot(xs, w_up.astype(x.dtype), sizes)
+    out = jax.lax.ragged_dot(nn.silu(gate) * up, w_down.astype(x.dtype),
+                             sizes)
+    # back to (row, choice) order by a gather (the inverse permutation),
+    # each choice times its gate. Rows past the last group belong to no
+    # expert: whatever the product left there is not read
+    back = out[jnp.argsort(order)].astype(jnp.float32).reshape(t, k, -1)
+    weight = jnp.where(held.reshape(t, k), gates, 0.0)[..., None]
+    y = jnp.sum(jnp.where(weight > 0, back * weight, 0.0), axis=1)
+    counts = jnp.stack([
+        jnp.int32(t * k), jnp.sum(held, dtype=jnp.int32), jnp.int32(n),
+        jnp.sum(sizes > 0, dtype=jnp.int32)])
+    return y, counts
+
+
+class ExpertShare(nn.Module):
+    """One chip's share of a routed SwiGLU expert layer, for serving:
+    the router keeps its published width (``n_experts``) and its
+    ``top_k`` experts a token; the layer is TOLD which experts it holds
+    (``held = (first id, count)``; count 0 = all), routes over all of
+    them and adds up its own experts' part of the result. What the
+    absent experts would have added is left out — there is no exchange
+    here and nothing stands in for one. Dropless (see
+    :func:`grouped_experts`): a row's output does not depend on who
+    shares its batch.
+
+    Parameters: ``router/kernel`` (d, n_experts) and
+    ``experts_{gate,up,down}/kernel`` stacked over the experts HELD.
+    The router's product and softmax run in f32 whatever the compute
+    dtype. Counts land in the ``"counters"`` collection (``moe``), when
+    the caller makes it mutable.
+    """
+
+    n_experts: int
+    top_k: int
+    mlp_dim: int
+    held: Tuple[int, int] = (0, 0)
+    renormalize: bool = True
+    scaling: float = 1.0
+
+    @nn.compact
+    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+        lead, d = x.shape[:-1], x.shape[-1]
+        first, n = self.held if self.held[1] else (0, self.n_experts)
+        if first < 0 or first + n > self.n_experts:
+            raise ValueError(f"experts held {self.held} lie outside the "
+                             f"router's {self.n_experts}")
+        init = nn.initializers.lecun_normal()
+
+        def kernel(name, shape):
+            return KernelLeaf(shape, init, name=name)()
+
+        xf = x.reshape(-1, d)
+        logits = jnp.matmul(
+            xf.astype(jnp.float32),
+            kernel("router", (d, self.n_experts)).astype(jnp.float32),
+            precision=jax.lax.Precision.HIGHEST)
+        gates, experts = route_top_k(logits, self.top_k, self.renormalize,
+                                     self.scaling)
+        y, counts = grouped_experts(
+            xf, gates, experts,
+            kernel("experts_gate", (n, d, self.mlp_dim)),
+            kernel("experts_up", (n, d, self.mlp_dim)),
+            kernel("experts_down", (n, self.mlp_dim, d)), first)
+        step = counts[jnp.array([1, 3])] * int(x.ndim == 3
+                                               and x.shape[1] == 1)
+        self.sow("counters", "moe", jnp.concatenate([counts, step]),
+                 init_fn=lambda: jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
+                 reduce_fn=lambda a, b: a + b)
+        return y.reshape(lead + (d,)).astype(x.dtype)
+
+
+class KernelLeaf(nn.Module):
+    """A bare ``kernel`` leaf under a name of its own, so that the
+    serving form of the weights and the benchmark's draws find stacked
+    expert kernels where they find every other: ``<site>/kernel``."""
+
+    shape: Tuple[int, ...]
+    init: Any
+
+    @nn.compact
+    def __call__(self) -> jnp.ndarray:
+        return self.param("kernel", self.init, self.shape)

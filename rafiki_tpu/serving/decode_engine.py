@@ -52,7 +52,8 @@ import functools
 import threading
 import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional, Tuple
+from typing import (Any, Callable, Dict, List, Optional, Sequence,
+                    Tuple)
 
 import jax
 import jax.numpy as jnp
@@ -78,6 +79,14 @@ SPEC_MIN_TOKENS_PER_CALL = 1.5
 # break-even floor sits higher than free host-side n-gram drafting
 SPEC_MIN_TOKENS_PER_CALL_DRAFT = 2.2
 SPEC_REPROBE_CALLS = 32
+#: rows of a paged engine's prefill programs: a chunk call computes this
+#: many rows x ``prefill_chunk`` tokens, dealt to the lanes that HAVE
+#: prompt left (a long prompt takes several rows: its consecutive
+#: chunks) instead of ``max_slots`` rows of which one or two are real.
+#: What does not fit takes the next call. 8 x 32 rows still fill the
+#: MXU's tiles, and a call's cost is then the weights' streaming, about
+#: a decode step
+PREFILL_LANES = 8
 #: generated-token interval between decode_mark trace spans per slot —
 #: coarse enough to stay off the hot path, fine enough that a stalled
 #: generation shows WHERE it stalled in /debug/requests
@@ -171,6 +180,36 @@ def _serving_tree(module: Any, params: Any) -> Any:
     from ..models.llama_lora import serving_llama_params
 
     return serving_llama_params(params, getattr(module, "dtype", None))
+
+
+def _empty_cache(module: Any, batch: int) -> Any:
+    """``module``'s decode cache for ``batch`` rows, all zeros: the
+    SHAPES come from ``jax.eval_shape`` over ``module.init`` — nothing is
+    drawn, so a module whose f32 weights would not fit the device still
+    gets its cache — and the leaves are allocated as zeros, which is
+    what every cache variable initialises to."""
+    shapes = jax.eval_shape(
+        lambda: module.init(jax.random.PRNGKey(0),
+                            jnp.zeros((batch, 1), jnp.int32),
+                            decode=True)["cache"])
+    return jax.tree_util.tree_map(
+        lambda leaf: jnp.zeros(leaf.shape, leaf.dtype), shapes)
+
+
+def _kernel_mode(module: Any, paged: bool) -> int:
+    """The ``paged_kernel_mode`` gauge: which decode legs walk the
+    block table in a Pallas kernel (0 none, 1 the single-token step, 2
+    windows too). A module that dispatches its own attention says what
+    its step and its windows really take (``paged_kernel_mode()``);
+    otherwise the ``paged_kernel`` flag resolved against the backend,
+    the ops-level rules ``_DecoderAttention`` follows."""
+    own = getattr(module, "paged_kernel_mode", None)
+    if callable(own):
+        return int(own()) if paged else 0
+    flag = getattr(module, "paged_kernel", None)
+    if not (paged and resolve_paged_kernel(flag)):
+        return 0
+    return 2 if resolve_paged_window_kernel(flag) else 1
 
 
 class DecodeEngine:
@@ -304,38 +343,49 @@ class DecodeEngine:
         #: parked slots by a monotonic park key, insertion-ordered
         self._parked: Dict[int, _Parked] = {}
         self._park_seq = 0
-        #: which paged-native Pallas kernels are live on this engine
-        #: (module flag resolved against the backend — the ops-level
-        #: dispatch rules)? ``paged_kernel_active``: the s==1 step
-        #: kernel; ``paged_kernel_windowed``: the multi-token window
-        #: kernel on top (chunked prefill + speculative verify).
-        #: Surfaced as the ``paged_kernel_mode`` gauge (0 = gather /
-        #: contiguous, 1 = step-only, 2 = windowed) so kernel-vs-gather
-        #: fleets — and step-only escape-hatch fleets — are tellable
-        #: apart on /metrics.
-        _pk_flag = getattr(module, "paged_kernel", None)
-        self.paged_kernel_active = bool(
-            self.paged and resolve_paged_kernel(_pk_flag))
-        self.paged_kernel_windowed = bool(
-            self.paged_kernel_active
-            and resolve_paged_window_kernel(_pk_flag))
-        self.paged_kernel_mode = (2 if self.paged_kernel_windowed
-                                  else 1 if self.paged_kernel_active
-                                  else 0)
+        #: which decode legs walk the block table in a Pallas kernel
+        #: (``_kernel_mode``): ``paged_kernel_active``: the s==1 step;
+        #: ``paged_kernel_windowed``: multi-token windows on top (chunked
+        #: prefill + speculative verify). Surfaced as the
+        #: ``paged_kernel_mode`` gauge (0 = gather / contiguous, 1 =
+        #: step-only, 2 = windowed) so kernel-vs-gather fleets — and
+        #: step-only fleets — are tellable apart on /metrics.
+        self.paged_kernel_mode = _kernel_mode(module, self.paged)
+        self.paged_kernel_active = self.paged_kernel_mode >= 1
+        self.paged_kernel_windowed = self.paged_kernel_mode >= 2
         self._ptab = np.zeros((self.B, self._n_table), np.int32)
         self._ptab_dev = jnp.asarray(self._ptab)
         self._ptab_dev_width = self._n_table
         self._ptab_dirty = False
-        self._cache = module.init(
-            jax.random.PRNGKey(0), jnp.zeros((self.B, 1), jnp.int32),
-            decode=True)["cache"]
+        self._cache = _empty_cache(module, self.B)
+        #: int32 counts the module's step and prefill programs hand back
+        #: beside their outputs (``module.device_counters`` names them,
+        #: ``module.book_device_counters`` adds a pulled vector to the
+        #: stats), carried from call to call ON the device and pulled
+        #: with the decode output — never a sync of their own
+        self._count_names = tuple(getattr(module, "device_counters", ()))
+        self._counts_zero = (jnp.zeros((len(self._count_names),),
+                                       jnp.int32)
+                             if self._count_names else None)
+        self._counts_dev = self._counts_zero
         # two compiled step programs: greedy-only traffic must not pay
         # the sampler's (B, vocab) sort per token (measured 18x slower
         # generation on CPU when it rode every step). The host picks per
         # fused call based on the live slots' temperatures.
         self._step_fns = {False: _make_step(module, self.B, self.K, False),
                           True: _make_step(module, self.B, self.K, True)}
-        self._prefill_fn = (_make_prefill(module, self.B, self.C)
+        #: rows a prefill call computes. Paged engines deal
+        #: ``PREFILL_LANES`` rows to the lanes with prompt left (the
+        #: pool is not laid out by slot, and a row whose table is all
+        #: zeros writes to the scratch page); contiguous caches are rows
+        #: BY slot, and a draft model's mirror pass is compiled
+        #: slot-wide: both keep one row a slot (0 = not gathered)
+        self._prefill_lanes = (
+            min(self.B, PREFILL_LANES)
+            if self.paged and not (draft is not None and self.spec_k)
+            else 0)
+        n_rows = self._prefill_lanes or self.B
+        self._prefill_fn = (_make_prefill(module, n_rows, self.C)
                             if self.C > 1 else None)
         #: narrow twin of the prefill program for short remainders: a
         #: 1-token admission walk must not pay a C-wide (B, C) matmul
@@ -344,7 +394,7 @@ class DecodeEngine:
         #: by a step. Walks ≤ this width run the narrow program.
         self._small_c = 4
         self._prefill_fn_small = (
-            _make_prefill(module, self.B, self._small_c)
+            _make_prefill(module, n_rows, self._small_c)
             if self._prefill_fn is not None and self.C > self._small_c
             else None)
         self._verify_fn = (_make_verify(module, self.B, self.spec_k)
@@ -365,9 +415,7 @@ class DecodeEngine:
         self.draft_params = _serving_tree(self.draft_module, draft_params)
         self._draft_cache = None
         if self.draft_module is not None and self.spec_k:
-            self._draft_cache = self.draft_module.init(
-                jax.random.PRNGKey(0), jnp.zeros((self.B, 1), jnp.int32),
-                decode=True)["cache"]
+            self._draft_cache = _empty_cache(self.draft_module, self.B)
             # draft phase: k-1 greedy steps with argmax feedback
             self._draft_scan = _make_step(self.draft_module, self.B,
                                           self.spec_k - 1, False)
@@ -456,6 +504,11 @@ class DecodeEngine:
             # bytes of the tree the step programs take (``params``: the
             # compute-dtype serving form, not the tree it was made from)
             "weight_bytes": 0,
+            # bytes one cached position costs over all layers: the
+            # cache's own leaves over the positions they hold (pool
+            # pages x page size, or slots x max_len)
+            "kv_pool_bytes_per_token": self._pool_bytes_per_token(),
+            **{name: 0 for name in self._count_names},
             # the host's share of the loop, counted where the phase
             # spans are cut (docs/observability.md "Phase spans"):
             # step() calls, nanoseconds inside them outside the output
@@ -482,6 +535,12 @@ class DecodeEngine:
         self._turn_seq = 0
         #: nanoseconds of the open turn spent in ``engine.sync_wait``
         self._turn_sync_ns = 0
+
+    def _pool_bytes_per_token(self) -> int:
+        positions = (self.n_pages * self.page_size if self.paged
+                     else self.B * self.L)
+        return sum(int(x.nbytes) for x in
+                   jax.tree_util.tree_leaves(self._cache)) // positions
 
     @property
     def params(self) -> Any:
@@ -1132,9 +1191,7 @@ class DecodeEngine:
         # scatters it into the hit slots' pages)
         snap_module = (self.module.clone(kv_page_size=0, kv_pages=0)
                        if self.paged else self.module)
-        cache1 = snap_module.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-            decode=True)["cache"]
+        cache1 = _empty_cache(snap_module, 1)
         # one multi-token cache pass over the prefix (same program shape
         # as chunked prefill, batch 1, chunk = len(prefix))
         fill = _make_prefill(snap_module, 1, len(prefix))
@@ -1163,9 +1220,7 @@ class DecodeEngine:
             # snapshot a prefix-hit slot would draft over zero KV for
             # 0..plen-1 (still lossless, but acceptance collapses and
             # the draft's cost is pure waste)
-            d1 = self.draft_module.init(
-                jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-                decode=True)["cache"]
+            d1 = _empty_cache(self.draft_module, 1)
             d_fill = _make_prefill(self.draft_module, 1, plen)
             d_snap = d_fill(self.draft_params, d1,
                             jnp.asarray(prefix[None, :]),
@@ -1238,9 +1293,7 @@ class DecodeEngine:
                 f"(1..{self.L - 2} tokens)")
         snap_module = (self.module.clone(kv_page_size=0, kv_pages=0)
                        if self.paged else self.module)
-        cache1 = snap_module.init(
-            jax.random.PRNGKey(0), jnp.zeros((1, 1), jnp.int32),
-            decode=True)["cache"]
+        cache1 = _empty_cache(snap_module, 1)
         flat, treedef = jax.tree_util.tree_flatten(cache1)
         leaves = [np.asarray(a) for a in blob.get("leaves") or []]
         if len(leaves) != len(flat) or any(
@@ -1271,7 +1324,9 @@ class DecodeEngine:
         what the worker's post-warmup scrub needs."""
         keep = {"paged_kernel_mode": self.paged_kernel_mode,
                 "kv_host_pages_total": self.host_pages,
-                "weight_bytes": self.stats["weight_bytes"]}
+                "weight_bytes": self.stats["weight_bytes"],
+                "kv_pool_bytes_per_token":
+                    self.stats["kv_pool_bytes_per_token"]}
         if self.paged:
             keep.update(kv_pages_total=self.n_pages - 1,
                         kv_pages_used=(self.n_pages - 1
@@ -1364,21 +1419,21 @@ class DecodeEngine:
                 self._res_total = 0
                 self._ptab_dirty = True
                 self.stats.set("kv_pages_used", 0)
-        self._cache = self.module.init(
-            jax.random.PRNGKey(0), jnp.zeros((self.B, 1), jnp.int32),
-            decode=True)["cache"]
+        self._cache = _empty_cache(self.module, self.B)
+        self._counts_dev = self._counts_zero
         if self.draft_module is not None and self.spec_k:
-            self._draft_cache = self.draft_module.init(
-                jax.random.PRNGKey(0), jnp.zeros((self.B, 1), jnp.int32),
-                decode=True)["cache"]
+            self._draft_cache = _empty_cache(self.draft_module, self.B)
 
     def _chunked_prefill(self) -> int:
         """Ingest admitted prompts C tokens per compiled call before they
         join the decode scan (positions 0..plen−2; the scan then starts
         at the LAST prompt token, whose step emits the first generated
-        token). Slots not prefilling re-feed their current input — an
-        identical rewrite of a cache entry, harmless by construction —
-        so one fixed-shape program serves any admission mix. Returns
+        token). A paged engine's call deals ``PREFILL_LANES`` rows to
+        the lanes that have prompt left, its spare rows writing to the
+        scratch page; elsewhere every slot is a row, and slots
+        not prefilling re-feed their current input — an identical
+        rewrite of a cache entry, harmless by construction. Either way
+        one fixed-shape program serves any admission mix. Returns
         the number of calls made; each is one ``engine.prefill_prep``
         span (its operands) and one ``engine.prefill_dispatch`` (the
         launch, then the call's counters and position advance)."""
@@ -1393,9 +1448,14 @@ class DecodeEngine:
             fill_fn, adv, tok_dev, pos_dev, aid_dev, ptab = prep
             calls += 1
             with SPANS.span("engine.prefill_dispatch"):
-                self._cache = fill_fn(
-                    self.params, self._cache, tok_dev, pos_dev, aid_dev,
-                    ptab)
+                if self._counts_dev is None:
+                    self._cache = fill_fn(
+                        self.params, self._cache, tok_dev, pos_dev,
+                        aid_dev, ptab)
+                else:
+                    self._cache, self._counts_dev = fill_fn(
+                        self.params, self._cache, tok_dev, pos_dev,
+                        aid_dev, ptab, self._counts_dev)
                 if self._draft_cache is not None and self._draft_synced:
                     # keep the draft's KV in lockstep with the prompt
                     # walk (while desynced, resync rebuilds prompts
@@ -1408,20 +1468,26 @@ class DecodeEngine:
 
     def _prefill_operands(self, occupied: np.ndarray) -> Optional[Tuple]:
         """One chunk call's program, advance and device operands, or
-        None when no occupied lane has prompt left to ingest."""
+        None when no occupied lane has prompt left to ingest. On a
+        paged engine the call's rows are GATHERED (:meth:`_gathered_rows`);
+        elsewhere one row a slot."""
         rem = np.where(occupied,
                        np.maximum(0, (self._prompt_len - 1)
                                   - self._pos), 0)
         if rem.max() == 0:
             return None
+        lanes = (np.flatnonzero(rem)[:self._prefill_lanes]
+                 if self._prefill_lanes else np.arange(self.B))
         fill_fn, c_use = self._prefill_fn, self.C
         if (self._prefill_fn_small is not None
                 and self._draft_cache is None
-                and rem.max() <= self._small_c):
+                and rem[lanes].max() <= self._small_c):
             # short remainder: the narrow program ingests it
             # without the C-wide call's cost (the draft mirror is
             # compiled at C only, so draft engines stay wide)
             fill_fn, c_use = self._prefill_fn_small, self._small_c
+        if self._prefill_lanes:
+            return (fill_fn,) + self._gathered_rows(rem, lanes, c_use)
         adv = np.minimum(rem, c_use)
         tok_chunk = np.empty((self.B, c_use), np.int32)
         pos_chunk = np.empty((self.B, c_use), np.int32)
@@ -1438,21 +1504,65 @@ class DecodeEngine:
             else:
                 tok_chunk[i, :] = self._tok[i]
                 pos_chunk[i, :] = self._pos[i]
-        if self.paged:
-            # lazy allocation tracks the prompt walk: each chunk
-            # only maps the pages it is about to write. The slot
-            # re-check matters on tiered engines: an earlier
-            # lane's growth may have PARKED this one (page
-            # reclaim) inside this very loop — its row is zeroed
-            # and its pos reset, so ensuring pages here would
-            # allocate for an empty lane and leak them
-            for i in range(self.B):
-                if adv[i] > 0 and self._slots[i] is not None:
-                    self._ensure_pages_to(
-                        i, int(self._pos[i]) + int(adv[i]) - 1)
+        self._map_prefill_pages(adv)
         return (fill_fn, adv, jnp.asarray(tok_chunk),
                 jnp.asarray(pos_chunk), jnp.asarray(self._aid),
                 self._ptab_arg())
+
+    def _gathered_rows(self, rem: np.ndarray, lanes: np.ndarray,
+                       c_use: int) -> Tuple:
+        """A paged call's ``_prefill_lanes`` rows of ``c_use`` tokens,
+        dealt to ``lanes`` (all with prompt left) in order: a lane takes
+        a row for every ``c_use`` tokens it has left while rows remain,
+        so ONE long prompt fills the call with its consecutive chunks —
+        every layer writes the whole call's rows to the pool before any
+        row attends, and a query sees the keys at or before its own
+        position, whichever row wrote them. Rows left over carry token
+        0 at position 0 through an all-zero table row: the scratch page.
+        Returns ``(adv, tokens, positions, adapter ids, table rows)``."""
+        n_rows = self._prefill_lanes
+        adv = np.zeros((self.B,), rem.dtype)
+        tok_chunk = np.zeros((n_rows, c_use), np.int32)
+        pos_chunk = np.zeros((n_rows, c_use), np.int32)
+        row_lane = []
+        for i in lanes:
+            left, p0 = int(rem[i]), int(self._pos[i])
+            while left > 0 and len(row_lane) < n_rows:
+                row, a = len(row_lane), min(left, c_use)
+                tok_chunk[row, :a] = self._prompt_buf[i, p0:p0 + a]
+                pos_chunk[row, :a] = np.arange(p0, p0 + a)
+                # pad by repeating the row's last real entry —
+                # rewrites a just-written cache slot identically
+                tok_chunk[row, a:] = tok_chunk[row, a - 1]
+                pos_chunk[row, a:] = pos_chunk[row, a - 1]
+                row_lane.append(i)
+                adv[i] += a
+                left, p0 = left - a, p0 + a
+        self._map_prefill_pages(adv)
+        # the rows' table rows at the engine's live width (the widths
+        # the step program meets, so no shape of its own), read AFTER
+        # the allocation: a lane parked by it has a zeroed row
+        n = len(row_lane)
+        aid = np.zeros((n_rows,), np.int32)
+        aid[:n] = self._aid[row_lane]
+        ptab = np.zeros((n_rows, self._live_table_width()), np.int32)
+        ptab[:n] = self._ptab[row_lane, :ptab.shape[1]]
+        return (adv, jnp.asarray(tok_chunk), jnp.asarray(pos_chunk),
+                jnp.asarray(aid), jnp.asarray(ptab))
+
+    def _map_prefill_pages(self, adv: np.ndarray) -> None:
+        """Lazy allocation tracks the prompt walk: each chunk call only
+        maps the pages it is about to write. The slot re-check matters
+        on tiered engines: an earlier lane's growth may have PARKED this
+        one (page reclaim) inside this very loop — its row is zeroed and
+        its pos reset, so ensuring pages here would allocate for an
+        empty lane and leak them."""
+        if not self.paged:
+            return
+        for i in np.flatnonzero(adv):
+            if self._slots[i] is not None:
+                self._ensure_pages_to(
+                    i, int(self._pos[i]) + int(adv[i]) - 1)
 
     def _book_prefill(self, adv: np.ndarray) -> None:
         """Counters and the host-side position advance of one chunk
@@ -1732,15 +1842,21 @@ class DecodeEngine:
         if path == "spec":
             return self._speculative_step(live)
         with SPANS.span("engine.decode_dispatch"):
-            self._cache, emitted = self._step_fns[any_sampling](*operands)
+            self._cache, emitted, *counts = \
+                self._step_fns[any_sampling](*operands)
             # the launch's operand handles die with the launch, as the
             # temporaries they are (freeing eleven device buffers takes
             # ~0.1 ms: inside a span, not in the turn's own time)
             del call, operands
         # the loop's OUTPUT sync: generated tokens must reach the host
-        # to stream; the fused K-step scan amortizes it
-        emitted, = self._sync_wait(emitted)
+        # to stream; the fused K-step scan amortizes it. The module's
+        # device counters, where it has any, come with them: one small
+        # vector that was ready when the tokens were
+        emitted, *counts = self._sync_wait(emitted, *counts)
         with SPANS.span("engine.harvest"):
+            if counts:  # the module books them under its own names
+                self.module.book_device_counters(self.stats, counts[0])
+                self._counts_dev = self._counts_zero
             self._harvest_scan(live, emitted, any_sampling)
         return len(live)
 
@@ -1919,7 +2035,8 @@ class DecodeEngine:
             jnp.asarray(self._prompt_len), jnp.asarray(self._stop_pos),
             jnp.asarray(self._temp), jnp.asarray(self._topk),
             jnp.asarray(self._topp), jnp.asarray(self._seed),
-            jnp.asarray(self._aid), self._ptab_arg())
+            jnp.asarray(self._aid), self._ptab_arg(),
+            *(() if self._counts_dev is None else (self._counts_dev,)))
 
     def _harvest_scan(self, live: List[int], emitted: np.ndarray,
                       any_sampling: bool) -> None:
@@ -2022,9 +2139,7 @@ class DecodeEngine:
         re-probe follows a gated-off stretch during which scan mirrors
         were skipped — a bounded number of K-chunk passes instead of a
         mirror on every gated scan."""
-        self._draft_cache = self.draft_module.init(
-            jax.random.PRNGKey(0), jnp.zeros((self.B, 1), jnp.int32),
-            decode=True)["cache"]
+        self._draft_cache = _empty_cache(self.draft_module, self.B)
         ctxs = {}
         maxp = 0
         for i in range(self.B):
@@ -2293,6 +2408,22 @@ def _select_next(logits, temp, top_k, top_p, seed, pos):
     return jnp.where(temp <= 0.0, greedy, sampled)
 
 
+def _mutable_collections(module: Any) -> List[str]:
+    """What a decode-path ``apply`` lets the module write: its cache,
+    and — for a module that names ``device_counters`` — the
+    ``"counters"`` collection its layers sow their counts into."""
+    return ["cache", "counters"] if getattr(
+        module, "device_counters", ()) else ["cache"]
+
+
+def _add_counts(counts: Sequence[jnp.ndarray], muts: Any
+                ) -> List[jnp.ndarray]:
+    """The running device counters (one int32 vector, or none) plus
+    what this ``apply`` sowed, summed over layers."""
+    sown = jax.tree_util.tree_leaves(muts.get("counters", {}))
+    return [c + sum(sown) for c in counts]
+
+
 @functools.lru_cache(maxsize=8)
 def _make_step(module: Any, n_slots: int, k: int,
                sampling: bool) -> Callable:
@@ -2310,22 +2441,27 @@ def _make_step(module: Any, n_slots: int, k: int,
     Multi-adapter modules additionally consume the per-slot ``aid``
     operand (which stacked fine-tune each row decodes under); paged-KV
     modules the per-slot ``ptab`` page tables (a tiny ignored constant
-    otherwise — one signature for both layouts)."""
+    otherwise — one signature for both layouts). A module with
+    ``device_counters`` takes their running int32 vector as one more
+    operand and returns it, its K steps' counts added, as one more
+    output (see :func:`_add_counts`)."""
     multi = int(getattr(module, "n_adapters", 0) or 0) > 0
     paged = int(getattr(module, "kv_page_size", 0) or 0) > 0
+    mutable = _mutable_collections(module)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def step_fn(params, cache, tok, pos, prompt_buf, prompt_len, stop_pos,
-                temp, top_k, top_p, seed, aid, ptab):
+                temp, top_k, top_p, seed, aid, ptab, *counts):
         rows = jnp.arange(n_slots)
 
         def body(carry, _):
-            cache, tok, pos = carry
+            cache, tok, pos, *counts = carry
             logits, muts = module.apply(
                 {"params": params, "cache": cache}, tok[:, None],
-                positions=pos[:, None], decode=True, mutable=["cache"],
+                positions=pos[:, None], decode=True, mutable=mutable,
                 **({"adapter_ids": aid} if multi else {}),
                 **({"page_tables": ptab} if paged else {}))
+            counts = _add_counts(counts, muts)
             lg = logits[:, -1].astype(jnp.float32)
             if sampling:
                 nxt = _select_next(lg, temp, top_k, top_p, seed, pos)
@@ -2339,11 +2475,11 @@ def _make_step(module: Any, n_slots: int, k: int,
             active = new_pos < stop_pos
             tok2 = jnp.where(active, nxt_input, tok)
             pos2 = jnp.where(active, new_pos, pos)
-            return (muts["cache"], tok2, pos2), nxt
+            return (muts["cache"], tok2, pos2, *counts), nxt
 
-        (cache, tok, pos), emitted = jax.lax.scan(
-            body, (cache, tok, pos), None, length=k)
-        return cache, emitted  # (K, n_slots)
+        (cache, tok, pos, *counts), emitted = jax.lax.scan(
+            body, (cache, tok, pos, *counts), None, length=k)
+        return (cache, emitted, *counts)  # emitted: (K, n_slots)
 
     return step_fn
 
@@ -2435,13 +2571,21 @@ def _make_prefill(module: Any, n_slots: int, chunk: int) -> Callable:
     multi = int(getattr(module, "n_adapters", 0) or 0) > 0
     paged = int(getattr(module, "kv_page_size", 0) or 0) > 0
 
+    mutable = _mutable_collections(module)
+
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_fn(params, cache, tok_chunk, pos_chunk, aid, ptab):
+    def prefill_fn(params, cache, tok_chunk, pos_chunk, aid, ptab,
+                   *counts):
         _, muts = module.apply(
             {"params": params, "cache": cache}, tok_chunk,
-            positions=pos_chunk, decode=True, mutable=["cache"],
+            positions=pos_chunk, decode=True, mutable=mutable,
             **({"adapter_ids": aid} if multi else {}),
             **({"page_tables": ptab} if paged else {}))
+        # ``counts`` is the *args tuple: its length is static under jit.
+        # None handed in (a prefix snapshot, a draft's mirror pass, a
+        # module that names no counters): the cache alone
+        if counts:  # rafiki: noqa[jax-tracer-branch] — a tuple's length
+            return (muts["cache"], *_add_counts(counts, muts))
         return muts["cache"]
 
     return prefill_fn

@@ -627,7 +627,12 @@ def test_worker_disagg_token_exact(trained, unified_reference):
     """The full wire path — predictor places the prefill leg, prefill
     worker ships pages over the hub, decode worker installs — streams
     the exact unified outputs, with zero fallbacks."""
-    dec, pre = _run_disagg(trained, unified_reference)
+    # "zero fallbacks" holds only while the decode side's wait outlasts
+    # the prefill worker's FIRST compile: 3 s does not under the loaded
+    # six-worker suite (one stream of three fell back: `assert 2 == 3`
+    # on kv_imports_installed, PR 31's whole run). The wait ends when
+    # the shipment arrives, so a long one costs nothing
+    dec, pre = _run_disagg(trained, unified_reference, kv_wait_s=30.0)
     assert pre.stats["kv_ships_sent"] == len(PROMPTS)
     assert dec.stats["kv_imports_installed"] == len(PROMPTS)
     assert dec.stats["kv_wait_timeouts"] == 0

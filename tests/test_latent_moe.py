@@ -1,0 +1,299 @@
+"""The latent-attention / routed-expert decoder
+(``rafiki_tpu.models.latent_moe``, ``ops/latent_attention.py``, the serving
+expert layer of ``ops/moe.py``): tiny sizes, seeded weights, the CPU. The
+plain reference is the benchmark's (``benchmark/reference/latent_moe.py``:
+float32, ``highest``, no cache, keys and values expanded, nothing of the
+program imported). Serving it through ``DecodeEngine`` is
+``test_latent_moe_serving.py``.
+
+- the module's full forward equals the reference's logits;
+- attending in latent space through the cache (chunked prefill, then
+  single-token steps, on the Pallas step kernel and on the page gather)
+  equals expand-then-attend; the step kernel equals the gather;
+- YaRN frequencies, interleaved rotary pairs, the position-dependent query
+  scale and the softmax scale against their formulas;
+- the shares add up: four ``experts_held`` shares of one layer, the shared
+  expert counted once, equal the uncut reference layer;
+- no dropped token: a row's output is the same alone and in a skewed
+  batch; rows of absent experts are left out; the gates' rule.
+"""
+
+import math
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from benchmark import harness
+from benchmark.drivers import serve_latent_moe as driver
+from benchmark.reference import latent_moe as ref
+from rafiki_tpu.models.latent_moe import (LatentMoEBlock,
+                                          position_query_scale,
+                                          rope_interleaved, yarn_inv_freq)
+from rafiki_tpu.ops import moe
+from rafiki_tpu.ops.latent_attention import (latent_decode_attention,
+                                             latent_gather_attention)
+from rafiki_tpu.serving import decode_engine
+
+YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 128,
+        "llama_4_scaling_beta": 0.1, "mscale": 1, "mscale_all_dim": 1,
+        "original_max_position_embeddings": 64, "rope_theta": 10000,
+        "rope_type": "yarn", "type": "yarn"}
+
+
+def tiny_cfg(**over):
+    cfg = harness.load_json("configs", "tiny-latent-moe.json")
+    cfg.update(over)
+    return cfg
+
+
+def weights(cfg, seed=3):
+    module = driver.build_module(cfg)
+    return module, driver.make_weights(cfg, driver.abstract_params(module),
+                                       seed)
+
+
+# ------------------------------------------------- model against reference
+def test_module_full_forward_equals_reference_logits():
+    cfg = tiny_cfg()
+    module, params = weights(cfg)
+    ids = np.random.default_rng(1).integers(0, cfg["vocab_size"], size=40)
+    got = module.apply({"params": params}, jnp.asarray(ids[None]))[0]
+    want = ref.forward(params, jnp.asarray(ids), cfg)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("kernel", [True, False], ids=["kernel", "gather"])
+def test_latent_space_decode_equals_expand_then_attend(kernel):
+    """Chunked prefill then single-token steps through the latent pool
+    (absorbed up-projection) against ONE cacheless call that expands keys
+    and values: the same logits at every position."""
+    cfg = tiny_cfg()
+    cfg["engine"]["paged_kernel"] = kernel
+    module, params = weights(cfg, seed=5)
+    b, t, page = 2, 21, module.kv_page_size
+    ids = np.random.default_rng(2).integers(0, cfg["vocab_size"],
+                                            size=(b, t)).astype(np.int32)
+    want = module.apply({"params": params}, jnp.asarray(ids))
+    cache = decode_engine._empty_cache(module, b)
+    tabs = jnp.asarray(1 + np.arange(b * 4).reshape(b, 4), jnp.int32)
+    got = []
+
+    @jax.jit
+    def call(cache, ids, pos):
+        return module.apply(
+            {"params": params, "cache": cache}, ids, positions=pos,
+            decode=True, page_tables=tabs, mutable=["cache"])
+
+    for lo, hi in ((0, 8), (8, 16)) + tuple((i, i + 1)
+                                            for i in range(16, t)):
+        pos = jnp.broadcast_to(jnp.arange(lo, hi), (b, hi - lo))
+        logits, muts = call(cache, jnp.asarray(ids[:, lo:hi]), pos)
+        cache = muts["cache"]
+        got.append(logits)
+    assert page * 4 >= t
+    np.testing.assert_allclose(np.asarray(jnp.concatenate(got, 1)),
+                               np.asarray(want), atol=2e-5, rtol=1e-5)
+
+
+@pytest.mark.parametrize("pages_per_step", [None, 1, 2, 8])
+def test_latent_step_kernel_equals_gather(pages_per_step):
+    rng = np.random.default_rng(0)
+    b, heads, r, dr, page, n_tables, n_pages = 3, 4, 16, 8, 4, 8, 40
+    pool = jnp.asarray(rng.normal(size=(n_pages, page, r + dr)),
+                       jnp.float32)
+    tabs = jnp.asarray(rng.permutation(np.arange(1, n_pages))[
+        :b * n_tables].reshape(b, n_tables), jnp.int32)
+    t = jnp.asarray([0, 13, 31], jnp.int32)
+    q = jnp.asarray(rng.normal(size=(b, heads, r + dr)), jnp.float32) * .3
+    got = latent_decode_attention(q, pool, tabs, t, rank=r,
+                                  pages_per_step=pages_per_step,
+                                  interpret=True)
+    rows = pool[tabs].reshape(b, n_tables * page, r + dr)
+    want = latent_gather_attention(q[:, None], rows, t[:, None], r)[:, 0]
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               atol=1e-5)
+
+
+# ----------------------------------------------------- rotary formulas
+def test_yarn_frequencies_against_the_formula():
+    dim, theta, factor, orig = 64, 1e4, 128.0, 8192
+    got = yarn_inv_freq(dim, theta, factor, orig, 32.0, 1.0)
+    plain = theta ** (-np.arange(0, dim, 2) / dim)
+    # correction dims, by hand: dim ln(orig / (turns 2 pi)) / (2 ln theta)
+    low = math.floor(dim * math.log(orig / (32 * 2 * math.pi))
+                     / (2 * math.log(theta)))
+    high = math.ceil(dim * math.log(orig / (1 * 2 * math.pi))
+                     / (2 * math.log(theta)))
+    assert (low, high) == (12, 25)
+    np.testing.assert_allclose(got[:low + 1], plain[:low + 1], rtol=1e-6)
+    np.testing.assert_allclose(got[high:], plain[high:] / factor,
+                               rtol=1e-6)
+    mid = 18  # inside the ramp: a blend by (18 - 12) / (25 - 12)
+    w = (mid - low) / (high - low)
+    np.testing.assert_allclose(
+        got[mid], plain[mid] / factor * w + plain[mid] * (1 - w),
+        rtol=1e-6)
+    # the reference computes its own, from the configuration's keys
+    rp = dict(YARN, original_max_position_embeddings=orig)
+    np.testing.assert_allclose(ref.yarn_inv_freq(rp, dim), got, rtol=1e-6)
+
+
+def test_rotary_turns_interleaved_pairs():
+    inv = np.asarray([0.5, 0.25], np.float32)
+    x = jnp.asarray([[[1.0, 0.0, 0.0, 2.0]]])  # pairs (1, 0) and (0, 2)
+    got = np.asarray(rope_interleaved(x, jnp.asarray([[3]]), inv))[0, 0]
+    a, b = 3 * 0.5, 3 * 0.25
+    want = [math.cos(a), math.sin(a), -2 * math.sin(b), 2 * math.cos(b)]
+    np.testing.assert_allclose(got, want, atol=1e-6)
+    # per head alike: (b, s, heads, dim) takes the same angles
+    xh = jnp.broadcast_to(x[:, :, None, :], (1, 1, 3, 4))
+    np.testing.assert_allclose(
+        np.asarray(rope_interleaved(xh, jnp.asarray([[3]]), inv))[0, 0, 2],
+        want, atol=1e-6)
+
+
+def test_query_scale_is_the_identity_below_the_original_context():
+    pos = jnp.asarray([[0, 1, 2047, 8191, 8192, 16383, 16384]])
+    got = np.asarray(position_query_scale(pos, 0.1, 8192))[0]
+    np.testing.assert_array_equal(got[:4], np.ones(4, np.float32))
+    np.testing.assert_allclose(got[4:], [1 + 0.1 * math.log(2)] * 2
+                               + [1 + 0.1 * math.log(3)], rtol=1e-6)
+    cfg = {"rope_parameters": dict(
+        YARN, original_max_position_embeddings=8192)}
+    np.testing.assert_allclose(np.asarray(ref.query_scale(cfg, pos[0])),
+                               got, rtol=1e-6)
+
+
+def test_softmax_scale_carries_mscale_all_dim_squared():
+    cfg = {"qk_head_dim": 128, "rope_parameters": dict(
+        YARN, original_max_position_embeddings=8192)}
+    rot, s = ref.attention_scales(cfg)
+    m = 0.1 * math.log(128) + 1
+    assert rot == 1.0 and abs(m - 1.4852) < 1e-4
+    assert abs(s - 128 ** -0.5 * m * m) < 1e-9
+
+
+# ------------------------------------------------- the chip's share
+def _layer_weights(n_held, n_router=8, seed=11):
+    cfg = tiny_cfg(n_routed_experts=n_held, num_hidden_layers=1)
+    cfg["published"] = {"n_routed_experts": n_router}
+    cfg["deployment"] = {"experts_held_first": 0}
+    return cfg, weights(cfg, seed)[1]["block_0"]
+
+
+def test_the_shares_add_up_to_the_uncut_layer():
+    """Four chips, two experts each, of one 8-expert layer: what the
+    shares add to the residual stream, the shared expert counted once,
+    is what the uncut layer adds — in the reference, and the program's
+    share equals the reference's share."""
+    cfg, full = _layer_weights(8)
+    x = jnp.asarray(np.random.default_rng(4).normal(size=(24, 64)),
+                    jnp.float32)
+    pos = jnp.arange(24)
+    uncut = ref.layer(full, x, pos, cfg, held=None)
+    nothing_routed = ref.layer(
+        {**full, "moe": {**full["moe"], **{
+            k: {"kernel": full["moe"][k]["kernel"][:0]}
+            for k in ("experts_gate", "experts_up", "experts_down")}}},
+        x, pos, cfg, held=(0, 0), shared=False)  # x + attention
+    total = nothing_routed
+    for chip in range(4):
+        first = 2 * chip
+        share = {**full, "moe": {**full["moe"], **{
+            k: {"kernel": full["moe"][k]["kernel"][first:first + 2]}
+            for k in ("experts_gate", "experts_up", "experts_down")}}}
+        part = ref.layer(share, x, pos, cfg, held=(first, 2),
+                         shared=chip == 0)
+        total = total + (part - nothing_routed)
+        # the program's layer, told the same share
+        mine = dict(cfg, n_routed_experts=2)
+        mine["deployment"] = {"experts_held_first": first}
+        got = _apply_block(driver.build_module(mine), share, x)
+        want = ref.layer(share, x, pos, cfg, held=(first, 2))
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   atol=2e-5)
+    np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),
+                               atol=2e-5)
+
+
+def _apply_block(module, block_params, x):
+    """One decoder layer of the program on hidden rows ``x`` (t, d)."""
+    block = LatentMoEBlock(*module.block_fields(), module.shared_dim,
+                           module.eps)
+    return block.apply({"params": block_params}, x[None],
+                       jnp.arange(x.shape[0])[None], False)[0]
+
+
+def test_no_dropped_token_in_a_skewed_batch():
+    """63 rows that all choose the same two experts beside one row of
+    another kind: that row's output is what it is alone (a capacity
+    would have dropped most of the 63, and the order of rows would
+    matter)."""
+    rng = np.random.default_rng(9)
+    d, f, n = 16, 24, 4
+    w = [jnp.asarray(rng.normal(size=s) / 4, jnp.float32)
+         for s in ((n, d, f), (n, d, f), (n, f, d))]
+    hot = rng.normal(size=(1, d))
+    x = jnp.asarray(np.concatenate([np.repeat(hot, 63, 0),
+                                    rng.normal(size=(1, d))]), jnp.float32)
+    experts = jnp.asarray([[0, 1]] * 63 + [[1, 3]], jnp.int32)
+    gates = jnp.asarray([[0.7, 0.3]] * 63 + [[0.4, 0.6]], jnp.float32)
+    full, counts = moe.grouped_experts(x, gates, experts, *w)
+    alone, _ = moe.grouped_experts(x[-1:], gates[-1:], experts[-1:], *w)
+    first, _ = moe.grouped_experts(x[:1], gates[:1], experts[:1], *w)
+    np.testing.assert_allclose(np.asarray(full[-1]), np.asarray(alone[0]),
+                               atol=1e-5)
+    np.testing.assert_allclose(np.asarray(full[:63]),
+                               np.repeat(np.asarray(first), 63, 0),
+                               atol=1e-5)
+    # by hand, for the lone row: 0.4 E1(x) + 0.6 E3(x)
+    def expert(e, row):
+        return (jax.nn.silu(row @ w[0][e]) * (row @ w[1][e])) @ w[2][e]
+    np.testing.assert_allclose(
+        np.asarray(full[-1]),
+        np.asarray(0.4 * expert(1, x[-1]) + 0.6 * expert(3, x[-1])),
+        atol=1e-5)
+    assert [int(c) for c in counts] == [128, 128, 4, 3]
+
+
+def test_rows_of_absent_experts_are_left_out_not_zeroed():
+    """Held experts 2-3 of 8: a row that chose 5 and 2 gets expert 2's
+    part alone; the counts say how many assignments were held."""
+    rng = np.random.default_rng(10)
+    d, f = 8, 12
+    w = [jnp.asarray(rng.normal(size=s) / 3, jnp.float32)
+         for s in ((2, d, f), (2, d, f), (2, f, d))]
+    x = jnp.asarray(rng.normal(size=(3, d)), jnp.float32)
+    experts = jnp.asarray([[5, 2], [0, 7], [3, 2]], jnp.int32)
+    gates = jnp.asarray([[0.5, 0.5], [0.9, 0.1], [0.2, 0.8]], jnp.float32)
+    y, counts = moe.grouped_experts(x, gates, experts, *w, first=2)
+
+    def expert(e, row):
+        return (jax.nn.silu(row @ w[0][e]) * (row @ w[1][e])) @ w[2][e]
+    np.testing.assert_allclose(np.asarray(y[0]),
+                               np.asarray(0.5 * expert(0, x[0])), atol=1e-5)
+    np.testing.assert_array_equal(np.asarray(y[1]), np.zeros(d))
+    np.testing.assert_allclose(
+        np.asarray(y[2]),
+        np.asarray(0.2 * expert(1, x[2]) + 0.8 * expert(0, x[2])),
+        atol=1e-5)
+    assert [int(c) for c in counts] == [6, 3, 2, 2]
+
+
+def test_gates_are_the_renormalised_top_k_of_a_softmax():
+    logits = jnp.asarray([[2.0, 0.0, 1.0, -1.0, 3.0]])
+    gates, experts = moe.route_top_k(logits, 2)
+    assert [int(e) for e in experts[0]] == [4, 0]
+    # renormalised top-k probabilities = a softmax over the chosen logits
+    np.testing.assert_allclose(np.asarray(gates[0]),
+                               np.asarray(jax.nn.softmax(
+                                   jnp.asarray([3.0, 2.0]))), rtol=1e-6)
+    raw, _ = moe.route_top_k(logits, 2, renormalize=False, scaling=2.0)
+    np.testing.assert_allclose(
+        np.asarray(raw[0]),
+        2.0 * np.asarray(jax.nn.softmax(logits[0]))[[4, 0]], rtol=1e-6)
+
+

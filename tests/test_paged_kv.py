@@ -198,6 +198,62 @@ def test_submit_rejects_request_larger_than_pool(trained):
         eng.submit("big", np.arange(1, 20, dtype=np.int32), 12)
 
 
+@pytest.mark.parametrize("lanes,kw", [(2, {}), (3, {"prefill_chunk": 4}),
+                                      (2, {"speculate_k": 3})])
+def test_prefill_gathers_the_lanes_with_prompt_left(trained, monkeypatch,
+                                                    lanes, kw):
+    """A paged engine's prefill call computes ``PREFILL_LANES`` rows —
+    the lanes that have prompt left, the others taking the next call —
+    not one row a slot: six requests admitted at once into six slots
+    with fewer rows than that are token-exact with the contiguous
+    engine (one row a slot), in more calls, and leave no page behind."""
+    from rafiki_tpu.serving import decode_engine
+
+    monkeypatch.setattr(decode_engine, "PREFILL_LANES", lanes)
+    reqs = _mixed_reqs(6, seed=3)
+    contig = DecodeEngine(trained._module(), trained._params,
+                          max_slots=6, max_len=L, **kw)
+    paged = DecodeEngine(
+        trained._module(kv_page_size=PS, kv_pages=25),
+        trained._params, max_slots=6, max_len=L, **kw)
+    assert (contig._prefill_lanes, paged._prefill_lanes) == (0, lanes)
+    assert _drain(paged, reqs) == _drain(contig, reqs)
+    assert paged.stats["prefill_tokens"] == contig.stats["prefill_tokens"]
+    assert paged.stats["prefill_calls"] > contig.stats["prefill_calls"]
+    assert paged.stats["kv_pages_used"] == 0
+    # an engine narrower than the rows has a row a slot
+    small = DecodeEngine(
+        trained._module(kv_page_size=PS, kv_pages=9),
+        trained._params, max_slots=2, max_len=L, **kw)
+    assert small._prefill_lanes == 2
+
+
+def test_one_long_prompt_fills_a_prefill_call_with_its_chunks(trained):
+    """A lane takes a row for every chunk it has left: a 27-token prompt
+    alone on a paged engine is ONE call of 7 rows x 4 tokens (26 tokens:
+    the last is the scan's), where the contiguous engine, a row a slot,
+    makes 7 — token-exact, because every layer writes the call's rows to
+    the pool before any row attends. Two such prompts are 14 rows: the
+    first takes 7 of a call's 8, the second the eighth and the next
+    call."""
+    prompt = np.arange(3, 30, dtype=np.int32)
+    reqs = [("long", prompt, 4)]
+    kw = dict(max_slots=8, max_len=L, prefill_chunk=4)
+    contig = DecodeEngine(trained._module(), trained._params, **kw)
+    paged = DecodeEngine(trained._module(kv_page_size=PS, kv_pages=33),
+                         trained._params, **kw)
+    assert _drain(paged, reqs) == _drain(contig, reqs)
+    assert paged.stats["prefill_tokens"] == 26
+    assert (contig.stats["prefill_calls"], paged.stats["prefill_calls"]
+            ) == (7, 1)
+    two = [("a", prompt, 4), ("b", prompt[::-1].copy(), 3)]
+    contig.reset_stats()
+    paged.reset_stats()
+    assert _drain(paged, two) == _drain(contig, two)
+    assert paged.stats["prefill_tokens"] == 52
+    assert paged.stats["prefill_calls"] == 2  # 7 + 1 rows, then 6
+
+
 def test_lazy_allocation_tracks_positions(trained):
     """Pages are allocated as positions cross boundaries — mid-flight a
     long-generation slot holds fewer pages than its reservation — and

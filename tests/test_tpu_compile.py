@@ -172,3 +172,42 @@ def test_patch_embed_fwd_bwd_compiles(spec):
         spec((64, 224, 224, 3), jnp.bfloat16),
         spec((16 * 16 * 3, 768), jnp.bfloat16), spec((768,), jnp.bfloat16))
     assert "tpu_custom_call" in text
+
+
+@pytest.mark.parametrize("page,n_tables", [(32, 64), (32, 1), (16, 128),
+                                           (16, 4)])
+def test_latent_step_kernel_compiles(spec, page, n_tables):
+    """The latent pool's single-token step at the widths the benchmark
+    serves: 64 slots, 32 heads, rows of 256 latent + 64 rotary-key
+    values (not a multiple of 128 lanes), a chunk of 256 rows a grid
+    step fetched as 8 or 16 pages side by side — and the narrowest
+    table slice the engine passes."""
+    from rafiki_tpu.ops.latent_attention import latent_decode_attention
+
+    def step(q, pool, tabs, t):
+        return latent_decode_attention(q, pool, tabs, t, rank=256,
+                                       interpret=False)
+
+    text = _compiled_text(
+        step, spec((64, 32, 320), jnp.bfloat16),
+        spec((1 + 64 * 2048 // page, page, 320), jnp.bfloat16),
+        spec((64, n_tables), jnp.int32), spec((64,), jnp.int32))
+    assert "latent_attn_step" in text and "tpu_custom_call" in text
+
+
+def test_grouped_experts_compile_to_grouped_kernels(spec):
+    """The serving expert layer's three products at the benchmark's
+    sizes (a decode step's 256 sorted assignments over 32 held experts
+    of 4096 x 2048): ``jax.lax.ragged_dot`` lowers to the TPU's grouped
+    matmul kernels, not to a dense product over every expert."""
+    from rafiki_tpu.ops.moe import grouped_experts
+
+    def layer(x, gates, experts, wg, wu, wd):
+        return grouped_experts(x, gates, experts, wg, wu, wd, first=0)
+
+    text = _compiled_text(
+        layer, spec((64, 4096), jnp.bfloat16), spec((64, 4), jnp.float32),
+        spec((64, 4), jnp.int32), spec((32, 4096, 2048), jnp.bfloat16),
+        spec((32, 4096, 2048), jnp.bfloat16),
+        spec((32, 2048, 4096), jnp.bfloat16))
+    assert text.count("ragged-dot") >= 3
