@@ -6,42 +6,64 @@ a slot's pages back into logical ``(b, max_len, heads, dh)`` order
 before the masked softmax, re-materializing the whole logical KV per
 generated token. This kernel consumes the pool **directly**:
 
-- **Grid over (batch, kv-head tile, pages).** Each program reads ONE
-  ``(page_size, block_h, dh)`` K/V block straight out of the pool — the
-  block table rides in as a scalar-prefetch operand and the BlockSpec
-  index map does the table walk (``tabs[b, page]``), so the page gather
-  never materializes in HBM.
-- **LSE-merged partial softmax.** Per page the program computes a
+- **Grid over (batch, kv-head tile, blocks of pages).** A grid step
+  consumes a BLOCK of a slot's pool pages — ``BLOCK_KEYS`` key
+  positions, fewer where many wide heads would not fit VMEM
+  (``BLOCK_BYTES``), or the whole table where it is narrower — so a
+  step moves hundreds of KB and the kernel's time follows the live KV
+  bytes, not a count of pages. Pages of a slot are not contiguous in
+  the pool, so a block is fetched page by page off the
+  scalar-prefetched block table; the page gather never materializes
+  in HBM.
+- **The kernel copies its own pages** where heads fill the 128 lanes
+  (``_copies_own_pages``): the pools stay in HBM and a step starts one
+  DMA a LIVE page for the NEXT live block while it computes this one
+  (two VMEM buffers, toggled across grid steps). Narrower heads, and
+  an int8 pool's scale rows, leave the fetch to the BlockSpec
+  pipeline, the pool handed to the call once a page of the block with
+  an index map of its own (``tabs[b, block * pages + j]``) — legal at
+  every shape, at a bookkeeping cost for every page of the table.
+- **Every kv head in one product.** A page's ``(page_size, heads, dh)``
+  block is read as the 2-D matrix ``(page_size * heads, dh)`` — rows
+  are key x head in the pool's own order, no per-head slice — and all
+  of a tile's query rows meet a block's rows in ONE ``QK^T`` and ONE
+  ``PV`` product. The columns of other kv heads are masked with the
+  dead keys, so their probabilities are exactly 0 and heads never mix;
+  the MXU, idle in a bandwidth-bound kernel, pays the extra flops.
+- **LSE-merged partial softmax.** Per block the program computes a
   partial (max, sum, weighted-V accumulator) and folds it into running
   f32 state in VMEM scratch — the same online-softmax recurrence
   ``_attn_fwd_kernel`` streams key blocks with, here streamed across
-  grid steps (TPU grids execute sequentially per core; the page axis is
-  minor, so a (batch, head-tile) row sees its pages back to back and
-  the final page step writes the normalized output).
+  grid steps (TPU grids execute sequentially per core; the block axis
+  is minor, so a (batch, head-tile) row sees its blocks back to back
+  and the last block's step writes the normalized output).
 - **Live pages only.** A slot at position ``t`` owns ``t // page_size
-  + 1`` live pages; later grid steps map their block index to pool
-  page 0 (the engine's scratch page — dead table entries already point
-  there) and skip compute via ``pl.when``. Consecutive same-index
-  fetches are elided by the pipeline, so per-step HBM traffic scales
-  with LIVE tokens, not ``max_len``.
+  + 1`` live pages. Blocks past them skip their compute via
+  ``pl.when`` and fetch nothing; in a partly live block the kernel's
+  own copies skip the dead pages, and the pipeline's index maps
+  collapse them onto pool page 0 (the engine's scratch page — dead
+  table entries already point there), whose repeated fetch is elided.
+  Per-step HBM traffic scales with LIVE tokens, not ``max_len``.
 - **Fused int8-KV dequant.** Quantized pools pass their f32 absmax
   scale rows (same pool geometry, same table walk); the kernel
-  dequantizes each page block in registers — the scale multiply fuses
-  into the f32 attention math and no dequantized cache ever exists.
-- **GQA without the repeat.** Queries arrive grouped per kv head
-  (``rep = n_heads / n_kv_heads`` query rows share one K/V page
-  block), so the ``jnp.repeat`` the gather path pays per step never
-  happens. ``block_h`` tiles kv heads per program exactly like
+  dequantizes each page in registers before the product — the scale
+  multiply fuses into the f32 attention math and no dequantized cache
+  ever exists.
+- **GQA without the repeat.** Query rows arrive grouped per kv head
+  tile (``rep = n_heads / n_kv_heads`` rows a kv head), so the
+  ``jnp.repeat`` the gather path pays per step never happens.
+  ``block_h`` tiles kv heads per program exactly like
   ``flash_attention``'s head tiling — but defaulting to the WHOLE kv
   axis, the one tile Mosaic accepts at every head count (see
   ``_resolve_block_h``).
 
-The single-token step above was PR 10; ``paged_window_attention``
-generalizes it to an (s >= 1) **query window** so chunked prefill and
-speculative-verify calls run paged-native too. The grid gains a
-query-tile dimension (``block_q`` window rows per program), the same
-block-table walk and LSE-merge recurrence stream across pages per query
-tile, and the causal mask becomes per ROW: window token i at absolute
+``paged_window_attention`` is the (s >= 1) **query window** form, so
+chunked prefill and speculative-verify calls run paged-native too. Its
+grid is (batch, kv-head tile, query tile, pages): ``block_q`` window
+rows per program, ONE ``(page_size, block_h, dh)`` K/V block a grid
+step through a BlockSpec whose index map walks the block table, a
+static unroll over the tile's heads, the same LSE-merge recurrence
+streamed across pages per query tile, and a causal mask per ROW: window token i at absolute
 position ``positions[b, i]`` sees keys ``k_pos <= positions[b, i]``
 (the s==1 "last token sees everything" rule is the degenerate case).
 Window positions must be NONDECREASING along each row — exactly what
@@ -224,61 +246,178 @@ def _head_scale(s_ref, head):
     return jnp.sum(jnp.where(lane == head, scales, 0.0), -1, keepdims=True)
 
 
-def _paged_decode_kernel(t_ref, tab_ref, q_ref, k_ref, v_ref, *rest,
+#: key positions one grid step of the step kernel spans (a BLOCK of
+#: pool pages): wide enough that a step moves hundreds of KB and feeds
+#: the MXU whole tiles, narrow enough that a half-dead block wastes
+#: little — the kernel's time follows the live KV bytes, not a count
+#: of pages
+BLOCK_KEYS = 256
+#: bytes a block of ONE pool may take in VMEM: K and V, each
+#: double-buffered, and the products' temporaries beside them stay
+#: inside the 16 MiB Mosaic gives a kernel (many wide heads take a
+#: shorter block)
+BLOCK_BYTES = 2 << 20
+
+
+def _pages_per_block(page_size: int, n_tables: int, page_bytes: int) -> int:
+    """Pool pages one grid step of the step kernel consumes: a block of
+    about ``BLOCK_KEYS`` key positions that fits ``BLOCK_BYTES``, and a
+    table narrower than that is ONE block — all read off the shapes the
+    call is made with."""
+    return max(1, min(n_tables, BLOCK_KEYS // page_size,
+                      BLOCK_BYTES // page_bytes))
+
+
+def _copies_own_pages(dh: int) -> bool:
+    """Whether the step kernel fetches K/V pages with its own DMAs (the
+    pools stay in HBM) or leaves them to the BlockSpec pipeline, one
+    operand a page. Its own copies cost a descriptor a LIVE page; the
+    pipeline's bookkeeping costs twice that for every page of the table,
+    live or dead — but Mosaic slices an HBM ref only where its minor dim
+    fills the 128 lanes, so narrower heads keep the pipeline."""
+    return dh % 128 == 0
+
+
+def _tile_scales(s_ref, h0, block_h: int):
+    """The (page_size, block_h) dequant scales of this program's head
+    tile: the whole block when the tile is the kv axis (``h0`` static
+    0), one lane-masked column a head otherwise (``_head_scale``)."""
+    if isinstance(h0, int):
+        return s_ref[0]
+    return jnp.concatenate(
+        [_head_scale(s_ref, h0 + hh) for hh in range(block_h)], axis=1)
+
+
+def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
                          sm_scale: float, page_size: int, block_h: int,
-                         n_tables: int, quantized: bool):
+                         rep: int, pages: int, n_blocks: int,
+                         quantized: bool, own_copies: bool):
     from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
 
+    # operands: K and V (own copies: the two pools in HBM; else a block
+    # ref a page), then the scale blocks of an int8 pool, a page each
+    n_kv_refs = 1 if own_copies else pages
+    k_refs, v_refs = rest[:n_kv_refs], rest[n_kv_refs:2 * n_kv_refs]
+    rest = rest[2 * n_kv_refs:]
+    ks_refs = vs_refs = (None,) * pages
     if quantized:
-        ks_ref, vs_ref, o_ref, m_scr, l_scr, acc_scr = rest
-    else:
-        ks_ref = vs_ref = None
-        o_ref, m_scr, l_scr, acc_scr = rest
-    bi = pl.program_id(0)
-    h0 = _tile_first_head(block_h, ks_ref)
-    pg = pl.program_id(2)
+        ks_refs, vs_refs, rest = (rest[:pages], rest[pages:2 * pages],
+                                  rest[2 * pages:])
+    o_ref, m_scr, l_scr, acc_scr, *copy_scr = rest
+    bi, kh, blk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    h0 = _tile_first_head(block_h, ks_refs[0])
     t = t_ref[bi]  # this slot's query position (keys k_pos <= t live)
-    n_live = t // page_size + 1
+    span = pages * page_size  # key positions a block covers
 
-    @pl.when(pg == 0)
+    if own_copies:
+        k_buf, v_buf, sems, slot_scr = copy_scr
+        n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
+
+        def block_copies(b_, kh_, blk_, slot, act: str):
+            """``start`` (or ``wait`` for) the copies of a block's LIVE
+            pages into buffer ``slot``: K and V of every page up to the
+            slot's last live one, each a (page_size, block_h, dh) DMA
+            straight out of the pool by the block table."""
+            first = blk_ * pages
+            n_live = jnp.clip(t_ref[b_] // page_size + 1 - first, 0, pages)
+
+            def page(j, carry):
+                entry = tab_ref[b_, first + j]
+                for pool, buf, sem in ((k_refs[0], k_buf, sems.at[slot, 0]),
+                                       (v_refs[0], v_buf, sems.at[slot, 1])):
+                    getattr(pltpu.make_async_copy(
+                        pool.at[entry, :, pl.ds(kh_ * block_h, block_h), :],
+                        buf.at[slot, j], sem), act)()
+                return carry
+
+            jax.lax.fori_loop(0, n_live, page, 0)
+
+        @pl.when((bi == 0) & (kh == 0) & (blk == 0))
+        def _prime():  # nothing fetched the call's first block yet.
+            # A partly live block's unfetched pages meet probabilities
+            # of exactly 0: what the buffers hold there must be finite
+            k_buf[...] = jnp.zeros_like(k_buf)
+            v_buf[...] = jnp.zeros_like(v_buf)
+            slot_scr[0] = 0
+            block_copies(bi, kh, blk, 0, "start")
+
+    @pl.when(blk == 0)
     def _init():  # fresh (batch, head-tile) row: reset the running state
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(pg < n_live)
-    def _partial():  # dead pages: no compute (their fetch was elided by
-        # the index map collapsing them onto the scratch page)
-        k_pos = pg * page_size + jax.lax.broadcasted_iota(
-            jnp.int32, (1, page_size), 1)
-        mask = k_pos <= t  # (1, page_size): masks the last live page's
-        # dead tail AND any speculative-overwrite rows above t
-        for hh in range(block_h):  # static unroll over the head tile
-            q = q_ref[0, hh].astype(jnp.float32) * sm_scale  # (rep, dh)
-            k = k_ref[0, :, hh, :].astype(jnp.float32)  # (page_size, dh)
-            v = v_ref[0, :, hh, :].astype(jnp.float32)
-            if quantized:  # dequant in registers, fused into the math
-                k = k * _head_scale(ks_ref, h0 + hh)
-                v = v * _head_scale(vs_ref, h0 + hh)
-            s = jax.lax.dot_general(
-                q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (rep, page_size)
-            s = jnp.where(mask, s, NEG_INF)
+    @pl.when(blk * span <= t)
+    def _partial():  # dead blocks: no compute, and nothing fetched
+        # (own copies skip them; the pipeline's index maps collapse
+        # their pages onto the scratch page and elide the fetch)
+        n_q, dh = q_ref.shape[-2:]
+        rows = page_size * block_h
+        if own_copies:
+            slot = slot_scr[0]
+            # double buffering across grid steps: start the NEXT live
+            # block's copies (this row's next block, else block 0 of the
+            # next row, which is always live), then wait for this one's
+            more = (blk + 1) * span <= t
+            row_end = kh == n_tiles - 1
+            nxt = (jnp.where(more | ~row_end, bi, bi + 1),
+                   jnp.where(more, kh, jnp.where(row_end, 0, kh + 1)),
+                   jnp.where(more, blk + 1, 0))
 
-            m_prev = m_scr[hh]  # (rep, 1) running max
-            m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
-            alpha = jnp.exp(m_prev - m_new)
-            p = jnp.exp(s - m_new)
-            l_scr[hh] = l_scr[hh] * alpha + jnp.sum(p, -1, keepdims=True)
-            acc_scr[hh] = acc_scr[hh] * alpha + jax.lax.dot_general(
-                p, v, (((1,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (rep, dh)
-            m_scr[hh] = m_new
+            @pl.when(nxt[0] < n_slots)
+            def _prefetch():
+                block_copies(*nxt, 1 - slot, "start")
 
-    @pl.when(pg == n_tables - 1)
+            block_copies(bi, kh, blk, slot, "wait")
+            slot_scr[0] = 1 - slot
+            k_pages = [k_buf.at[slot, j] for j in range(pages)]
+            v_pages = [v_buf.at[slot, j] for j in range(pages)]
+        else:
+            k_pages = [ref.at[0] for ref in k_refs]
+            v_pages = [ref.at[0] for ref in v_refs]
+
+        def block(page_refs, scale_refs):
+            # the block's pages as ONE 2-D matrix, row = key x head in
+            # the pool's own order: (pages * page_size * block_h, dh)
+            mats = []
+            for ref, s_ref in zip(page_refs, scale_refs):
+                page = ref[...]  # (page_size, block_h, dh)
+                if quantized:  # dequant in registers, before the product
+                    page = page.astype(jnp.float32) * _tile_scales(
+                        s_ref, h0, block_h)[:, :, None]
+                mats.append(page.reshape(rows, dh))
+            return jnp.concatenate(mats, axis=0)
+
+        k, v = block(k_pages, ks_refs), block(v_pages, vs_refs)
+        # every query row against every (key, head) row in ONE product;
+        # the columns of other kv heads are masked with the dead keys,
+        # so their probabilities are exactly 0 and heads never mix
+        s = jax.lax.dot_general(
+            q_ref[0, 0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32) * sm_scale  # (n_q, cols)
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, pages * rows), 1)
+        row = jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
+        # masks the dead pages of a partly live block, the last live
+        # page's tail AND any speculative-overwrite rows above t
+        live = (blk * span + col // block_h <= t) & (
+            col % block_h == row // rep)
+        s = jnp.where(live, s, NEG_INF)
+
+        m_prev = m_scr[...]  # (n_q, 1) running max
+        m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
+        alpha = jnp.exp(m_prev - m_new)
+        p = jnp.exp(s - m_new)
+        l_scr[...] = l_scr[...] * alpha + jnp.sum(p, -1, keepdims=True)
+        acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
+            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32)  # (n_q, dh)
+        m_scr[...] = m_new
+
+    @pl.when(blk == n_blocks - 1)
     def _finish():  # position 0 is always live, so l > 0 on every row
-        o_ref[0] = (acc_scr[...] /
-                    jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+        o_ref[0, 0] = (acc_scr[...] /
+                       jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
@@ -287,7 +426,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
                            block_h: Optional[int] = None,
                            interpret: Optional[bool] = None
                            ) -> jnp.ndarray:
-    """Single-token decode attention straight off a paged KV pool.
+    """Single-token decode attention straight off a paged KV pool: a
+    grid step takes a BLOCK of a slot's pool pages (``BLOCK_KEYS`` key
+    positions; a narrower table is one block) over every kv head of its
+    tile, in one masked ``QK^T`` and one ``PV`` product.
 
     - ``q``: (b, n_heads, dh) — this step's query vector per slot.
     - ``k_pool``/``v_pool``: (n_pages, page_size, n_kv_heads, dh), the
@@ -301,6 +443,12 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
       live-width slice).
     - ``positions``: (b,) int32 query positions; keys ``k_pos <=
       positions[i]`` are visible to slot i (the decode-branch mask).
+      Held to ``[0, n_tables * page_size)``.
+
+    The block (``_pages_per_block``) and who fetches its pages
+    (``_copies_own_pages``) follow from the shapes of the call. Products
+    run on the pool's own float type (int8 pools: dequantised to f32)
+    with f32 accumulation and f32 softmax state.
 
     Returns (b, n_heads, dh) in ``q``'s dtype. GQA queries are grouped
     per kv head internally (``jnp.repeat`` convention: q head h ↔ kv
@@ -322,56 +470,88 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         raise ValueError("k_scale and v_scale must be passed together")
     interpret = _resolve_interpret(interpret)
 
-    qh = q.reshape(b, n_kv, rep, dh)
-    t = jnp.asarray(positions, jnp.int32)
+    n_tiles, n_q = n_kv // block_h, block_h * rep
+    # a page of one pool in VMEM; an int8 page is priced at the bf16
+    # it would be: its f32 dequantised copy is the larger tenant
+    pages = _pages_per_block(
+        page_size, n_tables,
+        page_size * block_h * dh * max(k_pool.dtype.itemsize, 2))
+    n_blocks = -(-n_tables // pages)
+    own_copies = _copies_own_pages(dh)
+    # GQA query rows grouped per kv head tile: q head h <-> kv head
+    # h // rep, so a tile's rows are contiguous
+    qh = q.reshape(b, n_tiles, n_q, dh)
+    # position 0 is always live and no position lies past the table:
+    # held here, so that the kernel's own copies always pair a start
+    # with a wait whatever the caller passes
+    t = jnp.clip(jnp.asarray(positions, jnp.int32), 0,
+                 n_tables * page_size - 1)
     tabs = jnp.asarray(page_tables, jnp.int32)
 
-    def q_map(bi, kh, pg, t_ref, tab_ref):
+    def q_map(bi, kh, blk, t_ref, tab_ref):
         return (bi, kh, 0, 0)
 
-    def kv_map(bi, kh, pg, t_ref, tab_ref):
-        # the block-table walk: live pages come from the table, dead
-        # ones collapse onto pool page 0 so consecutive dead steps
-        # re-use one (skipped-compute) fetch instead of streaming
-        # garbage — per-step traffic scales with live tokens
-        live = pg <= t_ref[bi] // page_size
-        return (jnp.where(live, tab_ref[bi, pg], 0), 0, kh, 0)
+    def page_specs(block_shape):
+        """The BlockSpec pipeline's fetch of a block: pages of a slot
+        are not contiguous in the pool, so the pool is handed to the
+        call ``pages`` times, every copy with an index map of its own
+        that walks the block table, and a block's pages stream side by
+        side. Live pages come from the table; dead ones (past the
+        slot's last live page, or past the table in a ragged last
+        block) collapse onto pool page 0, so consecutive dead steps
+        re-use one fetch instead of streaming garbage. K/V blocks
+        (4-D) take their head tile, scale blocks the WHOLE kv axis
+        (``_tile_first_head``)."""
+        def spec(j):
+            def index(bi, kh, blk, t_ref, tab_ref):
+                pg = blk * pages + j
+                live = pg <= t_ref[bi] // page_size
+                page = jnp.where(
+                    live, tab_ref[bi, jnp.minimum(pg, n_tables - 1)], 0)
+                return ((page, 0, kh, 0) if len(block_shape) == 4
+                        else (page, 0, 0))
+            return pl.BlockSpec(block_shape, index)
+        return [spec(j) for j in range(pages)]
 
-    def sc_map(bi, kh, pg, t_ref, tab_ref):
-        # whole kv axis per block: see ``_tile_first_head``
-        live = pg <= t_ref[bi] // page_size
-        return (jnp.where(live, tab_ref[bi, pg], 0), 0, 0)
-
-    in_specs = [
-        pl.BlockSpec((1, block_h, rep, dh), q_map),
-        pl.BlockSpec((1, page_size, block_h, dh), kv_map),
-        pl.BlockSpec((1, page_size, block_h, dh), kv_map),
+    scratch_shapes = [
+        pltpu.VMEM((n_q, 1), jnp.float32),   # running max
+        pltpu.VMEM((n_q, 1), jnp.float32),   # running sum
+        pltpu.VMEM((n_q, dh), jnp.float32),  # weighted V
     ]
-    operands = [qh, k_pool, v_pool]
+    in_specs = [pl.BlockSpec((1, 1, n_q, dh), q_map)]
+    operands = [qh]
+    if own_copies:
+        in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
+        operands += [k_pool, v_pool]
+        scratch_shapes += [
+            # a block of K and of V pages, double-buffered
+            pltpu.VMEM((2, pages, page_size, block_h, dh), k_pool.dtype),
+            pltpu.VMEM((2, pages, page_size, block_h, dh), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2, 2)),  # (buffer, K or V)
+            pltpu.SMEM((1,), jnp.int32),      # the buffer in use
+        ]
+    else:
+        in_specs += 2 * page_specs((1, page_size, block_h, dh))
+        operands += [k_pool] * pages + [v_pool] * pages
     if quantized:
-        in_specs += [pl.BlockSpec((1, page_size, n_kv), sc_map),
-                     pl.BlockSpec((1, page_size, n_kv), sc_map)]
-        operands += [k_scale, v_scale]
+        in_specs += 2 * page_specs((1, page_size, n_kv))
+        operands += [k_scale] * pages + [v_scale] * pages
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_kv // block_h, n_tables),
+        grid=(b, n_tiles, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, block_h, rep, dh), q_map),
-        scratch_shapes=[
-            pltpu.VMEM((block_h, rep, 1), jnp.float32),   # running max
-            pltpu.VMEM((block_h, rep, 1), jnp.float32),   # running sum
-            pltpu.VMEM((block_h, rep, dh), jnp.float32),  # weighted V
-        ],
+        out_specs=pl.BlockSpec((1, 1, n_q, dh), q_map),
+        scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=float(sm_scale),
-        page_size=page_size, block_h=block_h, n_tables=n_tables,
-        quantized=quantized)
+        page_size=page_size, block_h=block_h, rep=rep, pages=pages,
+        n_blocks=n_blocks, quantized=quantized, own_copies=own_copies)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_kv, rep, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((b, n_tiles, n_q, dh), q.dtype),
         interpret=interpret,
         name="paged_attn_step",  # what a profile calls the kernel
     )
@@ -486,9 +666,11 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
 
     Returns (b, s, n_heads, dh) in ``q``'s dtype. ``block_q`` tiles the
     window (must divide s; default: largest divisor <= 16), ``block_h``
-    tiles kv heads as in the step kernel. With s == 1 this computes
-    bit-for-bit the same output as ``paged_decode_attention`` — same op
-    shapes, same order — which the property tests pin.
+    tiles kv heads as in the step kernel. With s == 1 this is the same
+    attention as ``paged_decode_attention`` in another summation order
+    (a page and a head at a time, where the step kernel takes a block of
+    pages over every head of its tile): the two agree to f32 roundoff,
+    which the property tests pin.
     """
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu

@@ -81,10 +81,20 @@ def _pool(spec, n_kv, dh, int8):
     return kv, scales
 
 
+#: (heads, slots, table width) of the step kernel's calls: a small
+#: engine at half a block of pages in every head geometry, then the
+#: dense benchmark cell's 32 slots at its narrowest table (one block, a
+#: single page wide) and at its widest (several blocks of pages), and
+#: 64 kv heads of 128, whose block VMEM bounds below 256 key positions
+STEP_CALLS = [(h, 4, 8) for h in HEADS] + [(HEADS[0], 32, 1),
+                                           (HEADS[0], 32, 32),
+                                           ((64, 64, 128), 4, 32)]
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
-@pytest.mark.parametrize("n_heads,n_kv,dh", HEADS)
-def test_paged_decode_kernel_compiles(spec, n_heads, n_kv, dh, int8):
-    b, n_tables = 4, 8
+@pytest.mark.parametrize("heads,b,n_tables", STEP_CALLS)
+def test_paged_decode_kernel_compiles(spec, heads, b, n_tables, int8):
+    n_heads, n_kv, dh = heads
     kv, scales = _pool(spec, n_kv, dh, int8)
 
     def step(q, k, v, tabs, t, *sc):
