@@ -4,7 +4,7 @@ Fault tolerance that is only exercised by real outages is untested
 code. This package injects the failure modes the request path claims to
 survive — worker death mid-stream, dropped replies, delayed queues,
 corrupted payloads — deterministically (seeded RNG, token-count
-triggers), so tier-1 tests and the ``bench_extra failover`` stage can
+triggers), so tier-1 tests (``tests/test_chaos.py``) can
 drive every branch of the breaker/failover/drain machinery on demand.
 
 Three pieces:
@@ -65,8 +65,8 @@ class ChaosConfig:
     - ``kill_admin_after_s``: the ADMIN process SIGKILLs itself this
       many seconds after arming (:func:`arm_admin_kill` in the admin
       entrypoint) — the deterministic "control plane dies mid-load"
-      drill behind the crash-recovery tests and the
-      ``bench_extra admin_recovery`` stage. SIGKILL on purpose: no
+      drill behind the crash-recovery tests
+      (``tests/test_admin_recovery.py``). SIGKILL on purpose: no
       graceful-shutdown path may run, exactly like an OOM-kill or a
       host reboot.
     - ``delay_kv_transfer_s``: every KV page shipment push (prefill →
@@ -81,8 +81,8 @@ class ChaosConfig:
     - ``kill_kvd_after_s``: SIGKILL the kvd DATA-PLANE process this
       many seconds after arming (:func:`arm_kvd_kill` — the admin
       holds the kvd's pid) — the deterministic "data plane dies
-      mid-load" drill behind the WAL-replay/respawn machinery and the
-      ``bench_extra kvd_recovery`` stage. SIGKILL on purpose: the
+      mid-load" drill behind the WAL-replay/respawn machinery
+      (``tests/test_hub_reconnect.py``). SIGKILL on purpose: the
       graceful-shutdown fsync must NOT run; recovery has to come from
       the WAL alone.
     - ``drop_hub_conn_p``: each hub RPC first force-closes the calling

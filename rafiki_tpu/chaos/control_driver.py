@@ -14,8 +14,8 @@ adopts the first driver's survivors and reports what it found.
 
 Run: ``python -m rafiki_tpu.chaos.control_driver --config cfg.json``
 with ``{workdir, db_path, n_services, ready_file,
-mode: "boot"|"reconcile", lease_ttl_s}``. Used by
-``bench_extra.py admin_recovery`` and the slow-tier recovery e2e test.
+mode: "boot"|"reconcile", lease_ttl_s}``. Used by the slow-tier
+recovery e2e test (``tests/test_admin_recovery.py``).
 """
 
 from __future__ import annotations

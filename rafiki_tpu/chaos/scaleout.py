@@ -1,30 +1,30 @@
 """Deterministic scale-out drill harness: stub engines, real plumbing.
 
-Proving "N workers ≥ ~N× one worker at equal TTFT" with real LM engines
-on the CPU-fallback rig is impossible — every in-process replica shares
-one host CPU, so aggregate throughput is flat no matter how the router
-spreads the streams. What the scale-out machinery actually needs proved
-is *placement*: streams spread across the pool, shared prefixes
-colocate, membership events (scale-up, drain-based scale-down, rolling
-restart) never drop or duplicate a token. Those are properties of the
-predictor/router/worker-loop plumbing, not of matmul throughput.
+A CPU test cannot say how fast N workers are: every in-process replica
+shares one host CPU, and a rate belongs to the chip (``benchmark/``).
+What it can prove of the scale-out machinery is *placement*: streams
+spread across the pool, shared prefixes colocate, membership events
+(scale-up, drain-based scale-down, rolling restart) never drop or
+duplicate a token. Those are properties of the
+predictor/router/worker-loop plumbing, not of any model's speed.
 
 So the drill runs the REAL stack — :class:`InferenceWorker` serve
 loops, the queue hub, the predictor's router/breaker/failover machinery
 — over a **stub decode engine with an explicit capacity model**: each
 engine step serves every live slot and costs
 ``base_step_s + per_req_step_s × live`` wall seconds (launch overhead +
-per-request service time), so one worker's token throughput saturates
-at ``1/per_req_step_s`` and capacity genuinely scales with engines, the
-way separate accelerators do. Token text is a deterministic function of
+per-request service time), so one stub serves at most
+``1/per_req_step_s`` tokens a second and more stubs serve more — a
+fake's sleep model that gives the router something to spread, not a
+measurement of anything. Token text is a deterministic function of
 (prompt, index), so any drop, duplication, or mis-resumed failover is a
 hard string mismatch — the zero-token-loss proof needs no reference
 run.
 
-Used by ``tests/test_scaleout.py`` (tier-1 acceptance) and the
-``bench_extra.py scaleout`` stage; results carry explicit
-simulated-capacity provenance — they measure the routing/scaling plane,
-never the kernels.
+Used by ``tests/test_scaleout.py`` (tier-1 acceptance) as a fake. The
+``tokens_per_s`` a drill returns is the stubs' sleep model read back:
+the test compares it between pool sizes to see that placement spread
+the streams; it is no statement about speed.
 """
 
 from __future__ import annotations

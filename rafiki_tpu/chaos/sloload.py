@@ -1,9 +1,9 @@
 """Deterministic mixed-traffic overload harness: SLO drills on stubs.
 
 The SLO story's acceptance property — "interactive p95 holds within
-1.5× its unloaded value while best-effort throughput fills the
-troughs" — is a property of the ADMISSION POLICY (class queues, aging,
-preemption, shedding, brownout), not of matmul throughput, so like the
+1.5× its unloaded value while best-effort work fills the troughs" — is
+a property of the ADMISSION POLICY (class queues, aging, preemption,
+shedding, brownout), not of any model's speed, so like the
 scale-out drills it runs on the :mod:`rafiki_tpu.chaos.scaleout`
 capacity-model stack: REAL :class:`InferenceWorker` serve loops, the
 real predictor (shed gate + brownout ladder), and a stub decode engine
@@ -23,9 +23,9 @@ run. (Per-mode token-exactness of the REAL engine's preempt-resume is
 tier-1 in ``tests/test_slo.py``; this harness proves the fleet-level
 latency/shed/starvation properties.)
 
-Used by ``tests/test_slo.py`` (tier-1 acceptance drill) and the
-``bench_extra.py slo_overload`` stage; results carry explicit
-simulated-capacity provenance.
+Used by ``tests/test_slo.py`` (tier-1 acceptance drill) as a fake: the
+latencies and ``tokens_per_s`` it returns are the stubs' sleep model
+read back, compared between policies, never quoted as speed.
 """
 
 from __future__ import annotations

@@ -59,7 +59,7 @@ LANES = 128
 XLA_SHORT_SEQ = int(os.environ.get("RAFIKI_XLA_SHORT_SEQ", "256"))
 # Fleet-applicable default for flash_attention's block_h (multi-head-
 # per-program forward): callers that don't pass block_h explicitly pick
-# this up, so a hardware sweep win (scripts/tune_attention_tpu.py) can
+# this up, so a sweep won on the chip (none has run: ROADMAP D7) can
 # be applied to every template without code edits — e.g.
 # RAFIKI_ATTN_BLOCK_H=4 flips ViT/BERT onto the mh kernels (and, per
 # the dispatch rule below, off the short-seq XLA route). Default 1 =
@@ -577,8 +577,8 @@ def flash_attention(q, k, v, sm_scale: Optional[float] = None,
     ``XLA_SHORT_SEQ`` route covers, an explicit ``block_h>1``
     DISABLES the short-seq XLA route (on TPU) rather than being
     silently dropped by it. The backward keeps the per-head kernels
-    (its grids are fewer and larger). Sweep on hardware with
-    ``scripts/tune_attention_tpu.py``.
+    (its grids are fewer and larger). Never swept on the chip
+    (ROADMAP S5, D7).
 
     Dispatch: with ``interpret=None`` (the default used by every model
     template) the Pallas kernels run only on a real TPU backend AND at

@@ -230,8 +230,7 @@ class DecodeEngine:
                  max_len: int, steps_per_sync: int = 4,
                  prefill_chunk: int = 32, speculate_k: int = 0,
                  draft: Optional[Tuple[Any, Any]] = None,
-                 host_kv_pages: int = 0,
-                 prefill_token_cost_s: float = 0.0) -> None:
+                 host_kv_pages: int = 0) -> None:
         self.module = module
         self.B = int(max_slots)
         self.L = int(max_len)
@@ -263,15 +262,6 @@ class DecodeEngine:
         #: turns B (1, d)-matvec steps into (C, d) matmuls the MXU can
         #: tile, and pays 1/C as many dispatches for prompt ingestion.
         self.C = max(1, min(int(prefill_chunk), self.L))
-        #: modeled prompt-compute floor (seconds per prompt token),
-        #: slept on the loop thread after each prefill chunk. For
-        #: benches/tests on hosts where the model under test is so
-        #: small that prompt ingestion is ~free (tiny-model cpu
-        #: fallback): production prompt forwards cost real wall time,
-        #: and the prefill/decode interleave this engine schedules is
-        #: invisible without it. 0 (the default) costs nothing.
-        self.prefill_token_cost_s = max(0.0,
-                                        float(prefill_token_cost_s))
         self._slots: List[Optional[_Slot]] = [None] * self.B
         #: class-aware admission queue (interactive > batch >
         #: background, FIFO within class, aging so background never
@@ -1574,11 +1564,6 @@ class DecodeEngine:
             # kernel (the chunk call is an s=C window)
             self.stats.inc("paged_kernel_window_tokens",
                            int(adv.sum()))
-        if self.prefill_token_cost_s:
-            # outside the engine lock (step releases it before
-            # prefill) so a dilated chunk stalls exactly what real
-            # prompt compute would: this loop thread, nothing else
-            time.sleep(self.prefill_token_cost_s * int(adv.sum()))
         for i in range(self.B):
             if adv[i] > 0 and self._slots[i] is not None:
                 # a lane parked mid-chunk (page reclaim) skips the

@@ -48,6 +48,14 @@ def _drain(eng, reqs, submit_kw=None):
     raise AssertionError(f"undrained: {sorted(done)} / {eng.stats}")
 
 
+def _nbytes(cache):
+    """Measured bytes of an engine's decode cache."""
+    import jax
+
+    return sum(int(np.prod(v.shape)) * v.dtype.itemsize
+               for v in jax.tree_util.tree_leaves(cache))
+
+
 def _pair(trained, reqs, pages, engine_kw=None, submit_kw=None,
           module_kw=None, params=None):
     """(contiguous outputs, paged outputs, paged engine) on identical
@@ -105,8 +113,6 @@ def test_paged_int8_kv_parity_and_pool_bytes(trained):
     """int8 KV pages identically (int8 pools + f32 scale pools): exact
     parity within the quantized world, and the paged pool's measured
     bytes sit well under the contiguous int8 cache's."""
-    import jax
-
     m8 = LlamaLoRA(**{**KNOBS, "kv_cache_int8": True})
     m8._params = trained._params
     reqs = _mixed_reqs(6, seed=2)
@@ -116,12 +122,25 @@ def test_paged_int8_kv_parity_and_pool_bytes(trained):
                          m8._params, max_slots=4, max_len=L)
     assert _drain(contig, reqs) == _drain(paged, reqs)
 
-    def nbytes(c):
-        return sum(int(np.prod(v.shape)) * v.dtype.itemsize
-                   for v in jax.tree_util.tree_leaves(c))
-
     # 9 pages * 8 positions = 72 vs 4 slots * 32 = 128 positions
-    assert nbytes(paged._cache) < 0.6 * nbytes(contig._cache)
+    assert _nbytes(paged._cache) < 0.6 * _nbytes(contig._cache)
+
+
+def test_worst_case_pool_fills_every_slot_on_fewer_bytes(trained):
+    """A pool sized to the traffic's worst case (prompt + max_new a
+    request, not max_len a slot) keeps all 4 slots busy and never
+    stalls an admission, token-exact, on fewer measured cache bytes
+    than the contiguous engine's max_slots x max_len."""
+    pages = 1 + 4 * 3  # scratch + 3 pages (<= 14 + 6 tokens) a slot
+    _, _, paged = _pair(trained, _mixed_reqs(12, seed=4), pages=pages)
+    s = paged.stats
+    assert s["admission_stalls"] == 0, dict(s)
+    assert s["max_concurrent"] == 4
+    assert s["kv_pages_high_water"] <= pages - 1
+    # 13 pages * 8 positions = 104 vs 4 slots * 32 = 128 positions
+    contig = DecodeEngine(trained._module(), trained._params,
+                          max_slots=4, max_len=L)
+    assert _nbytes(paged._cache) < _nbytes(contig._cache)
 
 
 def test_paged_multi_adapter_parity(trained):
@@ -309,13 +328,9 @@ def test_estimator_models_page_pool(trained):
     kv_cache term equals the PAGED ENGINE'S measured pool bytes (f32
     and int8 flavors), and the kv_pages=0 default mirrors the engine's
     full-coverage default."""
-    import jax
-
     def cache_bytes(model, **mk):
-        eng = DecodeEngine(model._module(**mk), model._params,
-                           max_slots=4, max_len=L)
-        return sum(int(np.prod(v.shape)) * v.dtype.itemsize
-                   for v in jax.tree_util.tree_leaves(eng._cache))
+        return _nbytes(DecodeEngine(model._module(**mk), model._params,
+                                    max_slots=4, max_len=L)._cache)
 
     b = trained.estimate_serving_device_bytes(
         max_slots=4, kv_page_size=PS, kv_pages=9)
