@@ -2,7 +2,14 @@
 feed-forward part, in every layer, is a routed expert layer beside one
 shared expert — served by ``DecodeEngine`` through the call it makes of
 every decoder (``ids, positions=, decode=True, page_tables=``, mutable
-``cache``). Every size is a field; nothing here names a model.
+``cache``). Every size is a field; nothing here names a model. What
+runs where: on the TPU the single-token step attends on the Pallas
+kernel ``latent_attn_step`` and windows on the page gather; the routed
+experts' three grouped products run on the Pallas kernel
+``moe_grouped_matmul`` (``ops/grouped_matmul.py``: a row tile sized to
+the call's rows an expert, 64 at a decode step and a prefill call, and
+weight tiles of megabytes; gate and up in one call, down in another);
+off the TPU both are XLA's (the gather, ``jax.lax.ragged_dot``).
 
 One layer, for ``x`` of ``(T, d)`` (RMSNorm, no biases):
 
@@ -16,7 +23,8 @@ One layer, for ``x`` of ``(T, d)`` (RMSNorm, no biases):
    q_rope.k_r) * s``, causal, softmax in f32, times ``v``, ``W_o``.
 5. ``h = norm(x)``; the routed experts (``ops/moe.py`` ``ExpertShare``:
    top-k of a softmax over all the router's logits, gates renormalised,
-   the sum over the experts HELD here) plus the shared expert's SwiGLU.
+   the sum over the experts HELD here, by grouped products) plus the
+   shared expert's SwiGLU.
 
 **Through the cache** a layer keeps ``[c_kv | k_r]`` — ``kv_rank +
 rope_dim`` values a token — in ONE pool (``(kv_pages, kv_page_size,

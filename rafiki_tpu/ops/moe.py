@@ -37,6 +37,9 @@ import jax
 import jax.numpy as jnp
 from flax import linen as nn
 
+from rafiki_tpu.ops.common import use_xla_fallback
+from rafiki_tpu.ops.grouped_matmul import group_visits, grouped_matmul
+
 #: standard weight on the load-balancing aux loss in the train
 #: objective (the Switch Transformer default) — one definition so the
 #: template, dryrun, and benches can't drift
@@ -184,7 +187,10 @@ MOE_COUNTERS = ("moe_assignments", "moe_assignments_held",
                 "moe_expert_slots", "moe_experts_touched",
                 # of those, in single-token (decode step) calls alone:
                 # what the grouped products of a step stream and compute
-                "moe_step_assignments_held", "moe_step_experts_touched")
+                "moe_step_assignments_held", "moe_step_experts_touched",
+                # (expert, row tile) pairs the narrow-tile grouped kernel
+                # visited in those calls; 0 off the TPU (``ragged_dot``)
+                "moe_step_row_tiles")
 
 
 def book_moe_counters(stats: Any, counts: Any) -> None:
@@ -198,6 +204,7 @@ def book_moe_counters(stats: Any, counts: Any) -> None:
     stats.inc("moe_experts_touched", int(counts[3]))
     stats.inc("moe_step_assignments_held", int(counts[4]))
     stats.inc("moe_step_experts_touched", int(counts[5]))
+    stats.inc("moe_step_row_tiles", int(counts[6]))
 
 
 def route_top_k(logits: jnp.ndarray, top_k: int, renormalize: bool = True,
@@ -213,10 +220,28 @@ def route_top_k(logits: jnp.ndarray, top_k: int, renormalize: bool = True,
     return gates * scaling, experts
 
 
+def narrow_row_tile(assignments: int, n: int) -> int:
+    """The row tile of the grouped products for a call of ``assignments``
+    (row, choice) pairs over ``n`` held experts, from those two static
+    numbers alone: the rows an expert would get if every assignment were
+    held, rounded up to a power of two, within 64 (below it a tile costs
+    the same — the weights' own passage through the MXU — and more
+    groups straddle a boundary) and 256 (the best at every larger shape
+    measured; 512 lost everywhere). The chip was asked at 8 rows an
+    expert (a decode step), 32 (a prefill call), 128, 256, 512, 1,024
+    and 2,048: the kernel took 0.60 / 0.43 / 0.46 / 0.50 / 0.54 / 0.60 /
+    0.69 of ``jax.lax.ragged_dot``'s time for one product, so no shape
+    the engine can make is handed back to XLA's kernel (PERF.md §6,
+    PR 34)."""
+    rows = -(-assignments // n)
+    return min(256, max(64, 1 << (rows - 1).bit_length()))
+
+
 def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
                     experts: jnp.ndarray, w_gate: jnp.ndarray,
                     w_up: jnp.ndarray, w_down: jnp.ndarray,
-                    first: int = 0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                    first: int = 0, interpret: Optional[bool] = None
+                    ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """The routed part of a SwiGLU expert layer over the experts HELD
     here, without a dropped token.
 
@@ -225,15 +250,28 @@ def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
     stacked kernels of the ``n`` experts held, ids ``first .. first + n
     - 1``. The (row, choice) assignments are sorted by expert, those of
     absent experts last and in no group, and the three products are
-    GROUPED matmuls (``jax.lax.ragged_dot``: on the TPU one Mosaic
-    kernel a product, which computes each group's rows against its own
-    expert's kernel and nothing for rows in no group — no capacity, no
-    one-hot dispatch, no multiplication by zero afterwards).
+    GROUPED matmuls: each group's rows against its own expert's kernel
+    and nothing for rows in no group — no capacity, no one-hot dispatch,
+    no multiplication by zero afterwards. What runs them:
+
+    - on the TPU ``ops/grouped_matmul.py``, the repo's Pallas kernel
+      (``moe_grouped_matmul`` in a profile), on a row tile sized to the
+      groups from the call's shapes (:func:`narrow_row_tile`: 64 for a
+      decode step and a prefill call of 8 x 32 rows, up to 256) and
+      weight tiles of megabytes read where the stacked kernels lie; an
+      expert with no row is not read at all. Gate and up are ONE call
+      (``silu(x w_gate) * (x w_up)`` in f32, rounded once), down
+      another;
+    - off the TPU (``interpret=None``) ``jax.lax.ragged_dot`` through
+      XLA, which is also the kernel's oracle; ``interpret=True`` runs
+      the Pallas kernel in the interpreter, for the tests.
 
     Returns ``(y, counts)``: ``y`` (T, d) f32, the sum over each row's
     HELD choices of ``gate * expert(x)`` (zero for a row with none), and
     int32 ``[assignments, of them on held experts, experts held, of them
-    with at least one row]`` — the first four of :data:`MOE_COUNTERS`.
+    with at least one row, (expert, row tile) pairs the narrow-tile
+    kernel visited, 0 on the ``ragged_dot`` route]`` — the first four of
+    :data:`MOE_COUNTERS` and, for a single-token call, the last.
     """
     t, k = experts.shape
     n = w_gate.shape[0]
@@ -243,10 +281,21 @@ def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
     xs = jnp.take(x, order // k, axis=0)  # (T k, d), grouped by expert
-    gate = jax.lax.ragged_dot(xs, w_gate.astype(x.dtype), sizes)
-    up = jax.lax.ragged_dot(xs, w_up.astype(x.dtype), sizes)
-    out = jax.lax.ragged_dot(nn.silu(gate) * up, w_down.astype(x.dtype),
-                             sizes)
+    w_gate, w_up, w_down = (w.astype(x.dtype)
+                            for w in (w_gate, w_up, w_down))
+    if use_xla_fallback(interpret):
+        gate = jax.lax.ragged_dot(xs, w_gate, sizes)
+        up = jax.lax.ragged_dot(xs, w_up, sizes)
+        out = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+        row_tiles = jnp.int32(0)
+    else:
+        row_tile = narrow_row_tile(t * k, n)
+        visits = group_visits(sizes, t * k, row_tile)
+        hidden = grouped_matmul(xs, (w_gate, w_up), visits, row_tile,
+                                interpret=interpret)
+        out = grouped_matmul(hidden, (w_down,), visits, row_tile,
+                             interpret=interpret)
+        row_tiles = visits.count[0]
     # back to (row, choice) order by a gather (the inverse permutation),
     # each choice times its gate. Rows past the last group belong to no
     # expert: whatever the product left there is not read
@@ -255,7 +304,7 @@ def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
     y = jnp.sum(jnp.where(weight > 0, back * weight, 0.0), axis=1)
     counts = jnp.stack([
         jnp.int32(t * k), jnp.sum(held, dtype=jnp.int32), jnp.int32(n),
-        jnp.sum(sizes > 0, dtype=jnp.int32)])
+        jnp.sum(sizes > 0, dtype=jnp.int32), row_tiles])
     return y, counts
 
 
@@ -268,7 +317,10 @@ class ExpertShare(nn.Module):
     absent experts would have added is left out — there is no exchange
     here and nothing stands in for one. Dropless (see
     :func:`grouped_experts`): a row's output does not depend on who
-    shares its batch.
+    shares its batch. On the TPU the three products are two calls of
+    the repo's narrow-tile Pallas kernel (``moe_grouped_matmul``: gate
+    and up together, then down); off the TPU ``jax.lax.ragged_dot``
+    through XLA.
 
     Parameters: ``router/kernel`` (d, n_experts) and
     ``experts_{gate,up,down}/kernel`` stacked over the experts HELD.
@@ -308,9 +360,9 @@ class ExpertShare(nn.Module):
             kernel("experts_gate", (n, d, self.mlp_dim)),
             kernel("experts_up", (n, d, self.mlp_dim)),
             kernel("experts_down", (n, self.mlp_dim, d)), first)
-        step = counts[jnp.array([1, 3])] * int(x.ndim == 3
-                                               and x.shape[1] == 1)
-        self.sow("counters", "moe", jnp.concatenate([counts, step]),
+        step = counts[jnp.array([1, 3, 4])] * int(x.ndim == 3
+                                                  and x.shape[1] == 1)
+        self.sow("counters", "moe", jnp.concatenate([counts[:4], step]),
                  init_fn=lambda: jnp.zeros((len(MOE_COUNTERS),), jnp.int32),
                  reduce_fn=lambda a, b: a + b)
         return y.reshape(lead + (d,)).astype(x.dtype)

@@ -15,7 +15,13 @@ program imported). Serving it through ``DecodeEngine`` is
 - the shares add up: four ``experts_held`` shares of one layer, the shared
   expert counted once, equal the uncut reference layer;
 - no dropped token: a row's output is the same alone and in a skewed
-  batch; rows of absent experts are left out; the gates' rule.
+  batch; rows of absent experts are left out; the gates' rule — on both
+  routes of the grouped products (``jax.lax.ragged_dot``, and the
+  narrow-tile Pallas kernel of ``ops/grouped_matmul.py`` through the
+  interpreter);
+- the narrow-tile kernel against a per-expert loop in f32 and against
+  the ``ragged_dot`` route over the shapes of groups that can go wrong;
+  the route and the engagement counter are what the shapes say.
 """
 
 import math
@@ -52,6 +58,13 @@ def weights(cfg, seed=3):
     module = driver.build_module(cfg)
     return module, driver.make_weights(cfg, driver.abstract_params(module),
                                        seed)
+
+
+#: the two routes of ``grouped_experts``'s products, by its ``interpret``:
+#: None off the TPU = ``jax.lax.ragged_dot``; True = the narrow-tile
+#: Pallas kernel in the interpreter
+ROUTES = pytest.mark.parametrize(
+    "interpret", [None, True], ids=["ragged_dot", "narrow_tile"])
 
 
 # ------------------------------------------------- model against reference
@@ -184,11 +197,14 @@ def _layer_weights(n_held, n_router=8, seed=11):
     return cfg, weights(cfg, seed)[1]["block_0"]
 
 
-def test_the_shares_add_up_to_the_uncut_layer():
+@ROUTES
+def test_the_shares_add_up_to_the_uncut_layer(interpret, monkeypatch):
     """Four chips, two experts each, of one 8-expert layer: what the
     shares add to the residual stream, the shared expert counted once,
     is what the uncut layer adds — in the reference, and the program's
     share equals the reference's share."""
+    if interpret:  # the module's own call, steered onto the kernel here
+        monkeypatch.setattr(moe, "use_xla_fallback", lambda _: False)
     cfg, full = _layer_weights(8)
     x = jnp.asarray(np.random.default_rng(4).normal(size=(24, 64)),
                     jnp.float32)
@@ -227,11 +243,13 @@ def _apply_block(module, block_params, x):
                        jnp.arange(x.shape[0])[None], False)[0]
 
 
-def test_no_dropped_token_in_a_skewed_batch():
+@ROUTES
+def test_no_dropped_token_in_a_skewed_batch(interpret):
     """63 rows that all choose the same two experts beside one row of
     another kind: that row's output is what it is alone (a capacity
     would have dropped most of the 63, and the order of rows would
-    matter)."""
+    matter). On the narrow-tile route the hot groups of 63 and 64 rows
+    share the first row tile and the second."""
     rng = np.random.default_rng(9)
     d, f, n = 16, 24, 4
     w = [jnp.asarray(rng.normal(size=s) / 4, jnp.float32)
@@ -241,9 +259,12 @@ def test_no_dropped_token_in_a_skewed_batch():
                                     rng.normal(size=(1, d))]), jnp.float32)
     experts = jnp.asarray([[0, 1]] * 63 + [[1, 3]], jnp.int32)
     gates = jnp.asarray([[0.7, 0.3]] * 63 + [[0.4, 0.6]], jnp.float32)
-    full, counts = moe.grouped_experts(x, gates, experts, *w)
-    alone, _ = moe.grouped_experts(x[-1:], gates[-1:], experts[-1:], *w)
-    first, _ = moe.grouped_experts(x[:1], gates[:1], experts[:1], *w)
+    full, counts = moe.grouped_experts(x, gates, experts, *w,
+                                       interpret=interpret)
+    alone, _ = moe.grouped_experts(x[-1:], gates[-1:], experts[-1:], *w,
+                                   interpret=interpret)
+    first, _ = moe.grouped_experts(x[:1], gates[:1], experts[:1], *w,
+                                   interpret=interpret)
     np.testing.assert_allclose(np.asarray(full[-1]), np.asarray(alone[0]),
                                atol=1e-5)
     np.testing.assert_allclose(np.asarray(full[:63]),
@@ -256,10 +277,14 @@ def test_no_dropped_token_in_a_skewed_batch():
         np.asarray(full[-1]),
         np.asarray(0.4 * expert(1, x[-1]) + 0.6 * expert(3, x[-1])),
         atol=1e-5)
-    assert [int(c) for c in counts] == [128, 128, 4, 3]
+    assert [int(c) for c in counts[:4]] == [128, 128, 4, 3]
+    # rows 0-62 | 63-126 | 127: tiles of 64 -> 1 + 2 + 1 visits
+    assert moe.narrow_row_tile(128, 4) == 64
+    assert int(counts[4]) == (4 if interpret else 0)
 
 
-def test_rows_of_absent_experts_are_left_out_not_zeroed():
+@ROUTES
+def test_rows_of_absent_experts_are_left_out_not_zeroed(interpret):
     """Held experts 2-3 of 8: a row that chose 5 and 2 gets expert 2's
     part alone; the counts say how many assignments were held."""
     rng = np.random.default_rng(10)
@@ -269,7 +294,8 @@ def test_rows_of_absent_experts_are_left_out_not_zeroed():
     x = jnp.asarray(rng.normal(size=(3, d)), jnp.float32)
     experts = jnp.asarray([[5, 2], [0, 7], [3, 2]], jnp.int32)
     gates = jnp.asarray([[0.5, 0.5], [0.9, 0.1], [0.2, 0.8]], jnp.float32)
-    y, counts = moe.grouped_experts(x, gates, experts, *w, first=2)
+    y, counts = moe.grouped_experts(x, gates, experts, *w, first=2,
+                                    interpret=interpret)
 
     def expert(e, row):
         return (jax.nn.silu(row @ w[0][e]) * (row @ w[1][e])) @ w[2][e]
@@ -280,7 +306,139 @@ def test_rows_of_absent_experts_are_left_out_not_zeroed():
         np.asarray(y[2]),
         np.asarray(0.2 * expert(1, x[2]) + 0.8 * expert(0, x[2])),
         atol=1e-5)
-    assert [int(c) for c in counts] == [6, 3, 2, 2]
+    assert [int(c) for c in counts] == [6, 3, 2, 2, 2 if interpret else 0]
+
+
+def _tiles_overlapped(sizes, tile):
+    """By hand: the row tiles each non-empty group overlaps, summed."""
+    total, start = 0, 0
+    for size in sizes:
+        if size:
+            total += (start + size - 1) // tile - start // tile + 1
+        start += size
+    return total
+
+
+def _experts_by_loop(x, gates, experts, w, first=0):
+    """``grouped_experts`` by a plain loop over (row, choice) in f32."""
+    x = np.asarray(x, np.float32)
+    w = [np.asarray(a, np.float32) for a in w]
+    y = np.zeros((x.shape[0], w[2].shape[2]), np.float32)
+    for t, (row_gates, row_experts) in enumerate(zip(np.asarray(gates),
+                                                     np.asarray(experts))):
+        for g, e in zip(row_gates, row_experts):
+            e = int(e) - first
+            if 0 <= e < w[0].shape[0]:
+                a = x[t] @ w[0][e]
+                h = a / (1.0 + np.exp(-a)) * (x[t] @ w[1][e])
+                y[t] += g * (h @ w[2][e])
+    return y
+
+
+#: (held experts, the expert of each (row, choice) in turn — ids past the
+#: held ones are absent; choices a row: 4, or 3 where 4 does not divide).
+#: Each call's shape gives the smallest row tile, 64
+GROUP_CASES = {
+    # 160 of 176 assignments on ONE expert: a group of two and a half tiles
+    "group_larger_than_the_tile": (4, [1] * 160 + [0, 2, 3, 3] * 2 + [9] * 8),
+    "empty_experts_first": (6, [2, 3, 4, 5] * 4),
+    "empty_experts_last": (6, [0, 1, 2, 3] * 4),
+    "empty_experts_in_a_run": (8, [0, 7] * 8 + [0, 7, 0, 0]),
+    # groups of 64, 64 and 8: the first two start and end ON a boundary
+    "group_from_boundary_to_boundary": (4, [0] * 64 + [1] * 64 + [3] * 8),
+    # groups of 65 and 63: one row past the boundary, then up to the next
+    "group_one_row_past_a_boundary": (4, [0] * 65 + [1] * 63 + [2] * 4),
+    # 23 rows x 3 choices = 69 assignments: the ROWS are padded to the
+    # tile, never the kernels
+    "assignments_no_multiple_of_the_tile": (4, [0, 1, 2] * 11 + [3, 0, 1] * 12),
+    "no_row_held_at_all": (4, [9, 8, 7, 6] * 4),
+    # a prefill call's share: 256 rows x 4 of 128 experts, 32 held: ~8
+    # rows an expert
+    "prefill_rows_an_expert": (32, None),
+}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(GROUP_CASES))
+def test_narrow_tile_kernel_equals_a_loop_and_the_other_route(case, dtype):
+    """The narrow-tile kernel (interpreter) against a per-expert loop in
+    f32 and against the ``ragged_dot`` route, over the shapes of groups
+    where a tiled grouped product can go wrong."""
+    n, chosen = GROUP_CASES[case]
+    rng = np.random.default_rng(sorted(GROUP_CASES).index(case))
+    k = 4 if chosen is None else (3 if len(chosen) % 4 else 4)
+    if chosen is None:
+        experts = np.stack([rng.choice(128, 4, replace=False)
+                            for _ in range(256)])
+    else:
+        experts = np.asarray(chosen).reshape(-1, k)
+    t = experts.shape[0]
+    d, f = 16, 24
+    w = [jnp.asarray(rng.normal(size=s) / 4, dtype)
+         for s in ((n, d, f), (n, d, f), (n, f, d))]
+    x = jnp.asarray(rng.normal(size=(t, d)), dtype)
+    gates = rng.uniform(0.1, 1.0, size=(t, k))
+    gates = jnp.asarray(gates / gates.sum(-1, keepdims=True), jnp.float32)
+    experts = jnp.asarray(experts, jnp.int32)
+    tile = moe.narrow_row_tile(t * k, n)
+    assert tile == 64
+
+    mine, counts = moe.grouped_experts(x, gates, experts, *w,
+                                       interpret=True)
+    other, other_counts = moe.grouped_experts(x, gates, experts, *w)
+    loop = _experts_by_loop(x, gates, experts, w)
+    # f32: summation order alone; bf16: the three products' roundings of
+    # gate, up and down, the same on both routes
+    tol = 2e-5 if dtype == jnp.float32 else 0.05
+    np.testing.assert_allclose(np.asarray(mine), loop, atol=tol)
+    np.testing.assert_allclose(np.asarray(mine), np.asarray(other),
+                               atol=tol)
+    sizes = np.bincount(np.asarray(experts).ravel(), minlength=128)[:n]
+    assert [int(c) for c in counts[:4]] == [int(c) for c in
+                                            other_counts[:4]]
+    assert int(counts[4]) == _tiles_overlapped(sizes, tile)
+    assert int(other_counts[4]) == 0
+    if case == "no_row_held_at_all":
+        np.testing.assert_array_equal(np.asarray(mine), 0.0)
+        assert int(counts[4]) == 0
+
+
+def test_the_route_is_what_the_shapes_say_and_the_counter_what_ran():
+    """A decode step's call (64 rows x 4 over 32 held experts) and a
+    prefill call's (256 rows) take the narrowest tile, more rows an
+    expert a wider one up to 256 — from the call's shapes alone; and
+    ``moe_step_row_tiles`` is the (expert, row tile) pairs the kernel
+    visited in single-token calls, 0 on the other route and in windows."""
+    assert moe.narrow_row_tile(64 * 4, 32) == 64
+    assert moe.narrow_row_tile(256 * 4, 32) == 64
+    assert moe.narrow_row_tile(1024 * 4, 32) == 128
+    assert moe.narrow_row_tile(16384 * 4, 32) == 256
+    assert moe.MOE_COUNTERS[-1] == "moe_step_row_tiles"
+
+    layer = moe.ExpertShare(n_experts=8, top_k=2, mlp_dim=24, held=(2, 4))
+    rng = np.random.default_rng(5)
+    step = jnp.asarray(rng.normal(size=(12, 1, 16)), jnp.float32)
+    window = step.reshape(3, 4, 16)
+    params = layer.init(jax.random.PRNGKey(0), step)["params"]
+
+    def sown(x):
+        _, state = layer.apply({"params": params}, x, mutable=["counters"])
+        return dict(zip(moe.MOE_COUNTERS,
+                        (int(c) for c in state["counters"]["moe"])))
+
+    off = sown(step)  # off the TPU: the ``ragged_dot`` route
+    assert off["moe_step_experts_touched"] > 0
+    assert off["moe_step_row_tiles"] == 0
+    with pytest.MonkeyPatch.context() as patch:
+        patch.setattr(moe, "use_xla_fallback", lambda _: False)
+        on, on_window = sown(step), sown(window)
+    assert on["moe_step_experts_touched"] == off["moe_step_experts_touched"]
+    # 24 assignments lie in one row tile: every touched expert is
+    # visited once
+    assert on["moe_step_row_tiles"] == on["moe_step_experts_touched"]
+    assert on_window["moe_step_row_tiles"] == 0
+    assert on_window["moe_experts_touched"] == on["moe_experts_touched"]
 
 
 def test_gates_are_the_renormalised_top_k_of_a_softmax():

@@ -205,19 +205,28 @@ def test_latent_step_kernel_compiles(spec, page, n_tables):
     assert "latent_attn_step" in text and "tpu_custom_call" in text
 
 
-def test_grouped_experts_compile_to_grouped_kernels(spec):
+@pytest.mark.parametrize("rows", [64, 256, 4096, 16384], ids=[
+    "decode_step", "prefill_call", "largest_tile", "many_rows"])
+def test_grouped_experts_compile_to_grouped_kernels(spec, rows):
     """The serving expert layer's three products at the benchmark's
-    sizes (a decode step's 256 sorted assignments over 32 held experts
-    of 4096 x 2048): ``jax.lax.ragged_dot`` lowers to the TPU's grouped
-    matmul kernels, not to a dense product over every expert."""
+    sizes (``rows`` x 4 sorted assignments over 32 held experts of 4096
+    x 2048, bf16) lower to the repo's narrow-tile Pallas kernel, gate
+    and up in one call and down in another: Mosaic takes its blocks and
+    its VMEM at the served widths, at a decode step's and a prefill
+    call's row tile (64) and at the largest (256), however many rows an
+    expert gets — and no product is XLA's ``ragged-dot`` with its
+    256-row tile, nor a dense one over every expert."""
     from rafiki_tpu.ops.moe import grouped_experts
 
     def layer(x, gates, experts, wg, wu, wd):
-        return grouped_experts(x, gates, experts, wg, wu, wd, first=0)
+        return grouped_experts(x, gates, experts, wg, wu, wd, first=0,
+                               interpret=False)
 
     text = _compiled_text(
-        layer, spec((64, 4096), jnp.bfloat16), spec((64, 4), jnp.float32),
-        spec((64, 4), jnp.int32), spec((32, 4096, 2048), jnp.bfloat16),
+        layer, spec((rows, 4096), jnp.bfloat16),
+        spec((rows, 4), jnp.float32), spec((rows, 4), jnp.int32),
+        spec((32, 4096, 2048), jnp.bfloat16),
         spec((32, 4096, 2048), jnp.bfloat16),
         spec((32, 2048, 4096), jnp.bfloat16))
-    assert text.count("ragged-dot") >= 3
+    assert text.count("moe_grouped_matmul") >= 2
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
