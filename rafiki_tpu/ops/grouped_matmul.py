@@ -15,8 +15,9 @@ step, which is made for decode: whole-``k`` weight tiles of
 ``WEIGHT_TILE_BYTES`` read where the stacked kernels lie (a grid step is
 one DMA of megabytes, not 32 KB), the list padded to its static bound by
 repeating its last pair (the pipeline elides a fetch whose block does
-not move, and the step is skipped), and optionally TWO kernels a call
-with ``silu(x w0) * (x w1)`` taken in f32 before it is rounded.
+not move, and the step is skipped), and an activation taken in f32
+before the one rounding: optionally TWO kernels a call with ``silu(x w0)
+* (x w1)``, or ``relu(x w0)^2`` of one.
 
 A group with no row is in no pair: its kernel is never fetched.
 """
@@ -72,7 +73,7 @@ def group_visits(sizes: jnp.ndarray, m: int, row_tile: int) -> GroupVisits:
 
 
 def _kernel(group_ref, tile_ref, starts_ref, ends_ref, count_ref, x_ref,
-            *rest, row_tile: int):
+            *rest, row_tile: int, relu2: bool):
     from jax.experimental import pallas as pl
 
     w_refs, o_ref = rest[:-1], rest[-1]
@@ -85,6 +86,8 @@ def _kernel(group_ref, tile_ref, starts_ref, ends_ref, count_ref, x_ref,
         if len(w_refs) == 2:
             acc = jax.nn.silu(acc) * jnp.dot(
                 x, w_refs[1][...], preferred_element_type=jnp.float32)
+        elif relu2:
+            acc = jnp.square(jnp.maximum(acc, 0.0))
         g = group_ref[v]
         row = tile_ref[v] * row_tile + jax.lax.broadcasted_iota(
             jnp.int32, acc.shape, 0)
@@ -96,25 +99,26 @@ def _kernel(group_ref, tile_ref, starts_ref, ends_ref, count_ref, x_ref,
 
 
 def column_tile(k: int, f: int, itemsize: int) -> int:
-    """Columns of a weight tile: the largest power-of-two multiple of 128
-    that divides ``f`` with ``k x columns`` within WEIGHT_TILE_BYTES (all
-    of ``f`` where it is no multiple of 128: a block may span a whole
-    dim whatever its size)."""
+    """Columns of a weight tile: the largest multiple of 128 that
+    divides ``f`` with ``k x columns`` within WEIGHT_TILE_BYTES — 896 of
+    2688 = 21 x 128 at ``k`` 1024, where a rule that only doubles would
+    stop at 128 (all of ``f`` where it is no multiple of 128: a block
+    may span a whole dim whatever its size)."""
     if f % 128:
         return f
-    cols = 128
-    while f % (2 * cols) == 0 \
-            and k * 2 * cols * itemsize <= WEIGHT_TILE_BYTES:
-        cols *= 2
-    return cols
+    fits = [cols for cols in range(128, f + 1, 128)
+            if f % cols == 0 and k * cols * itemsize <= WEIGHT_TILE_BYTES]
+    return max(fits, default=128)
 
 
 def grouped_matmul(xs: jnp.ndarray, ws: Sequence[jnp.ndarray],
                    visits: GroupVisits, row_tile: int,
-                   interpret: Optional[bool] = None) -> jnp.ndarray:
+                   interpret: Optional[bool] = None,
+                   relu2: bool = False) -> jnp.ndarray:
     """``xs[rows of group e] @ ws[0][e]`` for every group, (m, f) in
     ``xs.dtype`` from an f32 accumulator; with two kernels in ``ws``,
-    ``silu(xs @ ws[0][e]) * (xs @ ws[1][e])``. ``visits`` is
+    ``silu(xs @ ws[0][e]) * (xs @ ws[1][e])``; with ``relu2`` (one
+    kernel), ``relu(xs @ ws[0][e])^2``. ``visits`` is
     :func:`group_visits` of the groups' sizes at this ``row_tile`` (one
     list serves every product over the same groups). Rows in no group
     come back as whatever the buffer held: the caller does not read them.
@@ -125,7 +129,7 @@ def grouped_matmul(xs: jnp.ndarray, ws: Sequence[jnp.ndarray],
     m, k = xs.shape
     n, k_w, f = ws[0].shape
     if k_w != k or any(w.shape != ws[0].shape for w in ws) \
-            or not 1 <= len(ws) <= 2:
+            or not 1 <= len(ws) <= 2 - relu2:
         raise ValueError(f"rows are {k} wide, the kernels "
                          f"{[w.shape for w in ws]}")
     col_tile = column_tile(k, f, ws[0].dtype.itemsize)
@@ -148,7 +152,7 @@ def grouped_matmul(xs: jnp.ndarray, ws: Sequence[jnp.ndarray],
         out_specs=pl.BlockSpec((row_tile, col_tile), o_map),
     )
     out = pl.pallas_call(
-        functools.partial(_kernel, row_tile=row_tile),
+        functools.partial(_kernel, row_tile=row_tile, relu2=relu2),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((xs.shape[0], f), xs.dtype),
         compiler_params=pltpu.CompilerParams(

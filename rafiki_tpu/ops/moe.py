@@ -208,13 +208,26 @@ def book_moe_counters(stats: Any, counts: Any) -> None:
 
 
 def route_top_k(logits: jnp.ndarray, top_k: int, renormalize: bool = True,
-                scaling: float = 1.0) -> Tuple[jnp.ndarray, jnp.ndarray]:
+                scaling: float = 1.0,
+                score_bias: Optional[jnp.ndarray] = None
+                ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     """``(T, E)`` f32 router logits -> ``(gates, experts)``, both
     ``(T, k)``: the ``top_k`` largest of ``softmax(logits)`` with their
     expert ids, the gates divided by their sum (``renormalize``) and
-    multiplied by ``scaling``. No capacity: every choice stands."""
-    probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
-    gates, experts = jax.lax.top_k(probs, top_k)
+    multiplied by ``scaling``. No capacity: every choice stands.
+
+    With ``score_bias`` (E,) the second rule: scores ``s =
+    sigmoid(logits)``, the ``top_k`` largest of ``s + score_bias``
+    chosen, and the gates the chosen ``s`` themselves — the bias steers
+    the selection and never weighs a result."""
+    if score_bias is not None:
+        scores = jax.nn.sigmoid(logits.astype(jnp.float32))
+        _, experts = jax.lax.top_k(
+            scores + score_bias.astype(jnp.float32), top_k)
+        gates = jnp.take_along_axis(scores, experts, axis=-1)
+    else:
+        probs = jax.nn.softmax(logits.astype(jnp.float32), axis=-1)
+        gates, experts = jax.lax.top_k(probs, top_k)
     if renormalize:
         gates = gates / jnp.sum(gates, axis=-1, keepdims=True)
     return gates * scaling, experts
@@ -238,12 +251,13 @@ def narrow_row_tile(assignments: int, n: int) -> int:
 
 
 def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
-                    experts: jnp.ndarray, w_gate: jnp.ndarray,
+                    experts: jnp.ndarray, w_gate: Optional[jnp.ndarray],
                     w_up: jnp.ndarray, w_down: jnp.ndarray,
                     first: int = 0, interpret: Optional[bool] = None
                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """The routed part of a SwiGLU expert layer over the experts HELD
-    here, without a dropped token.
+    """The routed part of an expert layer over the experts HELD here,
+    without a dropped token: SwiGLU experts of three kernels, or with
+    ``w_gate=None`` experts of two, ``relu(x w_up)^2 w_down``.
 
     ``x`` (T, d) rows; ``gates`` / ``experts`` (T, k) from
     :func:`route_top_k` over ALL the router's experts; ``w_*`` the
@@ -260,8 +274,8 @@ def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
       decode step and a prefill call of 8 x 32 rows, up to 256) and
       weight tiles of megabytes read where the stacked kernels lie; an
       expert with no row is not read at all. Gate and up are ONE call
-      (``silu(x w_gate) * (x w_up)`` in f32, rounded once), down
-      another;
+      (``silu(x w_gate) * (x w_up)`` in f32, rounded once; for experts
+      of two kernels ``relu(x w_up)^2`` likewise), down another;
     - off the TPU (``interpret=None``) ``jax.lax.ragged_dot`` through
       XLA, which is also the kernel's oracle; ``interpret=True`` runs
       the Pallas kernel in the interpreter, for the tests.
@@ -274,25 +288,31 @@ def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
     :data:`MOE_COUNTERS` and, for a single-token call, the last.
     """
     t, k = experts.shape
-    n = w_gate.shape[0]
+    n = w_up.shape[0]
     local = experts.reshape(t * k) - first
     held = (local >= 0) & (local < n)
     group = jnp.where(held, local, n)  # absent experts sort last
     order = jnp.argsort(group, stable=True)
     sizes = jnp.sum(jax.nn.one_hot(group, n, dtype=jnp.int32), axis=0)
     xs = jnp.take(x, order // k, axis=0)  # (T k, d), grouped by expert
-    w_gate, w_up, w_down = (w.astype(x.dtype)
-                            for w in (w_gate, w_up, w_down))
+    w_in = tuple(w.astype(x.dtype) for w in (w_gate, w_up)
+                 if w is not None)
+    w_down = w_down.astype(x.dtype)
     if use_xla_fallback(interpret):
-        gate = jax.lax.ragged_dot(xs, w_gate, sizes)
-        up = jax.lax.ragged_dot(xs, w_up, sizes)
-        out = jax.lax.ragged_dot(nn.silu(gate) * up, w_down, sizes)
+        up = jax.lax.ragged_dot(xs, w_in[-1], sizes)
+        if w_gate is None:
+            hidden = jnp.square(nn.relu(up.astype(jnp.float32))
+                                ).astype(x.dtype)
+        else:
+            hidden = nn.silu(jax.lax.ragged_dot(xs, w_in[0], sizes)) * up
+        out = jax.lax.ragged_dot(hidden, w_down, sizes)
         row_tiles = jnp.int32(0)
     else:
         row_tile = narrow_row_tile(t * k, n)
         visits = group_visits(sizes, t * k, row_tile)
-        hidden = grouped_matmul(xs, (w_gate, w_up), visits, row_tile,
-                                interpret=interpret)
+        hidden = grouped_matmul(xs, w_in, visits, row_tile,
+                                interpret=interpret,
+                                relu2=w_gate is None)
         out = grouped_matmul(hidden, (w_down,), visits, row_tile,
                              interpret=interpret)
         row_tiles = visits.count[0]
@@ -309,24 +329,34 @@ def grouped_experts(x: jnp.ndarray, gates: jnp.ndarray,
 
 
 class ExpertShare(nn.Module):
-    """One chip's share of a routed SwiGLU expert layer, for serving:
-    the router keeps its published width (``n_experts``) and its
-    ``top_k`` experts a token; the layer is TOLD which experts it holds
-    (``held = (first id, count)``; count 0 = all), routes over all of
-    them and adds up its own experts' part of the result. What the
-    absent experts would have added is left out — there is no exchange
-    here and nothing stands in for one. Dropless (see
-    :func:`grouped_experts`): a row's output does not depend on who
-    shares its batch. On the TPU the three products are two calls of
-    the repo's narrow-tile Pallas kernel (``moe_grouped_matmul``: gate
-    and up together, then down); off the TPU ``jax.lax.ragged_dot``
-    through XLA.
+    """One chip's share of a routed expert layer, for serving: SwiGLU
+    experts of three kernels (``gated``), or experts of two with
+    ``relu(.)^2`` between. The router keeps its published width
+    (``n_experts``) and its ``top_k`` experts a token; the layer is TOLD
+    which experts it holds (``held = (first id, count)``; count 0 =
+    all), routes over all of them and adds up its own experts' part of
+    the result. What the absent experts would have added is left out —
+    there is no exchange here and nothing stands in for one. Dropless
+    (see :func:`grouped_experts`): a row's output does not depend on
+    who shares its batch. On the TPU the products are two calls of the
+    repo's narrow-tile Pallas kernel (``moe_grouped_matmul``: gate and
+    up together — or up with its ``relu^2`` — then down); off the TPU
+    ``jax.lax.ragged_dot`` through XLA.
 
-    Parameters: ``router/kernel`` (d, n_experts) and
-    ``experts_{gate,up,down}/kernel`` stacked over the experts HELD.
-    The router's product and softmax run in f32 whatever the compute
-    dtype. Counts land in the ``"counters"`` collection (``moe``), when
-    the caller makes it mutable.
+    Two rules of routing (:func:`route_top_k`): the ``top_k`` largest of
+    a softmax, or with ``sigmoid_scores`` the ``top_k`` largest of
+    ``sigmoid + score_bias`` weighed by their sigmoids alone. The
+    router may read other rows than the experts compute on (``route_on``
+    of the call): a layer whose experts work in a latent narrower than
+    the model routes on the model's activations.
+
+    Parameters: ``router/kernel`` (router's input width, n_experts),
+    ``score_bias`` (n_experts,) with ``sigmoid_scores``, and
+    ``experts_{gate,up,down}/kernel`` stacked over the experts HELD (no
+    ``experts_gate`` unless ``gated``). The router's product and scores
+    run in f32 whatever the compute dtype. Counts land in the
+    ``"counters"`` collection (``moe``), when the caller makes it
+    mutable.
     """
 
     n_experts: int
@@ -335,9 +365,12 @@ class ExpertShare(nn.Module):
     held: Tuple[int, int] = (0, 0)
     renormalize: bool = True
     scaling: float = 1.0
+    gated: bool = True
+    sigmoid_scores: bool = False
 
     @nn.compact
-    def __call__(self, x: jnp.ndarray) -> jnp.ndarray:
+    def __call__(self, x: jnp.ndarray,
+                 route_on: Optional[jnp.ndarray] = None) -> jnp.ndarray:
         lead, d = x.shape[:-1], x.shape[-1]
         first, n = self.held if self.held[1] else (0, self.n_experts)
         if first < 0 or first + n > self.n_experts:
@@ -349,15 +382,22 @@ class ExpertShare(nn.Module):
             return KernelLeaf(shape, init, name=name)()
 
         xf = x.reshape(-1, d)
+        rf = xf if route_on is None else route_on.reshape(
+            -1, route_on.shape[-1])
         logits = jnp.matmul(
-            xf.astype(jnp.float32),
-            kernel("router", (d, self.n_experts)).astype(jnp.float32),
+            rf.astype(jnp.float32),
+            kernel("router", (rf.shape[-1], self.n_experts)
+                   ).astype(jnp.float32),
             precision=jax.lax.Precision.HIGHEST)
+        bias = {"score_bias": self.param(
+            "score_bias", nn.initializers.zeros, (self.n_experts,))
+        } if self.sigmoid_scores else {}
         gates, experts = route_top_k(logits, self.top_k, self.renormalize,
-                                     self.scaling)
+                                     self.scaling, **bias)
         y, counts = grouped_experts(
             xf, gates, experts,
-            kernel("experts_gate", (n, d, self.mlp_dim)),
+            kernel("experts_gate", (n, d, self.mlp_dim))
+            if self.gated else None,
             kernel("experts_up", (n, d, self.mlp_dim)),
             kernel("experts_down", (n, self.mlp_dim, d)), first)
         step = counts[jnp.array([1, 3, 4])] * int(x.ndim == 3

@@ -235,6 +235,23 @@ class DecodeEngine:
         self.B = int(max_slots)
         self.L = int(max_len)
         self.K = max(1, int(steps_per_sync))
+        #: cache leaves the module keeps a SLOT (a recurrent state: one
+        #: row a slot and a scratch row), not a position. Such a module
+        #: is told, in every decode-path call, which slot each row
+        #: belongs to and how many of the row's tokens are real
+        #: (``slot_ids``, ``row_tokens``); what cannot carry that state
+        #: yet is refused by name, here or at the call
+        self._slot_state = tuple(getattr(module, "slot_state", ()))
+        if self._slot_state:
+            for what, asked in (
+                    ("a host KV tier (host_kv_pages > 0)", host_kv_pages),
+                    ("a draft model (draft=)", draft is not None),
+                    ("speculation (speculate_k >= 2)",
+                     int(speculate_k) >= 2),
+                    ("a contiguous cache (kv_page_size = 0)",
+                     not int(getattr(module, "kv_page_size", 0) or 0))):
+                if asked:
+                    raise ValueError(self._no_slot_state(what))
         #: >=2 enables greedy speculative decoding (prompt-lookup
         #: drafting, no draft model): each fused call verifies
         #: ``speculate_k - 1`` host-drafted tokens plus the model's own
@@ -498,6 +515,11 @@ class DecodeEngine:
             # cache's own leaves over the positions they hold (pool
             # pages x page size, or slots x max_len)
             "kv_pool_bytes_per_token": self._pool_bytes_per_token(),
+            # bytes ONE slot's recurrent state costs over all layers:
+            # the cache leaves the module indexes by slot (slots + a
+            # scratch row); 0 for a module that keeps none
+            "ssm_state_bytes_per_slot":
+                self._cache_bytes(per_slot=True) // (self.B + 1),
             **{name: 0 for name in self._count_names},
             # the host's share of the loop, counted where the phase
             # spans are cut (docs/observability.md "Phase spans"):
@@ -526,11 +548,25 @@ class DecodeEngine:
         #: nanoseconds of the open turn spent in ``engine.sync_wait``
         self._turn_sync_ns = 0
 
+    def _no_slot_state(self, what: str) -> str:
+        return (f"{type(self.module).__name__} keeps per-slot state "
+                f"{self._slot_state}, which {what} cannot carry yet: "
+                "pages can be parked, shipped and shared, a slot's "
+                "recurrent state cannot")
+
+    def _cache_bytes(self, per_slot: bool) -> int:
+        """Bytes of the cache's leaves indexed by slot (``per_slot``) or
+        of those indexed by position."""
+        return sum(
+            int(leaf.nbytes) for path, leaf in
+            jax.tree_util.tree_flatten_with_path(self._cache)[0]
+            if (getattr(path[-1], "key", None) in self._slot_state)
+            == per_slot)
+
     def _pool_bytes_per_token(self) -> int:
         positions = (self.n_pages * self.page_size if self.paged
                      else self.B * self.L)
-        return sum(int(x.nbytes) for x in
-                   jax.tree_util.tree_leaves(self._cache)) // positions
+        return self._cache_bytes(per_slot=False) // positions
 
     @property
     def params(self) -> Any:
@@ -588,6 +624,9 @@ class DecodeEngine:
         lower-class occupants when the pool/slots are full — the
         victim's pages free and it resumes token-exact later from its
         re-queued prefix. Unknown classes raise ``ValueError``."""
+        if self._slot_state and (prefill_only or kv_import is not None):
+            raise ValueError(self._no_slot_state(
+                "KV shipping (submit(prefill_only=) / submit(kv_import=))"))
         prompt = np.asarray(prompt_ids, np.int32).ravel()
         max_new = max(1, min(int(max_new), self.L - 1))
         prompt = prompt[:max(1, self.L - max_new)]
@@ -1170,6 +1209,9 @@ class DecodeEngine:
         function of the adapter that computed it, so each adapter keeps
         its OWN registered prefix (multi-tenant system prompts) and
         hits are gated on the requesting slot's adapter."""
+        if self._slot_state:
+            raise ValueError(self._no_slot_state(
+                "a shared prefix (register_prefix)"))
         aid = self._check_adapter_id(adapter_id)
         prefix = np.asarray(prefix_ids, np.int32).ravel()[:self.L - 2]
         if len(prefix) == 0:
@@ -1272,6 +1314,9 @@ class DecodeEngine:
         starts cold until generation warms the draft cache). Returns
         the installed length. Same concurrency contract as
         :meth:`register_prefix` (not concurrent with ``step``)."""
+        if self._slot_state:
+            raise ValueError(self._no_slot_state(
+                "a shared prefix (import_prefix)"))
         aid = self._check_adapter_id(adapter_id)
         if not isinstance(blob, dict) or int(blob.get("v", -1)) != 1:
             raise ValueError("not a prefix snapshot blob")
@@ -1316,7 +1361,9 @@ class DecodeEngine:
                 "kv_host_pages_total": self.host_pages,
                 "weight_bytes": self.stats["weight_bytes"],
                 "kv_pool_bytes_per_token":
-                    self.stats["kv_pool_bytes_per_token"]}
+                    self.stats["kv_pool_bytes_per_token"],
+                "ssm_state_bytes_per_slot":
+                    self.stats["ssm_state_bytes_per_slot"]}
         if self.paged:
             keep.update(kv_pages_total=self.n_pages - 1,
                         kv_pages_used=(self.n_pages - 1
@@ -1435,17 +1482,22 @@ class DecodeEngine:
                 prep = self._prefill_operands(occupied)
             if prep is None:
                 return calls
-            fill_fn, adv, tok_dev, pos_dev, aid_dev, ptab = prep
+            fill_fn, adv, tok_dev, pos_dev, aid_dev, ptab, rows = prep
             calls += 1
-            with SPANS.span("engine.prefill_dispatch"):
+            with SPANS.span("engine.prefill_dispatch") as dispatch:
+                if rows is not None:  # a gathered call
+                    dispatch.set(rows=int(np.count_nonzero(rows[1])),
+                                 lanes=int(np.count_nonzero(adv)))
+                    rows = (tuple(jnp.asarray(r) for r in rows)
+                            if self._slot_state else None)
                 if self._counts_dev is None:
                     self._cache = fill_fn(
                         self.params, self._cache, tok_dev, pos_dev,
-                        aid_dev, ptab)
+                        aid_dev, ptab, rows=rows)
                 else:
                     self._cache, self._counts_dev = fill_fn(
                         self.params, self._cache, tok_dev, pos_dev,
-                        aid_dev, ptab, self._counts_dev)
+                        aid_dev, ptab, self._counts_dev, rows=rows)
                 if self._draft_cache is not None and self._draft_synced:
                     # keep the draft's KV in lockstep with the prompt
                     # walk (while desynced, resync rebuilds prompts
@@ -1453,7 +1505,7 @@ class DecodeEngine:
                     self._draft_cache = self._draft_sync_c(
                         self.draft_params, self._draft_cache, tok_dev,
                         pos_dev, aid_dev, self._ptab_arg())
-                del prep, tok_dev, pos_dev, aid_dev, ptab  # see _turn()
+                del prep, tok_dev, pos_dev, aid_dev, ptab, rows  # see _turn()
                 self._book_prefill(adv)
 
     def _prefill_operands(self, occupied: np.ndarray) -> Optional[Tuple]:
@@ -1497,7 +1549,7 @@ class DecodeEngine:
         self._map_prefill_pages(adv)
         return (fill_fn, adv, jnp.asarray(tok_chunk),
                 jnp.asarray(pos_chunk), jnp.asarray(self._aid),
-                self._ptab_arg())
+                self._ptab_arg(), None)
 
     def _gathered_rows(self, rem: np.ndarray, lanes: np.ndarray,
                        c_use: int) -> Tuple:
@@ -1514,11 +1566,12 @@ class DecodeEngine:
         adv = np.zeros((self.B,), rem.dtype)
         tok_chunk = np.zeros((n_rows, c_use), np.int32)
         pos_chunk = np.zeros((n_rows, c_use), np.int32)
-        row_lane = []
+        row_lane, row_real = [], np.zeros((n_rows,), np.int32)
         for i in lanes:
             left, p0 = int(rem[i]), int(self._pos[i])
             while left > 0 and len(row_lane) < n_rows:
                 row, a = len(row_lane), min(left, c_use)
+                row_real[row] = a
                 tok_chunk[row, :a] = self._prompt_buf[i, p0:p0 + a]
                 pos_chunk[row, :a] = np.arange(p0, p0 + a)
                 # pad by repeating the row's last real entry —
@@ -1537,8 +1590,10 @@ class DecodeEngine:
         aid[:n] = self._aid[row_lane]
         ptab = np.zeros((n_rows, self._live_table_width()), np.int32)
         ptab[:n] = self._ptab[row_lane, :ptab.shape[1]]
+        slots = np.full((n_rows,), self.B, np.int32)
+        slots[:n] = row_lane
         return (adv, jnp.asarray(tok_chunk), jnp.asarray(pos_chunk),
-                jnp.asarray(aid), jnp.asarray(ptab))
+                jnp.asarray(aid), jnp.asarray(ptab), (slots, row_real))
 
     def _map_prefill_pages(self, adv: np.ndarray) -> None:
         """Lazy allocation tracks the prompt walk: each chunk call only
@@ -2401,12 +2456,15 @@ def _mutable_collections(module: Any) -> List[str]:
         module, "device_counters", ()) else ["cache"]
 
 
-def _add_counts(counts: Sequence[jnp.ndarray], muts: Any
+def _add_counts(module: Any, counts: Sequence[jnp.ndarray], muts: Any
                 ) -> List[jnp.ndarray]:
     """The running device counters (one int32 vector, or none) plus
-    what this ``apply`` sowed, summed over layers."""
-    sown = jax.tree_util.tree_leaves(muts.get("counters", {}))
-    return [c + sum(sown) for c in counts]
+    what this ``apply`` sowed, summed over layers — or, for a module
+    whose layers sow vectors of several kinds, folded into one by its
+    ``fold_device_counters``."""
+    fold = getattr(module, "fold_device_counters", None) or (
+        lambda sown: sum(jax.tree_util.tree_leaves(sown)))
+    return [c + fold(muts.get("counters", {})) for c in counts]
 
 
 @functools.lru_cache(maxsize=8)
@@ -2429,9 +2487,17 @@ def _make_step(module: Any, n_slots: int, k: int,
     otherwise — one signature for both layouts). A module with
     ``device_counters`` takes their running int32 vector as one more
     operand and returns it, its K steps' counts added, as one more
-    output (see :func:`_add_counts`)."""
+    output (see :func:`_add_counts`).
+
+    A module with per-slot state (``slot_state``) is told, at every
+    step, which slot each row is (row ``i`` is slot ``i``) and whether
+    its token is real: a recurrence is advanced by a re-fed token as by
+    any other, so a lane that is empty, or froze at its ``stop_pos``
+    earlier in this scan, has 0 real tokens and its state is left
+    alone."""
     multi = int(getattr(module, "n_adapters", 0) or 0) > 0
     paged = int(getattr(module, "kv_page_size", 0) or 0) > 0
+    slot_state = bool(getattr(module, "slot_state", ()))
     mutable = _mutable_collections(module)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
@@ -2440,13 +2506,15 @@ def _make_step(module: Any, n_slots: int, k: int,
         rows = jnp.arange(n_slots)
 
         def body(carry, _):
-            cache, tok, pos, *counts = carry
+            cache, tok, pos, alive, *counts = carry
             logits, muts = module.apply(
                 {"params": params, "cache": cache}, tok[:, None],
                 positions=pos[:, None], decode=True, mutable=mutable,
                 **({"adapter_ids": aid} if multi else {}),
-                **({"page_tables": ptab} if paged else {}))
-            counts = _add_counts(counts, muts)
+                **({"page_tables": ptab} if paged else {}),
+                **({"slot_ids": rows, "row_tokens": alive.astype(jnp.int32)}
+                   if slot_state else {}))
+            counts = _add_counts(module, counts, muts)
             lg = logits[:, -1].astype(jnp.float32)
             if sampling:
                 nxt = _select_next(lg, temp, top_k, top_p, seed, pos)
@@ -2460,10 +2528,12 @@ def _make_step(module: Any, n_slots: int, k: int,
             active = new_pos < stop_pos
             tok2 = jnp.where(active, nxt_input, tok)
             pos2 = jnp.where(active, new_pos, pos)
-            return (muts["cache"], tok2, pos2, *counts), nxt
+            return (muts["cache"], tok2, pos2, alive & active,
+                    *counts), nxt
 
-        (cache, tok, pos, *counts), emitted = jax.lax.scan(
-            body, (cache, tok, pos, *counts), None, length=k)
+        (cache, tok, pos, _, *counts), emitted = jax.lax.scan(
+            body, (cache, tok, pos, pos < stop_pos, *counts), None,
+            length=k)
         return (cache, emitted, *counts)  # emitted: (K, n_slots)
 
     return step_fn
@@ -2552,7 +2622,8 @@ def _make_prefill(module: Any, n_slots: int, chunk: int) -> Callable:
     positions through the decode-cache path. The lm_head output is
     discarded (prefill emits nothing), so XLA dead-code-eliminates the
     (B, C, vocab) projection — the call is pure KV-cache population at
-    matmul (not matvec) arithmetic intensity."""
+    matmul (not matvec) arithmetic intensity. ``rows`` (a module with
+    per-slot state alone): each row's slot and its real tokens."""
     multi = int(getattr(module, "n_adapters", 0) or 0) > 0
     paged = int(getattr(module, "kv_page_size", 0) or 0) > 0
 
@@ -2560,17 +2631,19 @@ def _make_prefill(module: Any, n_slots: int, chunk: int) -> Callable:
 
     @functools.partial(jax.jit, donate_argnums=(1,))
     def prefill_fn(params, cache, tok_chunk, pos_chunk, aid, ptab,
-                   *counts):
+                   *counts, rows=None):
         _, muts = module.apply(
             {"params": params, "cache": cache}, tok_chunk,
             positions=pos_chunk, decode=True, mutable=mutable,
             **({"adapter_ids": aid} if multi else {}),
-            **({"page_tables": ptab} if paged else {}))
+            **({"page_tables": ptab} if paged else {}),
+            **({} if rows is None else
+               {"slot_ids": rows[0], "row_tokens": rows[1]}))
         # ``counts`` is the *args tuple: its length is static under jit.
         # None handed in (a prefix snapshot, a draft's mirror pass, a
         # module that names no counters): the cache alone
         if counts:  # rafiki: noqa[jax-tracer-branch] — a tuple's length
-            return (muts["cache"], *_add_counts(counts, muts))
+            return (muts["cache"], *_add_counts(module, counts, muts))
         return muts["cache"]
 
     return prefill_fn

@@ -230,3 +230,78 @@ def test_grouped_experts_compile_to_grouped_kernels(spec, rows):
         spec((32, 2048, 4096), jnp.bfloat16))
     assert text.count("moe_grouped_matmul") >= 2
     assert "tpu_custom_call" in text and "ragged-dot" not in text
+
+
+# ---- the pattern-driven state-space / latent-expert decoder's kernels at
+# ---- the widths the benchmark serves (64 slots, pages of 32)
+def test_ssm_state_step_kernel_compiles_in_place(spec):
+    """The single-token state step at 64 rows x 128 heads x 64 x 128 in
+    float32: Mosaic takes a whole slot's state (4 MB) as one tile going
+    in and one coming out, and the table is updated IN PLACE (the
+    program's output aliases its 273 MB operand: no second table)."""
+    from rafiki_tpu.ops.ssm import ssm_state_step
+
+    def step(state, slots, advance, fresh, x, dt, a, b, c, d):
+        return ssm_state_step(state, slots, advance, fresh, x, dt, a, b, c,
+                              d, interpret=False)
+
+    compiled = jax.jit(step, donate_argnums=(0,)).lower(
+        spec((65, 128, 64, 128), jnp.float32), spec((64,), jnp.int32),
+        spec((64,), jnp.bool_), spec((64,), jnp.bool_),
+        spec((64, 128, 64), jnp.bfloat16), spec((64, 128), jnp.float32),
+        spec((128,), jnp.float32), spec((64, 8, 128), jnp.bfloat16),
+        spec((64, 8, 128), jnp.bfloat16), spec((128,), jnp.float32)
+    ).compile()
+    text = compiled.as_text()
+    assert "ssm_state_step" in text and "tpu_custom_call" in text
+    table = 65 * 128 * 64 * 128 * 4
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= table
+    assert memory.temp_size_in_bytes < table // 8
+
+
+@pytest.mark.parametrize("rows", [64, 1024], ids=["decode_step",
+                                                  "prefill_call"])
+def test_latent_experts_compile_to_grouped_kernels(spec, rows):
+    """Experts of TWO kernels with ``relu^2`` between, in a latent of
+    1024: ``rows`` x 22 sorted assignments over 128 held experts of 1024
+    x 2688 (column tiles of 896) and 2688 x 1024 (512), bf16 — up with
+    its activation in one call of the narrow-tile kernel, down in
+    another."""
+    from rafiki_tpu.ops.moe import grouped_experts
+
+    def layer(x, gates, experts, wu, wd):
+        return grouped_experts(x, gates, experts, None, wu, wd, first=0,
+                               interpret=False)
+
+    text = _compiled_text(
+        layer, spec((rows, 1024), jnp.bfloat16),
+        spec((rows, 22), jnp.float32), spec((rows, 22), jnp.int32),
+        spec((128, 1024, 2688), jnp.bfloat16),
+        spec((128, 2688, 1024), jnp.bfloat16))
+    assert text.count("moe_grouped_matmul") >= 2
+    assert "tpu_custom_call" in text and "ragged-dot" not in text
+
+
+@pytest.mark.parametrize("n_tables", [1, 128])
+def test_paged_kernels_compile_at_two_kv_heads_and_pages_of_32(
+        spec, n_tables):
+    """32 query heads over 2 kv heads of 128, pages of 32, 64 slots: the
+    step kernel, and the window kernel at a prefill call's 8 rows of
+    128 tokens, at the narrowest and the widest page table."""
+    kv = spec((1 + 64 * 128, 32, 2, 128), jnp.bfloat16)
+
+    def step(q, k, v, tabs, t):
+        return paged_decode_attention(q, k, v, tabs, t, sm_scale=128 ** -0.5,
+                                      interpret=False)
+
+    def window(q, k, v, tabs, t):
+        return paged_window_attention(q, k, v, tabs, t,
+                                      sm_scale=128 ** -0.5, interpret=False)
+
+    assert "tpu_custom_call" in _compiled_text(
+        step, spec((64, 32, 128), jnp.bfloat16), kv, kv,
+        spec((64, n_tables), jnp.int32), spec((64,), jnp.int32))
+    assert "tpu_custom_call" in _compiled_text(
+        window, spec((8, 128, 32, 128), jnp.bfloat16), kv, kv,
+        spec((8, n_tables), jnp.int32), spec((8, 128), jnp.int32))
