@@ -63,7 +63,7 @@ from flax import linen as nn
 from rafiki_tpu.models.llama_lora import (LoRADense, RMSNorm,
                                           _masked_decode_attention)
 from rafiki_tpu.ops.moe import (MOE_COUNTERS, ExpertShare,
-                                book_moe_counters)
+                                book_moe_counters, sown_counters)
 from rafiki_tpu.ops.paged_attention import (kv_cache_write,
                                             paged_decode_attention,
                                             paged_window_attention,
@@ -85,23 +85,6 @@ def book_ssm_counters(stats: Any, counts: Any) -> None:
     stats.inc("ssm_step_rows", int(counts[0]))
     stats.inc("ssm_prefill_rows", int(counts[1]))
     stats.inc("ssm_rows_chained", int(counts[2]))
-
-
-def _sown(tree: Any, name: str, width: int) -> jnp.ndarray:
-    """The sum of every vector sown under ``name`` in a ``"counters"``
-    collection (zeros where no layer sowed one)."""
-    total = jnp.zeros((width,), jnp.int32)
-
-    def visit(node: Any) -> None:
-        nonlocal total
-        for key, sub in node.items():
-            if key == name:
-                total = total + sum(jax.tree_util.tree_leaves(sub))
-            elif isinstance(sub, dict):
-                visit(sub)
-
-    visit(tree)
-    return total
 
 
 class Rows(NamedTuple):
@@ -431,8 +414,9 @@ class HybridSSMMoEDecoder(nn.Module):
     def fold_device_counters(self, sown: Any) -> jnp.ndarray:
         """One ``apply``'s ``"counters"`` collection as one vector in
         the order of ``device_counters``."""
-        return jnp.concatenate([_sown(sown, "moe", len(MOE_COUNTERS)),
-                                _sown(sown, "ssm", len(SSM_COUNTERS))])
+        return jnp.concatenate([
+            sown_counters(sown, "moe", len(MOE_COUNTERS)),
+            sown_counters(sown, "ssm", len(SSM_COUNTERS))])
 
     def book_device_counters(self, stats: Any, counts: Any) -> None:
         book_moe_counters(stats, counts[:len(MOE_COUNTERS)])

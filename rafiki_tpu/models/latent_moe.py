@@ -26,16 +26,21 @@ One layer, for ``x`` of ``(T, d)`` (RMSNorm, no biases):
    the sum over the experts HELD here, by grouped products) plus the
    shared expert's SwiGLU.
 
-**Through the cache** a layer keeps ``[c_kv | k_r]`` — ``kv_rank +
-rope_dim`` values a token — in ONE pool (``(kv_pages, kv_page_size,
-kv_rank + rope_dim)``, or ``(b, max_len, ...)`` contiguous) and attends
-in latent space: ``q_lat = q_nope W_kvb[K]``, scores against the row,
-output ``probs . c_kv`` through ``W_kvb[V]``
-(``ops/latent_attention.py``). The single-token step walks the block
-table in a Pallas kernel where :func:`latent_kernel_mode` says so;
-windows (chunked prefill) gather the slot's pages and stay in latent
-space too. Without a cache (``decode=False``) keys and values are
-expanded as in step 4: the two must agree, and a test holds them to it.
+**Through the cache** a layer keeps ``c_kv`` and ``k_r`` — ``kv_rank +
+rope_dim`` values a token — in two leaves: ``kv``, the latents
+(``(kv_pages, kv_page_size, kv_rank)``), and ``k_rope``, the rotary keys
+packed two positions a row (``(kv_pages, kv_page_size / 2, 2 *
+rope_dim)``: both minor dimensions fill whole 128-lane tiles at the
+published widths, which is what lets the step kernel copy pages out of
+HBM itself); contiguous, ``(b, max_len, kv_rank)`` and ``(b, max_len,
+rope_dim)``. It attends in latent space: ``q_lat = q_nope W_kvb[K]``,
+scores ``q_lat . c_kv + q_rope . k_r``, output ``probs . c_kv`` through
+``W_kvb[V]`` (``ops/latent_attention.py``). The single-token step walks
+the block table in a Pallas kernel where :func:`latent_kernel_mode`
+says so; windows (chunked prefill) gather the slot's pages and stay in
+latent space too. Without a cache (``decode=False``) keys and values
+are expanded as in step 4: the two must agree, and a test holds them to
+it.
 """
 
 from __future__ import annotations
@@ -49,10 +54,13 @@ import numpy as np
 from flax import linen as nn
 
 from rafiki_tpu.models.llama_lora import LoRADense, RMSNorm
-from rafiki_tpu.ops.latent_attention import (latent_decode_attention,
-                                             latent_gather_attention)
+from rafiki_tpu.ops.latent_attention import (copies_own_pages,
+                                             latent_decode_attention,
+                                             latent_gather_attention,
+                                             packed_key_rows,
+                                             packed_key_write)
 from rafiki_tpu.ops.moe import (MOE_COUNTERS, ExpertShare, KernelLeaf,
-                                book_moe_counters)
+                                book_moe_counters, sown_counters)
 from rafiki_tpu.ops.paged_attention import (kv_cache_write,
                                             resolve_paged_kernel)
 
@@ -103,6 +111,20 @@ def position_query_scale(positions: jnp.ndarray, beta: float,
     position below ``original_max``."""
     return 1.0 + beta * jnp.log1p(jnp.floor(
         positions.astype(jnp.float32) / original_max))
+
+
+#: what the latent attention counts on the device over single-token
+#: calls, after the expert layers' ``MOE_COUNTERS`` in the vector the
+#: engine carries: pool pages live under the slots' positions, summed
+#: over slots and layers, and pool pages those calls fetched
+LATENT_COUNTERS = ("latent_step_live_pages", "latent_step_page_fetches")
+
+
+def book_latent_counters(stats: Any, counts: Any) -> None:
+    """Add one pulled :data:`LATENT_COUNTERS` vector to a ``StatsMap`` —
+    each name a literal, as ``book_moe_counters`` has it and why."""
+    stats.inc("latent_step_live_pages", int(counts[0]))
+    stats.inc("latent_step_page_fetches", int(counts[1]))
 
 
 def latent_kernel_mode(kv_page_size: int, paged_kernel: Optional[bool]
@@ -197,10 +219,17 @@ class LatentAttention(nn.Module):
 
         live = decode and self.has_variable("cache", "kv")
         if decode:
-            paged = self.kv_page_size > 0
-            shape = ((self.kv_pages, self.kv_page_size, r + dr) if paged
-                     else (b, self.max_len, r + dr))
-            pool = self.variable("cache", "kv", jnp.zeros, shape, x.dtype)
+            paged, page = self.kv_page_size > 0, self.kv_page_size
+            if paged and page % 2:
+                raise ValueError(f"kv_page_size {page} must be even: the "
+                                 "rotary keys lie two positions a row")
+            lead = (self.kv_pages, page) if paged else (b, self.max_len)
+            lat = self.variable("cache", "kv", jnp.zeros, lead + (r,),
+                                x.dtype)
+            keys = self.variable(
+                "cache", "k_rope", jnp.zeros,
+                (self.kv_pages, page // 2, 2 * dr) if paged
+                else lead + (dr,), x.dtype)
         if not live:  # no cache, or the init trace (allocates only)
             o = expanded()
         else:
@@ -211,34 +240,48 @@ class LatentAttention(nn.Module):
                         "kv_page_size > 0 decode requires the "
                         "page_tables operand (the serving engine "
                         "supplies it)")
-                widx = (jnp.take_along_axis(
-                    page_tables, t // self.kv_page_size, axis=1),
-                    t % self.kv_page_size)
+                widx = (jnp.take_along_axis(page_tables, t // page, axis=1),
+                        t % page)
+                keys.value = packed_key_write(keys.value, *widx, k_r)
             else:
                 widx = (jnp.arange(b)[:, None], t)
-            pool.value = kv_cache_write(
-                pool.value, widx[0], widx[1],
-                jnp.concatenate([c_kv, k_r], -1))
+                keys.value = kv_cache_write(keys.value, *widx, k_r)
+            lat.value = kv_cache_write(lat.value, *widx, c_kv)
             # the softmax scale goes on in f32, BEFORE the one rounding
             # to the compute dtype: rounded itself it would tilt every
             # score the same way
-            q_lat = jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn],
-                               preferred_element_type=jnp.float32)
-            q_cat = (jnp.concatenate(
-                [q_lat, q_rope.astype(jnp.float32)], -1)
-                * sm_scale).astype(x.dtype)
-            if s == 1 and latent_kernel_mode(self.kv_page_size,
-                                             self.paged_kernel):
+            q_lat = (jnp.einsum("bshd,rhd->bshr", q_nope, w_kvb[..., :dn],
+                                preferred_element_type=jnp.float32)
+                     * sm_scale).astype(x.dtype)
+            q_rope = (q_rope.astype(jnp.float32) * sm_scale).astype(x.dtype)
+            step = paged and s == 1
+            kernel = step and latent_kernel_mode(page, self.paged_kernel)
+            if kernel:
                 o_lat = latent_decode_attention(
-                    q_cat[:, 0], pool.value, page_tables, t[:, 0],
-                    rank=r)[:, None]
+                    q_lat[:, 0], q_rope[:, 0], lat.value, keys.value,
+                    page_tables, t[:, 0])[:, None]
             else:
-                rows = pool.value
+                rows, key_rows = lat.value, keys.value
                 if paged:  # the live-width slice of the table only
                     rows = rows[page_tables].reshape(
-                        b, page_tables.shape[1] * self.kv_page_size,
-                        r + dr)
-                o_lat = latent_gather_attention(q_cat, rows, t, rank=r)
+                        b, page_tables.shape[1] * page, r)
+                    key_rows = packed_key_rows(key_rows, page_tables)
+                o_lat = latent_gather_attention(q_lat, q_rope, rows,
+                                                key_rows, t)
+            if step:
+                # pages a single-token call had to read, and pages it
+                # fetched: the live ones where the kernel copies its
+                # own, every table entry where the pipeline or the
+                # gather walks the table
+                live_pages = jnp.sum(t // page + 1, dtype=jnp.int32)
+                fetched = live_pages if kernel and copies_own_pages(
+                    lat.value, keys.value) else jnp.int32(
+                        b * page_tables.shape[1])
+                self.sow("counters", "latent",
+                         jnp.stack([live_pages, fetched]),
+                         init_fn=lambda: jnp.zeros(
+                             (len(LATENT_COUNTERS),), jnp.int32),
+                         reduce_fn=lambda a, b: a + b)
             o = jnp.einsum("bshr,rhd->bshd", o_lat, w_kvb[..., dn:])
         return dense(d, "wo")(o.reshape(b, s, nh * dv))
 
@@ -303,10 +346,18 @@ class LatentMoEDecoder(nn.Module):
 
     #: int32 counts the step and prefill programs hand back beside their
     #: outputs (the ``"counters"`` collection, summed over layers)
-    device_counters = MOE_COUNTERS
+    device_counters = MOE_COUNTERS + LATENT_COUNTERS
+
+    def fold_device_counters(self, sown: Any) -> jnp.ndarray:
+        """One ``apply``'s ``"counters"`` collection as one vector in
+        the order of ``device_counters``."""
+        return jnp.concatenate([
+            sown_counters(sown, "moe", len(MOE_COUNTERS)),
+            sown_counters(sown, "latent", len(LATENT_COUNTERS))])
 
     def book_device_counters(self, stats: Any, counts: Any) -> None:
-        book_moe_counters(stats, counts)
+        book_moe_counters(stats, counts[:len(MOE_COUNTERS)])
+        book_latent_counters(stats, counts[len(MOE_COUNTERS):])
 
     def paged_kernel_mode(self) -> int:
         return latent_kernel_mode(self.kv_page_size, self.paged_kernel)

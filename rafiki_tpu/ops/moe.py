@@ -207,6 +207,25 @@ def book_moe_counters(stats: Any, counts: Any) -> None:
     stats.inc("moe_step_row_tiles", int(counts[6]))
 
 
+def sown_counters(tree: Any, name: str, width: int) -> jnp.ndarray:
+    """The sum of every vector sown under ``name`` in a ``"counters"``
+    collection (zeros where no layer sowed one): how a module whose
+    layers sow vectors of several kinds folds them into the one vector
+    the engine carries (its ``fold_device_counters``)."""
+    total = jnp.zeros((width,), jnp.int32)
+
+    def visit(node: Any) -> None:
+        nonlocal total
+        for key, sub in node.items():
+            if key == name:
+                total = total + sum(jax.tree_util.tree_leaves(sub))
+            elif isinstance(sub, dict):
+                visit(sub)
+
+    visit(tree)
+    return total
+
+
 def route_top_k(logits: jnp.ndarray, top_k: int, renormalize: bool = True,
                 scaling: float = 1.0,
                 score_bias: Optional[jnp.ndarray] = None

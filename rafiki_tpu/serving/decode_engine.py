@@ -61,6 +61,7 @@ import numpy as np
 
 from ..obs.metrics import StatsMap
 from ..obs.trace import SPANS
+from ..ops.latent_attention import packed_key_write
 from ..ops.paged_attention import (resolve_paged_kernel,
                                    resolve_paged_window_kernel)
 from .kv_tier import HostPageTier
@@ -2597,18 +2598,23 @@ def _make_paged_prefix_install(plen: int, page_size: int) -> Callable:
     (1, plen, …) contiguous snapshot into the hit slots' PAGES —
     ``tabs`` is the (n_rows, n_tables) page-table slice of exactly the
     rows being installed, whose prefix pages the engine allocated at
-    admission. Cached by (length, page size) like its contiguous twin."""
+    admission. Cached by (length, page size) like its contiguous twin.
+    A leaf whose pages are not ``page_size`` rows long packs several
+    positions a row (the latent pool's rotary keys): the op that wrote
+    it places the rows."""
 
     @functools.partial(jax.jit, donate_argnums=(0,))
     def install(cache, pre, tabs):
         pos = jnp.arange(plen)
         pg = tabs[:, pos // page_size]   # (n_rows, plen) pool pages
-        off = pos % page_size            # (plen,) in-page offsets
+        off = jnp.broadcast_to(pos % page_size, pg.shape)  # in-page
 
         def put(c, p):
             vals = jnp.broadcast_to(
                 p[:, :plen].astype(c.dtype),
                 (tabs.shape[0], plen) + p.shape[2:])
+            if c.shape[1] != page_size:
+                return packed_key_write(c, pg, off, vals)
             return c.at[pg, off].set(vals)
 
         return jax.tree_util.tree_map(put, cache, pre)

@@ -9,7 +9,11 @@ program imported). Serving it through ``DecodeEngine`` is
 - the module's full forward equals the reference's logits;
 - attending in latent space through the cache (chunked prefill, then
   single-token steps, on the Pallas step kernel and on the page gather)
-  equals expand-then-attend; the step kernel equals the gather;
+  equals expand-then-attend; the step kernel equals the gather, on the
+  BlockSpec pipeline and on its own copies of live pages at the
+  positions, table widths and stale buffers that can go wrong; which of
+  the two fetches runs is what the leaves' shapes say, and the two
+  device counters say what was fetched;
 - YaRN frequencies, interleaved rotary pairs, the position-dependent query
   scale and the softmax scale against their formulas;
 - the shares add up: four ``experts_held`` shares of one layer, the shared
@@ -37,9 +41,12 @@ from benchmark.reference import latent_moe as ref
 from rafiki_tpu.models.latent_moe import (LatentMoEBlock,
                                           position_query_scale,
                                           rope_interleaved, yarn_inv_freq)
-from rafiki_tpu.ops import moe
-from rafiki_tpu.ops.latent_attention import (latent_decode_attention,
-                                             latent_gather_attention)
+from rafiki_tpu.ops import latent_attention, moe
+from rafiki_tpu.ops.latent_attention import (copies_own_pages,
+                                             latent_decode_attention,
+                                             latent_gather_attention,
+                                             packed_key_rows,
+                                             packed_key_write)
 from rafiki_tpu.serving import decode_engine
 
 YARN = {"beta_fast": 32, "beta_slow": 1, "factor": 128,
@@ -111,23 +118,144 @@ def test_latent_space_decode_equals_expand_then_attend(kernel):
                                np.asarray(want), atol=2e-5, rtol=1e-5)
 
 
-@pytest.mark.parametrize("pages_per_step", [None, 1, 2, 8])
-def test_latent_step_kernel_equals_gather(pages_per_step):
-    rng = np.random.default_rng(0)
-    b, heads, r, dr, page, n_tables, n_pages = 3, 4, 16, 8, 4, 8, 40
-    pool = jnp.asarray(rng.normal(size=(n_pages, page, r + dr)),
-                       jnp.float32)
-    tabs = jnp.asarray(rng.permutation(np.arange(1, n_pages))[
-        :b * n_tables].reshape(b, n_tables), jnp.int32)
-    t = jnp.asarray([0, 13, 31], jnp.int32)
-    q = jnp.asarray(rng.normal(size=(b, heads, r + dr)), jnp.float32) * .3
-    got = latent_decode_attention(q, pool, tabs, t, rank=r,
+def _pool(rng, b, r, dr, page, n_tables, dtype, big=()):
+    """A pool of ``b`` slots' pages under a shuffled block table: the
+    latents, the rotary keys in the packed leaf (written by the op the
+    model writes them with) AND as plain rows, for the oracle. Slots in
+    ``big`` hold rows 1e3 times the others'."""
+    n_pages = 1 + b * n_tables
+    lat = rng.normal(size=(n_pages, page, r))
+    key = rng.normal(size=(n_pages, page, dr))
+    tabs = rng.permutation(np.arange(1, n_pages)).reshape(b, n_tables)
+    for i in big:
+        lat[tabs[i]] *= 1e3
+        key[tabs[i]] *= 1e3
+    lat, key = jnp.asarray(lat, dtype), jnp.asarray(key, dtype)
+    where = np.indices((n_pages, page))
+    packed = packed_key_write(
+        jnp.zeros((n_pages, page // 2, 2 * dr), dtype),
+        jnp.asarray(where[0]), jnp.asarray(where[1]), key)
+    return lat, key, packed, jnp.asarray(tabs, jnp.int32)
+
+
+def _step_against_gather(lat, key, packed, tabs, t, heads, dtype, rng,
+                         pages_per_step, atol):
+    b, n_tables = tabs.shape
+    page, r, dr = lat.shape[1], lat.shape[2], key.shape[2]
+    t = jnp.asarray(t, jnp.int32)
+    q_lat = jnp.asarray(rng.normal(size=(b, heads, r)) * .3, dtype)
+    q_rope = jnp.asarray(rng.normal(size=(b, heads, dr)) * .3, dtype)
+    got = latent_decode_attention(q_lat, q_rope, lat, packed, tabs, t,
                                   pages_per_step=pages_per_step,
                                   interpret=True)
-    rows = pool[tabs].reshape(b, n_tables * page, r + dr)
-    want = latent_gather_attention(q[:, None], rows, t[:, None], r)[:, 0]
-    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
-                               atol=1e-5)
+    key_rows = packed_key_rows(packed, tabs)
+    np.testing.assert_array_equal(  # the packed leaf, unpacked
+        np.asarray(key_rows, np.float32),
+        np.asarray(key[tabs].reshape(b, n_tables * page, dr), np.float32))
+    want = latent_gather_attention(
+        q_lat[:, None], q_rope[:, None],
+        lat[tabs].reshape(b, n_tables * page, r), key_rows,
+        t[:, None])[:, 0]
+    np.testing.assert_allclose(np.asarray(got, np.float32),
+                               np.asarray(want, np.float32), atol=atol,
+                               rtol=atol)
+
+
+@pytest.mark.parametrize("pages_per_step", [None, 1, 2, 8])
+def test_latent_step_kernel_equals_gather(pages_per_step):
+    """The BlockSpec-pipeline fetch: widths no HBM slice could take."""
+    rng = np.random.default_rng(0)
+    lat, key, packed, tabs = _pool(rng, 3, 16, 8, 4, 8, jnp.float32)
+    assert not copies_own_pages(lat, packed)
+    _step_against_gather(lat, key, packed, tabs, [0, 13, 31], 4,
+                         jnp.float32, rng, pages_per_step, 1e-5)
+
+
+#: the kernel's own copies, pages of 32: two a step (64 positions), two
+#: steps a block (128). (table width, the three slots' positions, slots
+#: whose rows are huge)
+OWN_COPY_CASES = {
+    "position_0": (8, [0, 0, 0], ()),
+    "a_pages_last_row": (8, [31, 95, 32], ()),
+    "a_steps_last_row_and_its_first": (8, [63, 64, 191], ()),
+    "a_blocks_last_row": (8, [127, 255, 128], ()),
+    "a_blocks_first_row": (8, [128, 1, 129], ()),
+    "a_table_of_one_block": (4, [127, 5, 70], ()),
+    "a_table_no_multiple_of_the_block": (7, [223, 192, 100], ()),
+    # slot i long and slot i + 1 at position 0: the copy started a slot
+    # ahead is block 0 of the next slot
+    "long_then_position_0": (7, [223, 0, 130], ()),
+    # both buffers hold slot 0's huge rows when slot 1 meets a partly
+    # live step, and the call's first step is partly live over the
+    # zeroed buffers: dead rows meet probabilities of exactly 0
+    "stale_rows_of_another_slot": (8, [255, 33, 2], (0,)),
+    "zero_primed_first_block": (8, [2, 140, 0], ()),
+}
+
+
+@pytest.mark.parametrize("dtype,atol", [(jnp.float32, 2e-5),
+                                        (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(OWN_COPY_CASES))
+def test_latent_step_kernel_own_copies_equals_gather(case, dtype, atol,
+                                                     monkeypatch):
+    """The kernel's own DMAs of live pages, a block ahead, at leaves
+    whose minor dimensions fill the lanes (128 latent, 2 x 64 keys),
+    against the gather over the same leaves."""
+    monkeypatch.setattr(latent_attention, "ROWS_PER_BLOCK", 128)
+    n_tables, t, big = OWN_COPY_CASES[case]
+    rng = np.random.default_rng(sorted(OWN_COPY_CASES).index(case))
+    lat, key, packed, tabs = _pool(rng, 3, 128, 64, 32, n_tables, dtype,
+                                   big)
+    assert copies_own_pages(lat, packed)
+    _step_against_gather(lat, key, packed, tabs, t, 4, dtype, rng, 2,
+                         atol)
+
+
+def _attention_counts(r, dr, page, dtype, n_tables, positions):
+    """One single-token call of a ``LatentAttention`` layer through the
+    step kernel: the two counts it sows."""
+    from rafiki_tpu.models.latent_moe import LatentAttention
+
+    b = len(positions)
+    layer = LatentAttention(
+        n_heads=2, q_rank=8, kv_rank=r, nope_dim=8, rope_dim=dr, v_dim=8,
+        max_len=n_tables * page, kv_page_size=page,
+        kv_pages=1 + b * n_tables, paged_kernel=True)
+    x = jnp.ones((b, 1, 16), dtype)
+    pos = jnp.asarray(positions, jnp.int32)[:, None]
+    tabs = jnp.asarray(1 + np.arange(b * n_tables).reshape(b, n_tables),
+                       jnp.int32)
+    variables = layer.init(jax.random.PRNGKey(0), x, pos, True, tabs)
+    _, muts = layer.apply(variables, x, pos, True, tabs,
+                          mutable=["cache", "counters"])
+    cache = variables["cache"]
+    return (copies_own_pages(cache["kv"], cache["k_rope"]),
+            [int(c) for c in muts["counters"]["latent"]])
+
+
+def test_the_fetch_is_what_the_shapes_say_and_the_counters_what_ran():
+    """The kernel copies its own pages where both leaves fill the lanes
+    and a half page fills the sublane tiles — the published 256 + 2 x 64
+    in bf16 at pages of 32, 128 + 2 x 64 in f32 at pages of 16 — and
+    leaves the tiny widths, and half pages under a tile, to the
+    pipeline; the fetches counted are the live pages on the first and
+    every entry of the table on the second."""
+    spec = jax.ShapeDtypeStruct
+    for r, dr, page, dtype, own in (
+            (256, 64, 32, jnp.bfloat16, True),
+            (128, 64, 16, jnp.float32, True),
+            (256, 64, 16, jnp.bfloat16, False),   # 8 rows < a bf16 tile
+            (192, 32, 32, jnp.bfloat16, False),   # 2 x 32: half the lanes
+            (16, 8, 8, jnp.float32, False)):      # tiny-latent-moe
+        assert copies_own_pages(
+            spec((9, page, r), dtype),
+            spec((9, page // 2, 2 * dr), dtype)) == own, (r, dr, page)
+    positions = [0, 15, 16, 47]  # 1 + 1 + 2 + 3 live pages of 16
+    assert _attention_counts(128, 64, 16, jnp.float32, 4, positions) == (
+        True, [7, 7])
+    assert _attention_counts(16, 8, 16, jnp.float32, 4, positions) == (
+        False, [7, 4 * 4])
 
 
 # ----------------------------------------------------- rotary formulas

@@ -89,13 +89,14 @@ def test_engine_builds_where_init_would_not_fit():
     eng = DecodeEngine(module, None, max_slots=2, max_len=64)
     assert time.monotonic() - t0 < 60
     leaves = jax.tree_util.tree_leaves(eng._cache)
-    assert len(leaves) == 36
-    assert all(x.shape == (9, 16, 320) and x.dtype == jnp.bfloat16
-               and not x.any() for x in leaves)
+    # a layer's latents, and its rotary keys two positions a row
+    assert sorted(x.shape for x in leaves) == (
+        [(9, 8, 128)] * 36 + [(9, 16, 256)] * 36)
+    assert all(x.dtype == jnp.bfloat16 and not x.any() for x in leaves)
     assert eng.stats["kv_pool_bytes_per_token"] == 36 * 320 * 2
     assert eng.params is None and eng.stats["weight_bytes"] == 0
     eng.reset()  # the same path again
-    assert len(jax.tree_util.tree_leaves(eng._cache)) == 36
+    assert len(jax.tree_util.tree_leaves(eng._cache)) == 72
 
 
 def test_serving_form_identity_on_bf16_and_casts_stacked_f32():
@@ -249,6 +250,73 @@ def test_latent_pages_ship_and_park_unchanged():
     assert tiered.stats["kv_evictions_total"] > 0
 
 
+def test_kernels_own_copies_serve_the_gathers_tokens_through_page_moves():
+    """Leaves the step kernel can slice in HBM (256 latent values a row,
+    the 64-wide rotary keys two positions a row) under everything the
+    engine does with pages: the kernel's own copies emit the tokens the
+    gather emits through admission, release and reuse of pages (six
+    requests over three slots), a prefill-only shipment out of one engine
+    and into another (``_extract_slot_kv`` / ``_install_kv``), and slots
+    parked to a host tier and brought back."""
+    from rafiki_tpu.ops.latent_attention import copies_own_pages
+
+    cfg = tiny_cfg(kv_lora_rank=256, qk_rope_head_dim=64, qk_head_dim=72)
+    cfg["engine"].update(kv_page_size=16, paged_kernel=False)
+    gather, params = weights(cfg)
+    kernel = gather.clone(paged_kernel=True)
+    reqs = requests(cfg["vocab_size"])
+    _, want = serve(gather, params, reqs)
+
+    eng, got = serve(kernel, params, reqs)
+    leaves = eng._cache["block_0"]["attn"]
+    assert leaves["kv"].shape[1:] == (16, 256)
+    assert leaves["k_rope"].shape[1:] == (8, 128)
+    assert copies_own_pages(leaves["kv"], leaves["k_rope"])
+    assert got == want
+    s = eng.stats_snapshot()
+    assert s["paged_kernel_mode"] == 1
+    assert 0 < s["latent_step_live_pages"] == s["latent_step_page_fetches"]
+    # float32 here; the cell's bf16 leaves cost 640 B a layer
+    assert s["kv_pool_bytes_per_token"] == 2 * (256 + 64) * 4
+    served = DecodeEngine(kernel.clone(dtype=jnp.bfloat16), None,
+                          max_slots=2, max_len=kernel.max_len)
+    assert served.stats["kv_pool_bytes_per_token"] == 2 * 640
+
+    prefill = DecodeEngine(gather, params, max_slots=3,
+                           max_len=gather.max_len, prefill_chunk=8)
+    decode = DecodeEngine(kernel, params, max_slots=3,
+                          max_len=kernel.max_len, prefill_chunk=8)
+    for rid, (prompt, n) in enumerate(reqs):
+        prefill.submit(rid, prompt, n, prefill_only=True)
+    blobs = {}
+    while prefill.busy:
+        prefill.step()
+        blobs.update(dict(prefill.poll_kv()))
+    for rid, (prompt, n) in enumerate(reqs):
+        decode.submit(rid, prompt, n,
+                      kv_import=decode.stage_kv_blob(blobs[rid]))
+    shipped = {}
+    while decode.busy:
+        decode.step()
+        shipped.update(dict(decode.poll()))
+    assert shipped == want
+    assert decode.stats["kv_imports"] == len(reqs)
+    assert decode.stats["prefill_tokens"] == 0  # nothing recomputed
+
+    # a pool too small for every slot at once, a host tier behind it
+    tiered = DecodeEngine(kernel.clone(kv_pages=1 + 5), params, max_slots=3,
+                          max_len=kernel.max_len, prefill_chunk=8,
+                          host_kv_pages=12)
+    for rid, (prompt, n) in enumerate(reqs):
+        tiered.submit(rid, prompt, n)
+    parked = {}
+    while tiered.busy:
+        tiered.step()
+        parked.update(dict(tiered.poll()))
+    assert parked == want
+    assert tiered.stats["kv_evictions_total"] > 0
+
+
 @pytest.mark.parametrize("layout", ["paged", "contiguous"])
 def test_registered_prefix_installs_over_the_latent_pool(layout):
     """``register_prefix`` on a module that names device counters: the
@@ -270,9 +338,12 @@ def test_registered_prefix_installs_over_the_latent_pool(layout):
     eng = DecodeEngine(module, params, max_slots=3, max_len=module.max_len,
                        steps_per_sync=4, prefill_chunk=8)
     assert eng.register_prefix(prefix) == len(prefix)
+    # the snapshot is a contiguous cache's rows: keys unpacked, and
+    # packed two positions a row by the paged install
     snap = jax.tree_util.tree_leaves(eng._prefixes[0]["cache"])
-    assert all(x.shape == (1, len(prefix), cfg["kv_lora_rank"]
-                           + cfg["qk_rope_head_dim"]) for x in snap)
+    assert sorted(x.shape for x in snap) == sorted(
+        [(1, len(prefix), cfg["qk_rope_head_dim"]),
+         (1, len(prefix), cfg["kv_lora_rank"])] * 2)
     for rid, (prompt, n) in enumerate(reqs):
         eng.submit(rid, prompt, n)
     hit = {}

@@ -184,25 +184,44 @@ def test_patch_embed_fwd_bwd_compiles(spec):
     assert "tpu_custom_call" in text
 
 
-@pytest.mark.parametrize("page,n_tables", [(32, 64), (32, 1), (16, 128),
-                                           (16, 4)])
+@pytest.mark.parametrize("page,n_tables", [
+    (32, 64), (32, 1), (16, 128), (16, 4), (32, 8), (32, 16), (32, 32)])
 def test_latent_step_kernel_compiles(spec, page, n_tables):
     """The latent pool's single-token step at the widths the benchmark
-    serves: 64 slots, 32 heads, rows of 256 latent + 64 rotary-key
-    values (not a multiple of 128 lanes), a chunk of 256 rows a grid
-    step fetched as 8 or 16 pages side by side — and the narrowest
-    table slice the engine passes."""
-    from rafiki_tpu.ops.latent_attention import latent_decode_attention
+    serves: 64 slots, 32 heads, 256 latent values a row and the 64-wide
+    rotary keys two positions a row (128) — at every table width the
+    cell's engine passes and the narrowest. Pages of 32 leave both leaves in HBM (``pl.ANY``) for the
+    kernel's own copies; pages of 16 halve to 8 rows, under a bf16
+    sublane tile, and take the BlockSpec pipeline. Either way ONE custom
+    call named ``latent_attn_step``, and no copy of a pool leaf."""
+    from rafiki_tpu.ops.latent_attention import (PIPELINE_ROWS_PER_STEP,
+                                                 copies_own_pages,
+                                                 latent_decode_attention)
 
-    def step(q, pool, tabs, t):
-        return latent_decode_attention(q, pool, tabs, t, rank=256,
-                                       interpret=False)
+    def step(q_lat, q_rope, latents, keys, tabs, t):
+        return latent_decode_attention(q_lat, q_rope, latents, keys, tabs,
+                                       t, interpret=False)
 
+    n_pages = 1 + 64 * 2048 // page
+    latents = spec((n_pages, page, 256), jnp.bfloat16)
+    keys = spec((n_pages, page // 2, 128), jnp.bfloat16)
+    assert copies_own_pages(latents, keys) == (page == 32)
     text = _compiled_text(
-        step, spec((64, 32, 320), jnp.bfloat16),
-        spec((1 + 64 * 2048 // page, page, 320), jnp.bfloat16),
+        step, spec((64, 32, 256), jnp.bfloat16),
+        spec((64, 32, 64), jnp.bfloat16), latents, keys,
         spec((64, n_tables), jnp.int32), spec((64,), jnp.int32))
-    assert "latent_attn_step" in text and "tpu_custom_call" in text
+    assert text.count("tpu_custom_call") == 1
+    assert "latent_attn_step" in text
+    # a leaf is an operand ONCE where it stays in HBM (the whole of it
+    # would not fit VMEM) and once a page of the block on the pipeline
+    operands = text.split("operand_layout_constraints={")[1].split(
+        "custom_call_target")[0].split("frontend_attributes")[0]
+    times = 1 if page == 32 else min(n_tables,
+                                         PIPELINE_ROWS_PER_STEP // page)
+    assert operands.count(f"bf16[{n_pages},2,{page // 2},256]") == times
+    assert operands.count(f"bf16[{n_pages},{page // 2},128]") == times
+    assert not [line for line in text.splitlines()
+                if " copy(" in line and f"[{n_pages}," in line]
 
 
 @pytest.mark.parametrize("rows", [64, 256, 4096, 16384], ids=[
