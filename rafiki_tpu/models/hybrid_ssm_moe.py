@@ -1,12 +1,14 @@
-"""A decoder driven by a per-layer PATTERN over three kinds of layer —
-``M`` a state-space (Mamba-2) mixer, ``*`` attention, ``E`` routed
-experts in a latent narrower than the model — served by ``DecodeEngine``
-through the call it makes of every decoder (``ids, positions=,
-decode=True, page_tables=``, mutable ``cache``) and the two operands it
-gives a module that declares PER-SLOT STATE (``slot_state``):
-``slot_ids`` (which slot each row of the call belongs to) and
-``row_tokens`` (how many of the row's tokens are real; 0 = leave the
-slot's state alone). Every size is a field; nothing here names a model.
+"""A decoder driven by a per-layer PATTERN over five kinds of layer —
+``M`` a state-space (Mamba-2) mixer; ``*``, ``R`` and ``W`` attention
+(without a position embedding; with a rotary table; with a rotary table
+and a WINDOW); ``E`` routed experts, in a latent narrower than the model
+or at its width — served by ``DecodeEngine`` through the call it makes
+of every decoder (``ids, positions=, decode=True, page_tables=``,
+mutable ``cache``) and the two operands it gives a module that declares
+PER-SLOT STATE (``slot_state``): ``slot_ids`` (which slot each row of
+the call belongs to) and ``row_tokens`` (how many of the row's tokens
+are real; 0 = leave the slot's state alone). Every size is a field;
+nothing here names a model.
 
 A layer is ``x + f(norm(x))`` with ONE mixer or one feed-forward part
 (RMSNorm, no bias on any linear):
@@ -22,24 +24,38 @@ A layer is ``x + f(norm(x))`` with ONE mixer or one feed-forward part
 - ``*``: grouped-query causal attention, scale ``head_dim^-0.5``, NO
   rotary embedding; K / V through the paged pool and the two paged
   kernels of ``ops/paged_attention.py``.
+- ``R``: the same with q and k turned by a rotary table first
+  (half-split pairs; the table, ``rope_full``, is plain ``theta`` or
+  YaRN's blend, its cos and sin times an attention factor).
+- ``W``: the same with a table of its own (``rope_window``) and a
+  WINDOW: the query at ``i`` sees the keys ``i - window < j <= i``. It
+  keeps no pages: its K / V live in a ring of ``kv_ring`` positions a
+  SLOT (``ops/window_attention.py``), read by the paged kernels'
+  windowed forms, which fetch only what intersects the window.
 - ``E``: router in float32 on ``h`` (``ops/moe.py`` ``ExpertShare``: the
   ``top_k`` largest of ``sigmoid + bias``, weighed by their sigmoids over
-  their sum, times ``routed_scaling``); ``u = h W_down`` into the
-  latent; the routed experts HELD here on ``u`` (two kernels with
-  ``relu(.)^2`` between, by grouped products); ``r W_up`` back to the
-  model's width; plus the shared expert ``W2 relu(W1 h)^2`` at the
-  model's width. ``W_up`` is linear, so the chips' ``r W_up`` add up to
-  the whole; the shared expert, the router and both latent projections
-  are on every chip alike.
+  their sum, times ``routed_scaling`` — or, ``sigmoid_scores`` off, of a
+  softmax); ``u = h W_down`` into the latent (``latent_dim`` 0: ``u =
+  h``); the routed experts HELD here on ``u`` (two kernels with
+  ``relu(.)^2`` between, or ``experts_gated`` SwiGLU's three, by grouped
+  products); ``r W_up`` back to the model's width; plus, where
+  ``shared_dim``, the shared expert ``W2 relu(W1 h)^2`` at the model's
+  width. ``W_up`` is linear, so the chips' ``r W_up`` add up to the
+  whole; the shared expert, the router and both latent projections are
+  on every chip alike.
 
-**The cache** holds two kinds of leaves: the attention layers' paged
-pools (``k``, ``v``: (kv_pages, kv_page_size, kv heads, head dim),
-indexed by page, page 0 scratch) and per ``M`` layer a recurrent state
-``ssm`` (slots + 1, heads, head dim, state) float32 and a convolution
-tail ``conv`` (slots + 1, conv_width - 1, channels), indexed by SLOT, the
-last row scratch. What makes a recurrence safe under an engine that pads
-rows, keeps empty lanes stepping and deals one prompt's consecutive
-chunks to the rows of one prefill call:
+**The cache** holds two kinds of leaves: the ``*`` and ``R`` layers'
+paged pools (``k``, ``v``: (kv_pages, kv_page_size, kv heads, head dim),
+indexed by page, page 0 scratch), and leaves indexed by SLOT, the last
+row scratch — per ``M`` layer a recurrent state ``ssm`` (slots + 1,
+heads, head dim, state) float32 and a convolution tail ``conv`` (slots +
+1, conv_width - 1, channels), per ``W`` layer the rings ``ring_k``,
+``ring_v`` (slots + 1, kv_ring, kv heads, head dim). A ring needs none
+of the care below: a token that is not real writes to the scratch row,
+and what a slot's last request left lies above the next one's positions
+until it is overwritten. What makes a recurrence safe under an engine
+that pads rows, keeps empty lanes stepping and deals one prompt's
+consecutive chunks to the rows of one prefill call:
 
 - a padded token has ``dt`` = 0, so it advances nothing, and writes its
   keys to the scratch page; a row with no real token reads and writes
@@ -58,10 +74,12 @@ from typing import Any, NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from flax import linen as nn
 
+from rafiki_tpu.models.latent_moe import yarn_inv_freq
 from rafiki_tpu.models.llama_lora import (LoRADense, RMSNorm,
-                                          _masked_decode_attention)
+                                          _masked_decode_attention, rope)
 from rafiki_tpu.ops.moe import (MOE_COUNTERS, ExpertShare,
                                 book_moe_counters, sown_counters)
 from rafiki_tpu.ops.paged_attention import (kv_cache_write,
@@ -70,6 +88,8 @@ from rafiki_tpu.ops.paged_attention import (kv_cache_write,
                                             resolve_paged_kernel,
                                             resolve_paged_window_kernel)
 from rafiki_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssm_state_step
+from rafiki_tpu.ops.window_attention import (ring_write,
+                                             window_ring_attention)
 
 #: what the state-space layers count on the device, after the expert
 #: layers' ``MOE_COUNTERS`` in the vector the engine carries: (row,
@@ -85,6 +105,25 @@ def book_ssm_counters(stats: Any, counts: Any) -> None:
     stats.inc("ssm_step_rows", int(counts[0]))
     stats.inc("ssm_prefill_rows", int(counts[1]))
     stats.inc("ssm_rows_chained", int(counts[2]))
+
+
+#: what the rotary attention layers count on the device over
+#: single-token calls, after the counters above in a pattern that has
+#: such layers: keys live under the window of every (real row, ``W``
+#: layer), keys the ``W`` layers' step fetched for them (counted inside
+#: ``window_attn_step``, a page where its copy starts; off the TPU the
+#: ring the masked form is handed), and keys live under every (real row,
+#: ``R`` layer)
+WINDOW_COUNTERS = ("win_step_live_keys", "win_step_keys_fetched",
+                   "full_step_live_keys")
+
+
+def book_window_counters(stats: Any, counts: Any) -> None:
+    """Add one pulled :data:`WINDOW_COUNTERS` vector to a ``StatsMap`` —
+    each name a literal, as ``book_moe_counters`` has it and why."""
+    stats.inc("win_step_live_keys", int(counts[0]))
+    stats.inc("win_step_keys_fetched", int(counts[1]))
+    stats.inc("full_step_live_keys", int(counts[2]))
 
 
 class Rows(NamedTuple):
@@ -243,10 +282,28 @@ class Mamba2Mixer(nn.Module):
         return y.reshape((b, s + pad) + y.shape[2:])[:, :s]
 
 
-class PlainAttention(nn.Module):
-    """Grouped-query causal attention with NO position embedding; through
-    the cache, K / V live in a paged pool (page 0 scratch: where a token
-    that is not real writes)."""
+def rotary_table(dim: int, theta: float,
+                 yarn: Optional[Tuple[float, int, float, float]]
+                 ) -> np.ndarray:
+    """The ``dim / 2`` frequencies of a layer kind's rotary embedding:
+    ``theta^(-2i/dim)``, or with ``yarn`` = (factor, original max
+    positions, beta fast, beta slow) YaRN's blend of those and the same
+    over ``factor`` (``latent_moe.yarn_inv_freq``)."""
+    if yarn is None:
+        return (theta ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+                ).astype(np.float32)
+    return yarn_inv_freq(dim, theta, *yarn)
+
+
+class PatternAttention(nn.Module):
+    """Grouped-query causal attention of the pattern's three kinds.
+    ``rope`` None: no position embedding (``*``); else q and k are turned
+    by the table of ``rope`` = (theta, YaRN's four numbers or None, a
+    factor on cos and sin), half-split pairs (``R``, ``W``). ``window``
+    0: every key at or before the query's, K / V in a paged pool (page 0
+    scratch: where a token that is not real writes); else the last
+    ``window`` keys, K / V in a ring of ``kv_ring`` positions a slot
+    (``ops/window_attention.py``; the scratch row takes those tokens)."""
 
     n_heads: int
     n_kv_heads: int
@@ -254,6 +311,10 @@ class PlainAttention(nn.Module):
     kv_page_size: int = 0
     kv_pages: int = 0
     paged_kernel: Optional[bool] = None
+    rope: Optional[Tuple[float, Optional[Tuple], float]] = None
+    window: int = 0
+    kv_ring: int = 0
+    max_len: int = 0
 
     @nn.compact
     def __call__(self, h: jnp.ndarray, positions: jnp.ndarray, decode: bool,
@@ -264,29 +325,57 @@ class PlainAttention(nn.Module):
         q = LoRADense(nh * dh, 0, name="wq")(h).reshape(b, s, nh, dh)
         k = LoRADense(nkv * dh, 0, name="wk")(h).reshape(b, s, nkv, dh)
         v = LoRADense(nkv * dh, 0, name="wv")(h).reshape(b, s, nkv, dh)
+        if self.rope is not None:
+            theta, yarn, scale = self.rope
+            table = rotary_table(dh, theta, yarn)
+            q = rope(q, positions, inv_freq=table, scale=scale)
+            k = rope(k, positions, inv_freq=table, scale=scale)
         rep, sm = nh // nkv, dh ** -0.5
-        live = decode and self.has_variable("cache", "k")
+        ringed = self.window > 0
+        leaves = ("ring_k", "ring_v") if ringed else ("k", "v")
+        live = decode and self.has_variable("cache", leaves[0])
         if decode:
             if self.kv_page_size <= 0:
                 raise ValueError("per-slot state is served beside a PAGED "
                                  "pool: kv_page_size must be > 0")
-            shape = (self.kv_pages, self.kv_page_size, nkv, dh)
-            ck = self.variable("cache", "k", jnp.zeros, shape, h.dtype)
-            cv = self.variable("cache", "v", jnp.zeros, shape, h.dtype)
+            shape = ((b + 1, self.kv_ring) if ringed else
+                     (self.kv_pages, self.kv_page_size)) + (nkv, dh)
+            ck = self.variable("cache", leaves[0], jnp.zeros, shape, h.dtype)
+            cv = self.variable("cache", leaves[1], jnp.zeros, shape, h.dtype)
+        t = positions
+        counts = [0, 0, 0]  # WINDOW_COUNTERS, of a single-token call
         if not live:  # no cache, or the init trace (allocates only)
             scores = jnp.einsum(
                 "bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, axis=2),
                 preferred_element_type=jnp.float32) * sm
-            seen = positions[:, None, None, :] <= positions[:, None, :, None]
+            seen = t[:, None, None, :] <= t[:, None, :, None]
+            if ringed:
+                seen &= t[:, None, None, :] > t[:, None, :, None] \
+                    - self.window
             probs = jax.nn.softmax(jnp.where(seen, scores, -1e30), -1)
             o = jnp.einsum("bhqk,bkhd->bqhd", probs.astype(h.dtype),
                            jnp.repeat(v, rep, axis=2))
+        elif ringed:
+            # a row with nothing to advance reads and writes the scratch
+            # row, as a token that is not real writes there
+            has = rows.n_real > 0
+            slots = jnp.where(has, rows.slots, ck.value.shape[0] - 1)
+            ck.value = ring_write(ck.value, slots, t, rows.real, k)
+            cv.value = ring_write(cv.value, slots, t, rows.real, v)
+            kernel = (resolve_paged_kernel(self.paged_kernel) if s == 1
+                      else resolve_paged_window_kernel(self.paged_kernel))
+            o, fetched = window_ring_attention(
+                q, ck.value, cv.value, slots, t, self.window,
+                self.kv_page_size, self.max_len, sm, kernel)
+            if s == 1:
+                counts[0] = jnp.sum(jnp.where(
+                    has, jnp.minimum(t[:, 0] + 1, self.window), 0))
+                counts[1] = jnp.sum(jnp.where(has, fetched, 0))
         else:
             if page_tables is None:
                 raise ValueError("kv_page_size > 0 decode requires the "
                                  "page_tables operand (the serving engine "
                                  "supplies it)")
-            t = positions
             page = jnp.take_along_axis(page_tables, t // self.kv_page_size,
                                        axis=1)
             # a token that is not real (padding, a lane with nothing to
@@ -303,8 +392,8 @@ class PlainAttention(nn.Module):
                     q[:, 0], ck.value, cv.value, page_tables, t[:, 0],
                     sm_scale=sm)[:, None]
             elif resolve_paged_window_kernel(self.paged_kernel):
-                o = paged_window_attention(q, ck.value, cv.value,
-                                           page_tables, t, sm_scale=sm)
+                o = paged_window_attention(
+                    q, ck.value, cv.value, page_tables, t, sm_scale=sm)
             else:
                 def gathered(c):
                     return jnp.repeat(c[page_tables].reshape(
@@ -314,6 +403,15 @@ class PlainAttention(nn.Module):
                 o = _masked_decode_attention(
                     q, gathered(ck.value), gathered(cv.value), t, dh,
                     h.dtype)
+            if s == 1 and self.rope is not None:
+                counts[2] = jnp.sum(jnp.where(rows.n_real > 0,
+                                              t[:, 0] + 1, 0))
+        if self.rope is not None:  # the kinds that WINDOW_COUNTERS count
+            self.sow("counters", "win", jnp.stack(
+                [jnp.asarray(c, jnp.int32) for c in counts]),
+                init_fn=lambda: jnp.zeros((len(WINDOW_COUNTERS),),
+                                          jnp.int32),
+                reduce_fn=lambda u, w: u + w)
         return LoRADense(d, 0, name="wo")(o.reshape(b, s, nh * dh))
 
 
@@ -359,8 +457,8 @@ class _Layer(nn.Module):
         fields = dict(self.fields)
         if self.kind == "M":
             y = Mamba2Mixer(**fields, name="mixer")(h, decode, rows)
-        elif self.kind == "*":
-            y = PlainAttention(**fields, name="mixer")(
+        elif self.kind in "*RW":
+            y = PatternAttention(**fields, name="mixer")(
                 h, positions, decode, page_tables, rows)
         else:
             y = LatentExperts(**fields, name="mixer")(h)
@@ -369,11 +467,13 @@ class _Layer(nn.Module):
 
 class HybridSSMMoEDecoder(nn.Module):
     """Decoder-only LM whose layer ``i`` is ``layer_pattern[i]``: ``M`` a
-    :class:`Mamba2Mixer`, ``*`` a :class:`PlainAttention`, ``E`` a
-    :class:`LatentExperts`; untied head. ``experts_held = (first id,
-    count)`` is this chip's share of each ``E`` layer's ``n_experts``
-    routed experts (count 0 = all); the router stays ``n_experts``
-    wide."""
+    :class:`Mamba2Mixer`; ``*``, ``R``, ``W`` a :class:`PatternAttention`
+    (no rotary table; the table ``rope_full``; the table ``rope_window``
+    and the ``window``, its keys in a ring of ``kv_ring`` positions a
+    slot); ``E`` a :class:`LatentExperts`; untied head. ``experts_held =
+    (first id, count)`` is this chip's share of each ``E`` layer's
+    ``n_experts`` routed experts (count 0 = all); the router stays
+    ``n_experts`` wide."""
 
     vocab_size: int
     max_len: int
@@ -394,6 +494,20 @@ class HybridSSMMoEDecoder(nn.Module):
     experts_held: Tuple[int, int] = (0, 0)
     renormalize_gates: bool = True
     routed_scaling: float = 1.0
+    #: the ``E`` layers' rule: SwiGLU experts of three kernels, or two
+    #: with ``relu(.)^2`` between; the ``top_k`` largest of ``sigmoid +
+    #: bias``, or of a softmax
+    experts_gated: bool = False
+    sigmoid_scores: bool = True
+    #: the ``R`` and the ``W`` layers' rotary tables, each (theta,
+    #: YaRN's (factor, original max positions, beta fast, beta slow) or
+    #: None, the factor on cos and sin)
+    rope_full: Tuple[float, Optional[Tuple], float] = (10000.0, None, 1.0)
+    rope_window: Tuple[float, Optional[Tuple], float] = (10000.0, None, 1.0)
+    #: keys a ``W`` layer's query sees, and the positions a slot's ring
+    #: holds (``ops.window_attention.ring_positions``)
+    window: int = 0
+    kv_ring: int = 0
     conv_width: int = 4
     chunk_size: int = 128
     eps: float = 1e-5
@@ -406,21 +520,51 @@ class HybridSSMMoEDecoder(nn.Module):
     #: the cache leaves that are indexed by SLOT (one row a slot and a
     #: scratch row), not by position: a module that names any is handed
     #: ``slot_ids`` and ``row_tokens`` in every decode-path call
-    slot_state = ("ssm", "conv")
-    #: int32 counts the step and prefill programs hand back beside their
-    #: outputs (the ``"counters"`` collection, summed over layers)
-    device_counters = MOE_COUNTERS + SSM_COUNTERS
+    slot_state = ("ssm", "conv", "ring_k", "ring_v")
+    #: of those, the ``W`` layers' rings: keys and values, not a state
+    #: (the engine's ``window_kv_bytes_per_slot`` gauge)
+    window_state = ("ring_k", "ring_v")
+
+    @property
+    def _rotary(self) -> bool:
+        return any(kind in "RW" for kind in self.layer_pattern)
+
+    @property
+    def device_counters(self) -> Tuple[str, ...]:
+        """int32 counts the step and prefill programs hand back beside
+        their outputs (the ``"counters"`` collection, summed over
+        layers); :data:`WINDOW_COUNTERS` where the pattern has a layer
+        that counts them."""
+        return MOE_COUNTERS + SSM_COUNTERS + (
+            WINDOW_COUNTERS if self._rotary else ())
 
     def fold_device_counters(self, sown: Any) -> jnp.ndarray:
         """One ``apply``'s ``"counters"`` collection as one vector in
         the order of ``device_counters``."""
         return jnp.concatenate([
             sown_counters(sown, "moe", len(MOE_COUNTERS)),
-            sown_counters(sown, "ssm", len(SSM_COUNTERS))])
+            sown_counters(sown, "ssm", len(SSM_COUNTERS))] + (
+            [sown_counters(sown, "win", len(WINDOW_COUNTERS))]
+            if self._rotary else []))
 
     def book_device_counters(self, stats: Any, counts: Any) -> None:
-        book_moe_counters(stats, counts[:len(MOE_COUNTERS)])
-        book_ssm_counters(stats, counts[len(MOE_COUNTERS):])
+        n_moe, n_ssm = len(MOE_COUNTERS), len(SSM_COUNTERS)
+        book_moe_counters(stats, counts[:n_moe])
+        book_ssm_counters(stats, counts[n_moe:n_moe + n_ssm])
+        if self._rotary:
+            book_window_counters(stats, counts[n_moe + n_ssm:])
+
+    def ring_holds_call(self, call_tokens: int) -> None:
+        """Raise unless a slot's ring takes the ``call_tokens`` one call
+        may write for the slot beside the window the call's first row
+        still reads (``DecodeEngine`` asks at construction)."""
+        if "W" in self.layer_pattern \
+                and self.kv_ring < self.window + call_tokens:
+            raise ValueError(
+                f"kv_ring {self.kv_ring} is shorter than the window "
+                f"{self.window} plus the {call_tokens} tokens one call "
+                "may write for a slot: a later row's keys would land on "
+                "keys an earlier row still reads")
 
     def layer_fields(self, kind: str) -> Tuple[Tuple[str, Any], ...]:
         """The fields a layer of ``kind`` builds its mixer from, as
@@ -431,24 +575,37 @@ class HybridSSMMoEDecoder(nn.Module):
                 n_groups=self.ssm_groups, state_dim=self.ssm_state,
                 conv_width=self.conv_width, chunk_size=self.chunk_size,
                 eps=self.eps)
-        elif kind == "*":
+        elif kind in "*RW":
             fields = dict(
                 n_heads=self.n_heads, n_kv_heads=self.n_kv_heads,
                 head_dim=self.head_dim, kv_page_size=self.kv_page_size,
                 kv_pages=self.kv_pages, paged_kernel=self.paged_kernel)
+            if kind == "R":
+                fields.update(rope=tuple(self.rope_full))
+            elif kind == "W":
+                if self.window < 1 or (self.kv_page_size > 0 and (
+                        self.kv_ring < self.window
+                        or self.kv_ring % self.kv_page_size)):
+                    raise ValueError(
+                        f"a 'W' layer needs window >= 1 ({self.window}) "
+                        f"and a ring ({self.kv_ring}) of whole pages "
+                        "that holds it")
+                fields.update(rope=tuple(self.rope_window),
+                              window=self.window, kv_ring=self.kv_ring,
+                              max_len=self.max_len)
         elif kind == "E":
             fields = dict(
                 expert_fields=tuple(dict(
                     n_experts=self.n_experts, top_k=self.experts_per_token,
                     mlp_dim=self.expert_dim, held=tuple(self.experts_held),
                     renormalize=self.renormalize_gates,
-                    scaling=self.routed_scaling, gated=False,
-                    sigmoid_scores=True).items()),
+                    scaling=self.routed_scaling, gated=self.experts_gated,
+                    sigmoid_scores=self.sigmoid_scores).items()),
                 latent_dim=self.latent_dim, shared_dim=self.shared_dim)
         else:
             raise ValueError(
                 f"layer_pattern {self.layer_pattern!r}: a layer is 'M', "
-                f"'E' or '*', not {kind!r}")
+                f"'E', '*', 'R' or 'W', not {kind!r}")
         return tuple(fields.items())
 
     @nn.compact

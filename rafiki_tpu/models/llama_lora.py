@@ -73,10 +73,15 @@ TP_RULES = {"experts": 0,
 
 def rope(x: jnp.ndarray, positions: jnp.ndarray,
          theta: float = 10000.0,
-         scaling: Optional[Tuple[float, float, float, float]] = None
+         scaling: Optional[Tuple[float, float, float, float]] = None,
+         inv_freq: Optional[np.ndarray] = None, scale: float = 1.0
          ) -> jnp.ndarray:
     """Rotary embedding over (b, s, heads, head_dim) with (b, s)
-    positions.
+    positions: the half-split pairs ``(i, i + head_dim / 2)``.
+
+    ``inv_freq`` (head_dim / 2,) puts an explicit frequency table in
+    ``theta``'s place (a layer kind's own: YaRN's blend, say), and
+    ``scale`` multiplies cos and sin (YaRN's attention factor).
 
     ``scaling`` applies Llama-3.1-style frequency-dependent NTK
     scaling: ``(factor, low_freq_factor, high_freq_factor,
@@ -87,7 +92,10 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray,
     pretrained context window without retraining the short-range
     geometry."""
     half = x.shape[-1] // 2
-    freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
+    if inv_freq is not None:
+        freqs = jnp.asarray(inv_freq, jnp.float32)
+    else:
+        freqs = theta ** (-jnp.arange(0, half, dtype=jnp.float32) / half)
     if scaling is not None:
         factor, low_f, high_f, orig_len = scaling
         # ratio = original_context / wavelength (wavelength = 2π/freq)
@@ -102,6 +110,8 @@ def rope(x: jnp.ndarray, positions: jnp.ndarray,
     angles = positions[..., None].astype(jnp.float32) * freqs  # (b, s, half)
     cos = jnp.cos(angles)[:, :, None, :]
     sin = jnp.sin(angles)[:, :, None, :]
+    if scale != 1.0:
+        cos, sin = cos * scale, sin * scale
     x1, x2 = x[..., :half], x[..., half:]
     return jnp.concatenate(
         [x1 * cos - x2 * sin, x1 * sin + x2 * cos],

@@ -71,6 +71,14 @@ the engine's prefill/verify windows provide (idle and overhang rows
 repeat the last real entry) — so a query tile's last row bounds its
 live pages and dead-page skipping carries over per tile.
 
+Both kernels take a static ``window``: a query then sees only the last
+``window`` keys, and the grid's last axis walks only the blocks (step)
+or pages (query window) that can hold them, from the one with the
+window's first key — what lies behind the window is neither fetched nor
+read. They are then ``window_attn_step`` / ``window_attn_prefill`` in a
+profile, and ``ops/window_attention.py`` calls them over per-slot rings;
+``window=None`` lowers to the program it was.
+
 Dispatch policy (mirrors ``ops/attention.py``): the decode path runs
 the kernel on TPU by default and falls back to the page gather off-TPU
 (``resolve_paged_kernel``); multi-token windows additionally honor the
@@ -278,6 +286,18 @@ def _copies_own_pages(dh: int) -> bool:
     return dh % 128 == 0
 
 
+def _window_start(t, window: int):
+    """The first key position a query at ``t`` sees through a window of
+    ``window`` keys: ``t - window + 1``, held to 0."""
+    return jnp.maximum(t - (window - 1), 0)
+
+
+def _window_blocks(window: int, span: int, n_blocks: int) -> int:
+    """Blocks of ``span`` key positions a window of ``window`` keys can
+    touch wherever it starts, within the table's ``n_blocks``."""
+    return min(n_blocks, -(-(window - 1) // span) + 1)
+
+
 def _tile_scales(s_ref, h0, block_h: int):
     """The (page_size, block_h) dequant scales of this program's head
     tile: the whole block when the tile is the kv axis (``h0`` static
@@ -291,7 +311,8 @@ def _tile_scales(s_ref, h0, block_h: int):
 def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
                          sm_scale: float, page_size: int, block_h: int,
                          rep: int, pages: int, n_blocks: int,
-                         quantized: bool, own_copies: bool):
+                         quantized: bool, own_copies: bool,
+                         window: Optional[int] = None):
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -304,11 +325,42 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
     if quantized:
         ks_refs, vs_refs, rest = (rest[:pages], rest[pages:2 * pages],
                                   rest[2 * pages:])
-    o_ref, m_scr, l_scr, acc_scr, *copy_scr = rest
+    o_ref, *rest = rest
+    count_fetched = window is not None
+    if count_fetched:  # a second result: pages fetched, slot by slot
+        cnt_ref, *rest = rest
+    m_scr, l_scr, acc_scr, *copy_scr = rest
     bi, kh, blk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     h0 = _tile_first_head(block_h, ks_refs[0])
     t = t_ref[bi]  # this slot's query position (keys k_pos <= t live)
     span = pages * page_size  # key positions a block covers
+    # with a ``window`` the grid's block axis starts at the block that
+    # holds the window's first key (``_window_start``) and is only as
+    # long as a window can span: ``lblk`` is the block of the TABLE
+    lblk = blk if window is None else \
+        _window_start(t, window) // span + blk
+
+    def live_pages(b_, blk_):
+        """Of block ``blk_`` of slot ``b_``'s walk: its first page in
+        the TABLE, and the pages ``[below, n_live)`` of it that hold a
+        key the slot's query sees."""
+        if window is None:
+            first, below = blk_ * pages, 0
+        else:  # the pages below the window's first are not fetched
+            lo = _window_start(t_ref[b_], window)
+            first = (lo // span + blk_) * pages
+            below = jnp.clip(lo // page_size - first, 0, pages)
+        n_live = jnp.clip(t_ref[b_] // page_size + 1 - first, 0, pages)
+        return first, below, n_live
+
+    if count_fetched:
+        @pl.when((bi == 0) & (kh == 0) & (blk == 0))
+        def _zero():
+            def zero(i, carry):
+                cnt_ref[i] = 0
+                return carry
+
+            jax.lax.fori_loop(0, pl.num_programs(0), zero, 0)
 
     if own_copies:
         k_buf, v_buf, sems, slot_scr = copy_scr
@@ -319,8 +371,9 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
             pages into buffer ``slot``: K and V of every page up to the
             slot's last live one, each a (page_size, block_h, dh) DMA
             straight out of the pool by the block table."""
-            first = blk_ * pages
-            n_live = jnp.clip(t_ref[b_] // page_size + 1 - first, 0, pages)
+            first, below, n_live = live_pages(b_, blk_)
+            if count_fetched and act == "start":  # counted where copied
+                cnt_ref[b_] += jnp.maximum(n_live - below, 0)
 
             def page(j, carry):
                 entry = tab_ref[b_, first + j]
@@ -331,7 +384,7 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
                         buf.at[slot, j], sem), act)()
                 return carry
 
-            jax.lax.fori_loop(0, n_live, page, 0)
+            jax.lax.fori_loop(below, n_live, page, 0)
 
         @pl.when((bi == 0) & (kh == 0) & (blk == 0))
         def _prime():  # nothing fetched the call's first block yet.
@@ -348,7 +401,7 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(blk * span <= t)
+    @pl.when(lblk * span <= t)
     def _partial():  # dead blocks: no compute, and nothing fetched
         # (own copies skip them; the pipeline's index maps collapse
         # their pages onto the scratch page and elide the fetch)
@@ -359,7 +412,7 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
             # double buffering across grid steps: start the NEXT live
             # block's copies (this row's next block, else block 0 of the
             # next row, which is always live), then wait for this one's
-            more = (blk + 1) * span <= t
+            more = (lblk + 1) * span <= t
             row_end = kh == n_tiles - 1
             nxt = (jnp.where(more | ~row_end, bi, bi + 1),
                    jnp.where(more, kh, jnp.where(row_end, 0, kh + 1)),
@@ -376,6 +429,9 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
         else:
             k_pages = [ref.at[0] for ref in k_refs]
             v_pages = [ref.at[0] for ref in v_refs]
+            if count_fetched:  # what the index maps did not collapse
+                _, below, n_live = live_pages(bi, blk)
+                cnt_ref[bi] += jnp.maximum(n_live - below, 0)
 
         def block(page_refs, scale_refs):
             # the block's pages as ONE 2-D matrix, row = key x head in
@@ -400,8 +456,10 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
         row = jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
         # masks the dead pages of a partly live block, the last live
         # page's tail AND any speculative-overwrite rows above t
-        live = (blk * span + col // block_h <= t) & (
+        live = (lblk * span + col // block_h <= t) & (
             col % block_h == row // rep)
+        if window is not None:  # and the keys behind the window
+            live &= lblk * span + col // block_h > t - window
         s = jnp.where(live, s, NEG_INF)
 
         m_prev = m_scr[...]  # (n_q, 1) running max
@@ -424,8 +482,8 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
                            sm_scale: float,
                            k_scale=None, v_scale=None,
                            block_h: Optional[int] = None,
-                           interpret: Optional[bool] = None
-                           ) -> jnp.ndarray:
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None):
     """Single-token decode attention straight off a paged KV pool: a
     grid step takes a BLOCK of a slot's pool pages (``BLOCK_KEYS`` key
     positions; a narrower table is one block) over every kv head of its
@@ -444,6 +502,20 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
     - ``positions``: (b,) int32 query positions; keys ``k_pos <=
       positions[i]`` are visible to slot i (the decode-branch mask).
       Held to ``[0, n_tables * page_size)``.
+
+    - ``window``: slot i sees only the keys ``positions[i] - window <
+      k_pos <= positions[i]``, and only the blocks that hold them are
+      walked (the grid's block axis is as long as a window can span,
+      from the block of the window's first key): pages behind the
+      window are neither fetched nor read, whatever the table holds
+      there. The kernel is then ``window_attn_step`` in a profile.
+      ``None`` is the kernel as it was, ``paged_attn_step``.
+      With a window the call returns ``(out, keys)``: ``keys`` (b,)
+      int32 the key positions whose K (and V) it FETCHED for each slot,
+      counted inside the kernel — a page where its copy is started, or,
+      on the pipeline, where the index maps hand a page of the table
+      and not the scratch page — so that a caller can tell a kernel
+      that reads the window from one that only masks to it.
 
     The block (``_pages_per_block``) and who fetches its pages
     (``_copies_own_pages``) follow from the shapes of the call. Products
@@ -477,6 +549,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         page_size, n_tables,
         page_size * block_h * dh * max(k_pool.dtype.itemsize, 2))
     n_blocks = -(-n_tables // pages)
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window} must be >= 1")
+        n_blocks = _window_blocks(window, pages * page_size, n_blocks)
     own_copies = _copies_own_pages(dh)
     # GQA query rows grouped per kv head tile: q head h <-> kv head
     # h // rep, so a tile's rows are contiguous
@@ -505,7 +581,13 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         def spec(j):
             def index(bi, kh, blk, t_ref, tab_ref):
                 pg = blk * pages + j
-                live = pg <= t_ref[bi] // page_size
+                if window is None:
+                    live = pg <= t_ref[bi] // page_size
+                else:  # from the block of the window's first key
+                    lo = _window_start(t_ref[bi], window)
+                    pg += lo // (pages * page_size) * pages
+                    live = (pg <= t_ref[bi] // page_size) & (
+                        pg >= lo // page_size)
                 page = jnp.where(
                     live, tab_ref[bi, jnp.minimum(pg, n_tables - 1)], 0)
                 return ((page, 0, kh, 0) if len(block_shape) == 4
@@ -537,35 +619,46 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         in_specs += 2 * page_specs((1, page_size, n_kv))
         operands += [k_scale] * pages + [v_scale] * pages
 
+    out_specs = pl.BlockSpec((1, 1, n_q, dh), q_map)
+    out_shape = jax.ShapeDtypeStruct((b, n_tiles, n_q, dh), q.dtype)
+    if window is not None:  # a counter a slot, in SMEM for the call
+        out_specs = [out_specs, pl.BlockSpec(memory_space=pltpu.SMEM)]
+        out_shape = [out_shape, jax.ShapeDtypeStruct((b,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(b, n_tiles, n_blocks),
         in_specs=in_specs,
-        out_specs=pl.BlockSpec((1, 1, n_q, dh), q_map),
+        out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
         _paged_decode_kernel, sm_scale=float(sm_scale),
         page_size=page_size, block_h=block_h, rep=rep, pages=pages,
-        n_blocks=n_blocks, quantized=quantized, own_copies=own_copies)
+        n_blocks=n_blocks, quantized=quantized, own_copies=own_copies,
+        window=window)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((b, n_tiles, n_q, dh), q.dtype),
+        out_shape=out_shape,
         interpret=interpret,
-        name="paged_attn_step",  # what a profile calls the kernel
+        # what a profile calls the kernel
+        name="paged_attn_step" if window is None else "window_attn_step",
     )
     if interpret and jax.device_count() > 1:
         out = _partitioner_shield(call, t, tabs, *operands)
     else:
         out = call(t, tabs, *operands)
-    return out.reshape(b, n_heads, dh)
+    if window is None:
+        return out.reshape(b, n_heads, dh)
+    out, fetched = out  # pages, every head tile's counted
+    return out.reshape(b, n_heads, dh), fetched // n_tiles * page_size
 
 
 def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
                          *rest, sm_scale: float, page_size: int,
                          block_h: int, block_q: int,
-                         n_tables: int, quantized: bool):
+                         n_tables: int, quantized: bool,
+                         window: Optional[int] = None):
     from jax.experimental import pallas as pl
 
     if quantized:
@@ -576,7 +669,7 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
     bi = pl.program_id(0)
     h0 = _tile_first_head(block_h, ks_ref)
     qt = pl.program_id(2)
-    pg = pl.program_id(3)
+    pg = step = pl.program_id(3)
     # positions are NONDECREASING along the window (the engine repeats
     # the last real entry into idle/overhang rows), so this tile's last
     # row bounds its live pages — the per-tile twin of the step
@@ -585,8 +678,11 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
     # only load scalars from SMEM"), so the tile's per-row positions
     # arrive as the blocked VMEM operand ``trow_ref`` instead
     n_live = t_ref[bi, qt * block_q + block_q - 1] // page_size + 1
+    if window is not None:  # the page axis starts at the page of the
+        # first key the tile's FIRST row sees, and ends with the grid
+        pg += _window_start(t_ref[bi, qt * block_q], window) // page_size
 
-    @pl.when(pg == 0)
+    @pl.when(step == 0)
     def _init():  # fresh (batch, head-tile, query-tile) row
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
@@ -601,6 +697,8 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
         # and sees keys k_pos <= its own absolute position — inside the
         # window, earlier tokens do NOT see later tokens' keys
         mask = k_pos <= trow_ref[0, 0]  # (block_q*rep, page_size)
+        if window is not None:  # and not the keys behind its window
+            mask &= k_pos > trow_ref[0, 0] - window
         for hh in range(block_h):  # static unroll over the head tile
             q = q_ref[0, hh, 0].astype(jnp.float32) * sm_scale  # (bq*rep, dh)
             k = k_ref[0, :, hh, :].astype(jnp.float32)  # (page_size, dh)
@@ -623,8 +721,8 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
                 preferred_element_type=jnp.float32)  # (bq*rep, dh)
             m_scr[hh] = m_new
 
-    @pl.when(pg == n_tables - 1)
-    def _finish():  # k_pos 0 <= any position, so l > 0 on every row
+    @pl.when(step == n_tables - 1)
+    def _finish():  # a row's own position is live, so l > 0 on every row
         o_ref[0, :, 0] = (acc_scr[...] / jnp.maximum(
             l_scr[...], 1e-30)).astype(o_ref.dtype)
 
@@ -642,7 +740,8 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
                            k_scale=None, v_scale=None,
                            block_h: Optional[int] = None,
                            block_q: Optional[int] = None,
-                           interpret: Optional[bool] = None
+                           interpret: Optional[bool] = None,
+                           window: Optional[int] = None
                            ) -> jnp.ndarray:
     """Multi-token window attention straight off a paged KV pool.
 
@@ -663,6 +762,15 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
       NONDECREASING: the engine's windows guarantee this (prefill pads
       overhang with the last entry, verify freezes inactive slots), and
       the kernel exploits it to bound live pages per query tile.
+
+    - ``window``: row i sees only the keys ``positions[b, i] - window <
+      k_pos <= positions[b, i]``, and a query tile walks only the pages
+      from its first row's first key on, as many as a window and a tile
+      can span — not the table. A tile's positions are then held to be
+      consecutive or repeated (last - first < ``block_q``), as the
+      engine's prefill rows are. The kernel is then
+      ``window_attn_prefill`` in a profile; ``None`` is the kernel as it
+      was, ``paged_attn_window``.
 
     Returns (b, s, n_heads, dh) in ``q``'s dtype. ``block_q`` tiles the
     window (must divide s; default: largest divisor <= 16), ``block_h``
@@ -716,17 +824,23 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
     def trow_map(bi, kh, qt, pg, t_ref, tab_ref):
         return (bi, qt, 0, 0)
 
-    def kv_map(bi, kh, qt, pg, t_ref, tab_ref):
+    def live_page(bi, qt, pg, t_ref, tab_ref):
         # the block-table walk, bounded per QUERY TILE: nondecreasing
         # positions make the tile's last row its page horizon, so dead
         # pages collapse onto the scratch page exactly as in the step
         # kernel
+        if window is not None:  # from the page of the tile's first key
+            pg = jnp.minimum(pg + _window_start(
+                t_ref[bi, qt * block_q], window) // page_size,
+                n_tables - 1)
         live = pg <= t_ref[bi, qt * block_q + block_q - 1] // page_size
-        return (jnp.where(live, tab_ref[bi, pg], 0), 0, kh, 0)
+        return jnp.where(live, tab_ref[bi, pg], 0)
+
+    def kv_map(bi, kh, qt, pg, t_ref, tab_ref):
+        return (live_page(bi, qt, pg, t_ref, tab_ref), 0, kh, 0)
 
     def sc_map(bi, kh, qt, pg, t_ref, tab_ref):
-        live = pg <= t_ref[bi, qt * block_q + block_q - 1] // page_size
-        return (jnp.where(live, tab_ref[bi, pg], 0), 0, 0)
+        return (live_page(bi, qt, pg, t_ref, tab_ref), 0, 0)
 
     in_specs = [
         pl.BlockSpec((1, block_h, 1, rows, dh), q_map),
@@ -740,9 +854,16 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
                      pl.BlockSpec((1, page_size, n_kv), sc_map)]
         operands += [k_scale, v_scale]
 
+    n_walked = n_tables
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window} must be >= 1")
+        # keys (first - window, last] of a tile, last - first < block_q
+        n_walked = _window_blocks(window + block_q - 1, page_size,
+                                  n_tables)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_kv // block_h, n_qt, n_tables),
+        grid=(b, n_kv // block_h, n_qt, n_walked),
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_h, 1, rows, dh), q_map),
         scratch_shapes=[
@@ -754,13 +875,15 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
     kernel = functools.partial(
         _paged_window_kernel, sm_scale=float(sm_scale),
         page_size=page_size, block_h=block_h, block_q=block_q,
-        n_tables=n_tables, quantized=quantized)
+        n_tables=n_walked, quantized=quantized, window=window)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, n_kv, n_qt, rows, dh), q.dtype),
         interpret=interpret,
-        name="paged_attn_window",  # what a profile calls the kernel
+        # what a profile calls the kernel
+        name="paged_attn_window" if window is None
+        else "window_attn_prefill",
     )
     if interpret and jax.device_count() > 1:
         out = _partitioner_shield(call, t, tabs, *operands)
@@ -772,7 +895,8 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
 
 def _paged_attention_reference(q, k_pool, v_pool, page_tables, positions,
                                sm_scale: float, k_scale=None,
-                               v_scale=None) -> jnp.ndarray:
+                               v_scale=None, window: Optional[int] = None
+                               ) -> jnp.ndarray:
     """Pure-XLA oracle: gather the pages back into logical order (the
     pre-kernel serving path) and run the masked softmax in f32. The
     kernel-equivalence property tests compare against this."""
@@ -795,15 +919,19 @@ def _paged_attention_reference(q, k_pool, v_pool, page_tables, positions,
     s = jnp.einsum("bhd,bkhd->bhk", q.astype(jnp.float32),
                    k) * sm_scale
     k_pos = jnp.arange(length)[None, None, :]
-    s = jnp.where(k_pos <= jnp.asarray(positions)[:, None, None],
-                  s, NEG_INF)
+    t = jnp.asarray(positions)[:, None, None]
+    seen = k_pos <= t
+    if window is not None:
+        seen &= k_pos > t - window
+    s = jnp.where(seen, s, NEG_INF)
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhk,bkhd->bhd", p, v).astype(q.dtype)
 
 
 def _paged_window_reference(q, k_pool, v_pool, page_tables, positions,
                             sm_scale: float, k_scale=None,
-                            v_scale=None) -> jnp.ndarray:
+                            v_scale=None, window: Optional[int] = None
+                            ) -> jnp.ndarray:
     """Pure-XLA window oracle: gather the pages back into logical order
     and run the per-row masked softmax in f32 — the same math the
     multi-token gather fallback in ``_DecoderAttention`` computes."""
@@ -827,6 +955,9 @@ def _paged_window_reference(q, k_pool, v_pool, page_tables, positions,
                         k) * sm_scale
     k_pos = jnp.arange(length)[None, None, None, :]
     t = jnp.asarray(positions)[:, None, :, None]  # (b, 1, s, 1)
-    scores = jnp.where(k_pos <= t, scores, NEG_INF)
+    seen = k_pos <= t
+    if window is not None:
+        seen &= k_pos > t - window
+    scores = jnp.where(seen, scores, NEG_INF)
     p = jax.nn.softmax(scores, axis=-1)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v).astype(q.dtype)

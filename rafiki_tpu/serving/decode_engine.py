@@ -231,7 +231,7 @@ class DecodeEngine:
                  max_len: int, steps_per_sync: int = 4,
                  prefill_chunk: int = 32, speculate_k: int = 0,
                  draft: Optional[Tuple[Any, Any]] = None,
-                 host_kv_pages: int = 0) -> None:
+                 host_kv_pages: int = 0, table_floor: int = 1) -> None:
         self.module = module
         self.B = int(max_slots)
         self.L = int(max_len)
@@ -243,6 +243,9 @@ class DecodeEngine:
         #: (``slot_ids``, ``row_tokens``); what cannot carry that state
         #: yet is refused by name, here or at the call
         self._slot_state = tuple(getattr(module, "slot_state", ()))
+        #: of those, the rings of keys and values that window layers
+        #: keep a slot (``ops/window_attention.py``): counted apart
+        self._window_state = tuple(getattr(module, "window_state", ()))
         if self._slot_state:
             for what, asked in (
                     ("a host KV tier (host_kv_pages > 0)", host_kv_pages),
@@ -323,6 +326,12 @@ class DecodeEngine:
                 raise ValueError("paged KV needs kv_pages >= 2 (scratch"
                                  " page + at least one usable page)")
             self._n_table = self.L // self.page_size  # table width
+            #: the narrowest table operand a call is handed (see
+            #: :meth:`_live_table_width`): every width from here up is a
+            #: compilation of each program, so a deep model whose traffic
+            #: keeps some slot long anyway starts at the width it would
+            #: reach — the whole table gives ONE shape a program
+            self._table_floor = max(1, min(int(table_floor), self._n_table))
             #: LIFO free list over pages 1..n_pages-1; reservation
             #: accounting (below) guarantees pops never fail mid-flight
             self._free_pages = list(range(self.n_pages - 1, 0, -1))
@@ -393,6 +402,12 @@ class DecodeEngine:
             if self.paged and not (draft is not None and self.spec_k)
             else 0)
         n_rows = self._prefill_lanes or self.B
+        #: a module whose window layers keep a ring of keys a slot says
+        #: whether the ring takes what ONE call may write for a slot
+        #: (a prompt's consecutive chunks, dealt to the call's rows)
+        ring_holds = getattr(module, "ring_holds_call", None)
+        if ring_holds is not None:
+            ring_holds(max(self._prefill_lanes, 1) * self.C)
         self._prefill_fn = (_make_prefill(module, n_rows, self.C)
                             if self.C > 1 else None)
         #: narrow twin of the prefill program for short remainders: a
@@ -519,8 +534,13 @@ class DecodeEngine:
             # bytes ONE slot's recurrent state costs over all layers:
             # the cache leaves the module indexes by slot (slots + a
             # scratch row); 0 for a module that keeps none
-            "ssm_state_bytes_per_slot":
-                self._cache_bytes(per_slot=True) // (self.B + 1),
+            "ssm_state_bytes_per_slot": self._cache_bytes(tuple(
+                name for name in self._slot_state
+                if name not in self._window_state)) // (self.B + 1),
+            # bytes ONE slot's rings of keys and values cost over all
+            # window layers (slots + a scratch row); 0 without such
+            "window_kv_bytes_per_slot":
+                self._cache_bytes(self._window_state) // (self.B + 1),
             **{name: 0 for name in self._count_names},
             # the host's share of the loop, counted where the phase
             # spans are cut (docs/observability.md "Phase spans"):
@@ -553,21 +573,22 @@ class DecodeEngine:
         return (f"{type(self.module).__name__} keeps per-slot state "
                 f"{self._slot_state}, which {what} cannot carry yet: "
                 "pages can be parked, shipped and shared, a slot's "
-                "recurrent state cannot")
+                "recurrent state or ring of keys cannot")
 
-    def _cache_bytes(self, per_slot: bool) -> int:
-        """Bytes of the cache's leaves indexed by slot (``per_slot``) or
-        of those indexed by position."""
+    def _cache_bytes(self, names: Tuple[str, ...],
+                     named: bool = True) -> int:
+        """Bytes of the cache's leaves called one of ``names`` (``named``
+        False: of all the others)."""
         return sum(
             int(leaf.nbytes) for path, leaf in
             jax.tree_util.tree_flatten_with_path(self._cache)[0]
-            if (getattr(path[-1], "key", None) in self._slot_state)
-            == per_slot)
+            if (getattr(path[-1], "key", None) in names) == named)
 
     def _pool_bytes_per_token(self) -> int:
+        """Of the leaves indexed by position: those no slot keeps."""
         positions = (self.n_pages * self.page_size if self.paged
                      else self.B * self.L)
-        return self._cache_bytes(per_slot=False) // positions
+        return self._cache_bytes(self._slot_state, named=False) // positions
 
     @property
     def params(self) -> Any:
@@ -1032,14 +1053,15 @@ class DecodeEngine:
         """Table columns the NEXT compiled call actually needs: enough
         to cover every slot's allocated pages (``_ensure_pages_to`` runs
         before every call, so ``_n_alloc`` already reflects that call's
-        write horizon), rounded up to a power of two so the jit cache
-        sees at most log2(max_len/page_size) distinct operand widths.
+        write horizon), doubled up from ``table_floor`` (1: a power of
+        two) so the jit cache sees at most log2(max_len/page_size)
+        distinct operand widths.
         Slicing the operand shrinks BOTH decode paths' per-step cost to
         live tokens: the gather fallback stops materializing (and
         soft-maxing over) dead pages, and the kernel's page grid stops
         iterating them."""
         hi = max(1, int(self._n_alloc.max()))
-        w = 1
+        w = self._table_floor
         while w < hi:
             w *= 2
         return min(w, self._n_table)
@@ -1364,7 +1386,9 @@ class DecodeEngine:
                 "kv_pool_bytes_per_token":
                     self.stats["kv_pool_bytes_per_token"],
                 "ssm_state_bytes_per_slot":
-                    self.stats["ssm_state_bytes_per_slot"]}
+                    self.stats["ssm_state_bytes_per_slot"],
+                "window_kv_bytes_per_slot":
+                    self.stats["window_kv_bytes_per_slot"]}
         if self.paged:
             keep.update(kv_pages_total=self.n_pages - 1,
                         kv_pages_used=(self.n_pages - 1

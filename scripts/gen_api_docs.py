@@ -50,6 +50,8 @@ MODULES = [
     ("rafiki_tpu.ops.patch_embed", "Patch embed (Pallas)"),
     ("rafiki_tpu.ops.latent_attention", "Latent paged attention (Pallas)"),
     ("rafiki_tpu.ops.ssm", "State-space step and chunked scan (Pallas)"),
+    ("rafiki_tpu.ops.window_attention",
+     "Sliding-window attention over per-slot rings"),
     ("rafiki_tpu.ops.ring_attention", "Ring attention"),
     ("rafiki_tpu.ops.ulysses", "Ulysses sequence parallelism"),
     ("rafiki_tpu.models.mlp", "Zoo: MLP"),
@@ -62,7 +64,8 @@ MODULES = [
     ("rafiki_tpu.models.latent_moe",
      "Decoder: latent attention + routed experts"),
     ("rafiki_tpu.models.hybrid_ssm_moe",
-     "Decoder: a layer pattern over state-space, attention, latent experts"),
+     "Decoder: a layer pattern over state-space, attention (plain, rotary, "
+     "windowed), routed experts"),
     ("rafiki_tpu.models.pos_tagging", "Zoo: POS tagging"),
     ("rafiki_tpu.models.tabular", "Zoo: tabular"),
     ("rafiki_tpu.models.sklearn_models", "Zoo: sklearn/GBDT/SVM"),

