@@ -85,6 +85,7 @@ from rafiki_tpu.ops.moe import (MOE_COUNTERS, ExpertShare,
 from rafiki_tpu.ops.paged_attention import (kv_cache_write,
                                             paged_decode_attention,
                                             paged_window_attention,
+                                            paged_window_grid_steps,
                                             resolve_paged_kernel,
                                             resolve_paged_window_kernel)
 from rafiki_tpu.ops.ssm import causal_conv, ssd_chunk_scan, ssm_state_step
@@ -107,15 +108,18 @@ def book_ssm_counters(stats: Any, counts: Any) -> None:
     stats.inc("ssm_rows_chained", int(counts[2]))
 
 
-#: what the rotary attention layers count on the device over
-#: single-token calls, after the counters above in a pattern that has
-#: such layers: keys live under the window of every (real row, ``W``
-#: layer), keys the ``W`` layers' step fetched for them (counted inside
+#: what the rotary attention layers count on the device, after the
+#: counters above in a pattern that has such layers. Over single-token
+#: calls: keys live under the window of every (real row, ``W`` layer),
+#: keys the ``W`` layers' step fetched for them (counted inside
 #: ``window_attn_step``, a page where its copy starts; off the TPU the
 #: ring the masked form is handed), and keys live under every (real row,
-#: ``R`` layer)
+#: ``R`` layer). Over prefill calls: the grid steps of the layers'
+#: query-window kernel calls (0 where the gather or the masked form
+#: serves the window), and the call's rows x chunk tokens, once a call
 WINDOW_COUNTERS = ("win_step_live_keys", "win_step_keys_fetched",
-                   "full_step_live_keys")
+                   "full_step_live_keys", "attn_prefill_grid_steps",
+                   "attn_prefill_tokens")
 
 
 def book_window_counters(stats: Any, counts: Any) -> None:
@@ -124,6 +128,17 @@ def book_window_counters(stats: Any, counts: Any) -> None:
     stats.inc("win_step_live_keys", int(counts[0]))
     stats.inc("win_step_keys_fetched", int(counts[1]))
     stats.inc("full_step_live_keys", int(counts[2]))
+    stats.inc("attn_prefill_grid_steps", int(counts[3]))
+    stats.inc("attn_prefill_tokens", int(counts[4]))
+
+
+def _sow_window_counters(module: nn.Module, counts: Any) -> None:
+    """Add ``counts``, in the order of :data:`WINDOW_COUNTERS`, to what
+    this ``apply`` sows under ``"win"``."""
+    module.sow("counters", "win",
+               jnp.stack([jnp.asarray(c, jnp.int32) for c in counts]),
+               init_fn=lambda: jnp.zeros((len(WINDOW_COUNTERS),), jnp.int32),
+               reduce_fn=lambda u, w: u + w)
 
 
 class Rows(NamedTuple):
@@ -343,7 +358,7 @@ class PatternAttention(nn.Module):
             ck = self.variable("cache", leaves[0], jnp.zeros, shape, h.dtype)
             cv = self.variable("cache", leaves[1], jnp.zeros, shape, h.dtype)
         t = positions
-        counts = [0, 0, 0]  # WINDOW_COUNTERS, of a single-token call
+        counts = [0] * len(WINDOW_COUNTERS)
         if not live:  # no cache, or the init trace (allocates only)
             scores = jnp.einsum(
                 "bqhd,bkhd->bhqk", q, jnp.repeat(k, rep, axis=2),
@@ -371,6 +386,10 @@ class PatternAttention(nn.Module):
                 counts[0] = jnp.sum(jnp.where(
                     has, jnp.minimum(t[:, 0] + 1, self.window), 0))
                 counts[1] = jnp.sum(jnp.where(has, fetched, 0))
+            elif kernel:
+                counts[3] = paged_window_grid_steps(
+                    q, nkv, self.kv_page_size,
+                    -(-self.max_len // self.kv_page_size), self.window)
         else:
             if page_tables is None:
                 raise ValueError("kv_page_size > 0 decode requires the "
@@ -394,6 +413,8 @@ class PatternAttention(nn.Module):
             elif resolve_paged_window_kernel(self.paged_kernel):
                 o = paged_window_attention(
                     q, ck.value, cv.value, page_tables, t, sm_scale=sm)
+                counts[3] = paged_window_grid_steps(
+                    q, nkv, self.kv_page_size, page_tables.shape[1])
             else:
                 def gathered(c):
                     return jnp.repeat(c[page_tables].reshape(
@@ -407,11 +428,7 @@ class PatternAttention(nn.Module):
                 counts[2] = jnp.sum(jnp.where(rows.n_real > 0,
                                               t[:, 0] + 1, 0))
         if self.rope is not None:  # the kinds that WINDOW_COUNTERS count
-            self.sow("counters", "win", jnp.stack(
-                [jnp.asarray(c, jnp.int32) for c in counts]),
-                init_fn=lambda: jnp.zeros((len(WINDOW_COUNTERS),),
-                                          jnp.int32),
-                reduce_fn=lambda u, w: u + w)
+            _sow_window_counters(self, counts)
         return LoRADense(d, 0, name="wo")(o.reshape(b, s, nh * dh))
 
 
@@ -627,6 +644,9 @@ class HybridSSMMoEDecoder(nn.Module):
                 jnp.arange(b) if slot_ids is None else slot_ids,
                 jnp.full((b,), s) if row_tokens is None else row_tokens,
                 positions)
+        if decode and s > 1 and self._rotary:  # a prefill call's tokens
+            _sow_window_counters(
+                self, [0] * (len(WINDOW_COUNTERS) - 1) + [b * s])
         x = nn.Embed(self.vocab_size, self.hidden_dim,
                      name="tok_embed")(ids)
         if self.dtype is not None:

@@ -60,7 +60,9 @@ generated token. This kernel consumes the pool **directly**:
 ``paged_window_attention`` is the (s >= 1) **query window** form, so
 chunked prefill and speculative-verify calls run paged-native too. Its
 grid is (batch, kv-head tile, query tile, pages): ``block_q`` window
-rows per program, ONE ``(page_size, block_h, dh)`` K/V block a grid
+tokens per program (a prefill chunk's worth, up to ``TILE_QUERIES``,
+so that a row of a prefill call is one tile and walks its pages
+once), ONE ``(page_size, block_h, dh)`` K/V block a grid
 step through a BlockSpec whose index map walks the block table, a
 static unroll over the tile's heads, the same LSE-merge recurrence
 streamed across pages per query tile, and a causal mask per ROW: window token i at absolute
@@ -96,6 +98,7 @@ decode mode in ``tests/test_paged_kv.py``).
 from __future__ import annotations
 
 import functools
+import math
 import os
 from typing import Optional
 
@@ -700,7 +703,13 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
         if window is not None:  # and not the keys behind its window
             mask &= k_pos > trow_ref[0, 0] - window
         for hh in range(block_h):  # static unroll over the head tile
-            q = q_ref[0, hh, 0].astype(jnp.float32) * sm_scale  # (bq*rep, dh)
+            # operands widened in registers: the MXU's one default pass
+            # takes them at bf16 all the same — on a bf16 pool bit for
+            # bit what explicit bf16 operands give on the chip, 8-25%
+            # faster (Mosaic repacks a head's bf16 rows out of the page
+            # block). The scale meets the f32 scores, not the queries
+            # before that pass rounds them
+            q = q_ref[0, hh, 0].astype(jnp.float32)  # (bq*rep, dh)
             k = k_ref[0, :, hh, :].astype(jnp.float32)  # (page_size, dh)
             v = v_ref[0, :, hh, :].astype(jnp.float32)
             if quantized:  # dequant in registers, fused into the math
@@ -708,7 +717,7 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
                 v = v * _head_scale(vs_ref, h0 + hh)
             s = jax.lax.dot_general(
                 q, k, (((1,), (1,)), ((), ())),
-                preferred_element_type=jnp.float32)  # (bq*rep, psz)
+                preferred_element_type=jnp.float32) * sm_scale  # (bq*rep, psz)
             s = jnp.where(mask, s, NEG_INF)
 
             m_prev = m_scr[hh]  # (bq*rep, 1) running max
@@ -727,12 +736,72 @@ def _paged_window_kernel(t_ref, tab_ref, q_ref, trow_ref, k_ref, v_ref,
             l_scr[...], 1e-30)).astype(o_ref.dtype)
 
 
-def _default_block_q(s: int) -> int:
-    """Largest window-tile width <= 16 that divides the window."""
-    for d in range(min(s, 16), 0, -1):
-        if s % d == 0:
+#: window tokens one grid step of the query-window kernel takes at most:
+#: a prefill chunk's worth, so that a row of the call is one query tile
+TILE_QUERIES = 64
+#: bytes a query tile's state may take in VMEM (``_tile_bytes``): half
+#: of the 16 MiB Mosaic gives a kernel, the rest for the K / V pages and
+#: the products' temporaries
+TILE_BYTES = 8 << 20
+
+
+def _tile_bytes(block_q: int, rep: int, block_h: int, dh: int,
+                itemsize: int) -> int:
+    """VMEM a query tile of ``block_q`` window tokens holds across the
+    page axis: per query row and kv head of the tile the f32 accumulator,
+    the running max and sum (a lane tile each), the query and output
+    blocks (double-buffered), and a lane tile a row of positions
+    (double-buffered)."""
+    rows = block_q * rep
+    return (rows * block_h * (dh * 4 + 2 * 128 * 4 + 4 * dh * itemsize)
+            + 2 * rows * 128 * 4)
+
+
+def _default_block_q(s: int, rep: int, block_h: int, dh: int,
+                     itemsize: int) -> int:
+    """The window tile of a call: the largest divisor of the window
+    ``s`` that is at most ``TILE_QUERIES`` and whose state fits
+    ``TILE_BYTES``, read off the call's shapes. A tile of 16 tokens or
+    fewer (speculative verify) always stands."""
+    for d in range(min(s, TILE_QUERIES), 0, -1):
+        if s % d == 0 and (d <= 16 or _tile_bytes(
+                d, rep, block_h, dh, itemsize) <= TILE_BYTES):
             return d
     return 1
+
+
+def _window_grid(q, n_kv: int, page_size: int, n_tables: int,
+                 window: Optional[int], block_h: Optional[int],
+                 block_q: Optional[int]):
+    """``(block_h, block_q, grid)`` of a query-window call: the grid is
+    (rows, kv-head tiles, query tiles, pages walked) — the table's
+    pages, or with ``window`` as many as the keys ``(first - window,
+    last]`` of a tile can span (last - first < ``block_q``)."""
+    b, s, n_heads, dh = q.shape
+    rep = gqa_repeat_factor(n_heads, n_kv)
+    block_h = _resolve_block_h(block_h, n_kv)
+    if block_q is None:
+        block_q = _default_block_q(s, rep, block_h, dh, q.dtype.itemsize)
+    if block_q < 1 or s % block_q:
+        raise ValueError(f"block_q={block_q} must be >= 1 and divide "
+                         f"the window length ({s})")
+    n_walked = n_tables
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window} must be >= 1")
+        n_walked = _window_blocks(window + block_q - 1, page_size,
+                                  n_tables)
+    return block_h, block_q, (b, n_kv // block_h, s // block_q, n_walked)
+
+
+def paged_window_grid_steps(q, n_kv: int, page_size: int, n_tables: int,
+                            window: Optional[int] = None) -> int:
+    """Grid steps ``paged_window_attention`` takes for queries ``q``
+    (b, s, n_heads, dh) over a pool of ``n_kv`` heads in pages of
+    ``page_size`` and a table ``n_tables`` wide: a Python int at trace
+    time, what a caller counts its prefill work by."""
+    return math.prod(_window_grid(
+        q, n_kv, page_size, n_tables, window, None, None)[2])
 
 
 def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
@@ -773,8 +842,12 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
       was, ``paged_attn_window``.
 
     Returns (b, s, n_heads, dh) in ``q``'s dtype. ``block_q`` tiles the
-    window (must divide s; default: largest divisor <= 16), ``block_h``
-    tiles kv heads as in the step kernel. With s == 1 this is the same
+    window (must divide s; default: a prefill chunk's worth, the largest
+    divisor <= ``TILE_QUERIES`` whose state fits VMEM — see
+    ``_default_block_q``), ``block_h`` tiles kv heads as in the step
+    kernel. Operands are widened to f32 in registers (int8 pools:
+    dequantised there); scores, accumulation and softmax state are
+    f32. With s == 1 this is the same
     attention as ``paged_decode_attention`` in another summation order
     (a page and a head at a time, where the step kernel takes a block of
     pages over every head of its tile): the two agree to f32 roundoff,
@@ -789,12 +862,8 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
         raise ValueError(f"head_dim mismatch: q has {dh}, pool {dh_k}")
     rep = gqa_repeat_factor(n_heads, n_kv)
     n_tables = page_tables.shape[1]
-    block_h = _resolve_block_h(block_h, n_kv)
-    if block_q is None:
-        block_q = _default_block_q(s)
-    if block_q < 1 or s % block_q:
-        raise ValueError(f"block_q={block_q} must be >= 1 and divide "
-                         f"the window length ({s})")
+    block_h, block_q, grid = _window_grid(
+        q, n_kv, page_size, n_tables, window, block_h, block_q)
     quantized = k_scale is not None
     if quantized != (v_scale is not None):
         raise ValueError("k_scale and v_scale must be passed together")
@@ -854,16 +923,9 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
                      pl.BlockSpec((1, page_size, n_kv), sc_map)]
         operands += [k_scale, v_scale]
 
-    n_walked = n_tables
-    if window is not None:
-        if window < 1:
-            raise ValueError(f"window={window} must be >= 1")
-        # keys (first - window, last] of a tile, last - first < block_q
-        n_walked = _window_blocks(window + block_q - 1, page_size,
-                                  n_tables)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_kv // block_h, n_qt, n_walked),
+        grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((1, block_h, 1, rows, dh), q_map),
         scratch_shapes=[
@@ -875,7 +937,7 @@ def paged_window_attention(q, k_pool, v_pool, page_tables, positions,
     kernel = functools.partial(
         _paged_window_kernel, sm_scale=float(sm_scale),
         page_size=page_size, block_h=block_h, block_q=block_q,
-        n_tables=n_walked, quantized=quantized, window=window)
+        n_tables=grid[3], quantized=quantized, window=window)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -20,10 +20,13 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from rafiki_tpu.ops.paged_attention import (_paged_attention_reference,
+from rafiki_tpu.ops.paged_attention import (TILE_BYTES, _default_block_q,
+                                            _paged_attention_reference,
                                             _paged_window_reference,
+                                            _tile_bytes,
                                             paged_decode_attention,
                                             paged_window_attention,
+                                            paged_window_grid_steps,
                                             resolve_paged_kernel,
                                             resolve_paged_window_kernel)
 
@@ -297,17 +300,17 @@ def _wsetup(positions, n_kv=2, rep=2, dh=8, ps=8, n_tables=4,
     return q, kp, vp, tabs, t, scales
 
 
-def _wboth(q, kp, vp, tabs, t, scales=None, **kw):
+def _wboth(q, kp, vp, tabs, t, scales=None, window=None, **kw):
     sm = 1.0 / np.sqrt(q.shape[-1])
     sk, sv = scales if scales else (None, None)
     out = paged_window_attention(q, kp, vp, tabs, t, sm_scale=sm,
                                  k_scale=sk, v_scale=sv,
-                                 interpret=True, **kw)
+                                 interpret=True, window=window, **kw)
     ref = _paged_window_reference(
         jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
         jnp.asarray(tabs), t, sm,
         None if sk is None else jnp.asarray(sk),
-        None if sv is None else jnp.asarray(sv))
+        None if sv is None else jnp.asarray(sv), window=window)
     return np.asarray(out, np.float32), np.asarray(ref, np.float32)
 
 
@@ -422,6 +425,82 @@ def test_window_s1_degenerate_agrees_with_step_kernel():
                                        err_msg=f"int8={int8}")
         np.testing.assert_allclose(step, win[:, 0], atol=1e-5, rtol=1e-4,
                                    err_msg=f"int8={int8}")
+
+
+#: what the kernel may differ from the f32 oracle by, a pool dtype: a
+#: float32 pool as every case above (another summation order); an int8
+#: pool dequantised in f32 as its own case above; a bfloat16 pool
+#: against the oracle on the SAME bf16-rounded pool and queries — the
+#: operands are those values widened, and the result is returned in
+#: the queries' bf16 (8 bits of mantissa)
+POOL_TOLERANCE = {"float32": dict(atol=2e-6, rtol=1e-5),
+                  "int8": dict(atol=1e-5, rtol=1e-4),
+                  "bfloat16": dict(atol=2e-2, rtol=2e-2)}
+
+
+@pytest.mark.parametrize("rep", [4, 8, 16])
+@pytest.mark.parametrize("window", [None, 24], ids=["full", "window24"])
+@pytest.mark.parametrize("pool", ["float32", "bfloat16", "int8"])
+@pytest.mark.parametrize("block_q", [16, 32, 64])
+def test_window_tiles_of_a_chunk_match_reference(block_q, pool, window,
+                                                 rep):
+    """A query tile of up to a prefill chunk (64 tokens x ``rep`` rows a
+    kv head) on operands in the pool's own dtype: a fresh prompt, a
+    chunk deep in a context that straddles pages, and a chunk whose
+    overhang repeats its last real entry — against the page-gather
+    oracle, with and without a window shorter than the context."""
+    s = 64
+    pos = np.stack([np.arange(s), 37 + np.arange(s),
+                    np.minimum(70 + np.arange(s), 70 + 40)])
+    q, kp, vp, tabs, t, scales = _wsetup(
+        pos, n_kv=2, rep=rep, ps=8, n_tables=16, n_pages=41,
+        seed=block_q + rep, int8=pool == "int8",
+        dtype=jnp.bfloat16 if pool == "bfloat16" else np.float32)
+    out, ref = _wboth(q, kp, vp, tabs, t, scales=scales, window=window,
+                      block_q=block_q)
+    np.testing.assert_allclose(out, ref, **POOL_TOLERANCE[pool])
+
+
+@pytest.mark.parametrize("s,want,cut", [
+    (4, 4, 4), (6, 6, 6), (16, 16, 16), (32, 32, 32), (64, 64, 32),
+    (128, 64, 32), (96, 48, 48)])
+def test_default_window_tile_is_a_chunk_inside_the_vmem_budget(s, want,
+                                                               cut):
+    """The default tile divides the window, is at most 64 tokens and
+    fits the VMEM budget at every shape the cells call with — and is
+    cut to fit where heads are many and wide; a window of 16 tokens or
+    fewer (speculative verify) keeps the tile it had, whatever the
+    heads."""
+    for rep, block_h in ((8, 4), (16, 2), (4, 8), (1, 32)):
+        tile = _default_block_q(s, rep, block_h, 128, 2)
+        assert tile == want and s % tile == 0
+        assert tile <= 16 or _tile_bytes(
+            tile, rep, block_h, 128, 2) <= TILE_BYTES
+    # 64 query heads over 16 kv heads of 128: 64 tokens do not fit
+    assert _default_block_q(s, 4, 16, 128, 2) == cut
+    assert cut <= 16 or _tile_bytes(cut, 4, 16, 128, 2) <= TILE_BYTES
+    assert _tile_bytes(64, 4, 16, 128, 2) > TILE_BYTES
+    # and the 16 tokens a verify window had stand whatever the heads
+    assert _default_block_q(s, 8, 64, 256, 4) == min(want, 16)
+
+
+@pytest.mark.parametrize("shape,n_kv,page,n_tables,window,want", [
+    ((8, 64, 32, 128), 4, 32, 224, None, 8 * 224),   # a full layer,
+    ((8, 64, 32, 128), 4, 32, 224, 1024, 8 * 35),    # a sliding layer
+    ((8, 128, 32, 128), 2, 32, 128, None, 8 * 2 * 128),
+    ((8, 32, 32, 128), 8, 16, 32, None, 8 * 32),
+    ((32, 4, 32, 128), 8, 16, 32, None, 32 * 32),    # a verify window
+], ids=["window_moe_full", "window_moe_sliding", "hybrid_ssm", "dense",
+        "verify"])
+def test_window_grid_steps_of_the_served_calls(shape, n_kv, page,
+                                               n_tables, window, want):
+    """What a caller counts its prefill work by is the grid the call is
+    made with: rows x query tiles x pages walked (every kv head in one
+    tile). The 28-layer cell's call of 8 x 64 tokens: 7 x 1,792 + 21 x
+    280 = 18,424 grid steps (73,024 at 16 queries a tile)."""
+    q = jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+    assert paged_window_grid_steps(q, n_kv, page, n_tables,
+                                   window) == want
 
 
 def test_window_composes_with_jit():

@@ -334,7 +334,7 @@ def test_windowed_kernels_compile_over_rings_of_a_window_and_a_call(spec):
     kernel with its own copies of the window's pages (5 blocks of 256
     keys, counting the pages it copies into a second, SMEM result), and
     the query-window kernel at a prefill call's 8 rows of 64 tokens (35
-    pages a tile of 16 queries) — each under its own name in the
+    pages a tile of the chunk's 64 queries) — each under its own name in the
     program."""
     from rafiki_tpu.ops.window_attention import window_ring_attention
 
@@ -375,6 +375,37 @@ def test_paged_kernels_compile_at_four_kv_heads_and_a_table_of_224(spec):
     assert "paged_attn_window" in _compiled_text(
         window, spec((8, 64, 32, 128), jnp.bfloat16), kv, kv,
         spec((8, 224), jnp.int32), spec((8, 64), jnp.int32))
+
+
+#: the query-window kernel's calls of the three serving cells that run
+#: it, bf16 pools: (rows, chunk, q heads, kv heads, page, table, window,
+#: the kernel's name)
+WINDOW_CALLS = {
+    "window_moe_full": (8, 64, 32, 4, 32, 224, None, "paged_attn_window"),
+    "window_moe_sliding": (8, 64, 32, 4, 32, 224, 1024,
+                           "window_attn_prefill"),
+    "hybrid_ssm": (8, 128, 32, 2, 32, 128, None, "paged_attn_window"),
+    "dense": (8, 32, 32, 8, 16, 32, None, "paged_attn_window"),
+}
+
+
+@pytest.mark.parametrize("call", list(WINDOW_CALLS))
+def test_query_window_kernel_compiles_at_the_cells_calls(spec, call):
+    """A prefill call's rows at the DEFAULT query tile — a chunk's worth
+    of tokens, 128 to 1,024 query rows a kv head — and products on the
+    bf16 pool as stored: Mosaic takes the tile's blocks and its state
+    fits the kernel's VMEM."""
+    b, s, n_heads, n_kv, page, n_tables, window, name = WINDOW_CALLS[call]
+    kv = spec((1 + 32 * 49, page, n_kv, 128), jnp.bfloat16)
+
+    def prefill(q, k, v, tabs, t):
+        return paged_window_attention(q, k, v, tabs, t, sm_scale=128 ** -0.5,
+                                      window=window, interpret=False)
+
+    text = _compiled_text(
+        prefill, spec((b, s, n_heads, 128), jnp.bfloat16), kv, kv,
+        spec((b, n_tables), jnp.int32), spec((b, s), jnp.int32))
+    assert name in text and "tpu_custom_call" in text
 
 
 def test_grouped_experts_compile_at_2304_by_896(spec):

@@ -256,8 +256,9 @@ def test_the_windowed_kernels_walk_a_windows_blocks_and_have_their_names():
     """What a profile and the grid say: with a window the kernels are
     ``window_attn_step`` / ``window_attn_prefill`` and their last grid
     axis is as long as a window can span (1,024 keys: 5 blocks of 256,
-    35 pages of 32 beside a tile of 16 queries), not the table's 28
-    blocks / 224 pages; without one they are what they were."""
+    35 pages of 32 beside a tile of the chunk's 64 queries), not the
+    table's 28 blocks / 224 pages; without one they are what they were.
+    A row of the prefill call is ONE query tile."""
     pool = jnp.zeros((1 + 4 * 49, 32, 4, 128), jnp.bfloat16)
     tabs = jnp.zeros((4, 224), jnp.int32)
     q1, t1 = jnp.zeros((4, 32, 128), jnp.bfloat16), jnp.zeros((4,), jnp.int32)
@@ -279,7 +280,7 @@ def test_the_windowed_kernels_walk_a_windows_blocks_and_have_their_names():
     assert "paged_attn_window" in pre and "window_attn" not in pre
     assert "window_attn_prefill" in wpre and "paged_attn" not in wpre
     assert "grid=(4, 1, 28)" in step and "grid=(4, 1, 5)" in wstep
-    assert "grid=(4, 1, 4, 224)" in pre and "grid=(4, 1, 4, 34)" in wpre
+    assert "grid=(4, 1, 1, 224)" in pre and "grid=(4, 1, 1, 35)" in wpre
 
 
 def test_ring_positions_hold_the_window_and_a_calls_tokens():

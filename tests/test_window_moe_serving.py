@@ -113,6 +113,25 @@ def test_engine_served_logits_agree_with_reference_forward(kernels):
             <= 1.5 * s["win_step_live_keys"]
     else:  # the masked form is handed the ring
         assert s["win_step_keys_fetched"] == layers[0] * 44 * steps
+    # the prefill calls alone, 4 rows each: of the chunk's 8 tokens or
+    # the narrow program's 4, a row one query tile. A full layer walks
+    # the table's 32 pages a row, a sliding layer the pages that a
+    # window of 8 and a tile of 8 (4) tokens can span: 5 (4)
+    assert eng.module.device_counters[-2:] == (
+        "attn_prefill_grid_steps", "attn_prefill_tokens")
+    def a_call(pages_walked_by_a_sliding_layer):
+        return int(kernels) * 4 * (
+            layers[1] * 32 + layers[0] * pages_walked_by_a_sliding_layer)
+
+    calls = s["prefill_calls"]
+    assert s["attn_prefill_tokens"] == 32 * calls
+    assert s["attn_prefill_grid_steps"] == calls * a_call(5)
+    eng.submit(99, reqs[0][:4], 2)  # 3 tokens to ingest: the narrow one
+    drain(eng)
+    assert eng.stats["prefill_calls"] == calls + 1
+    assert eng.stats["attn_prefill_tokens"] == 32 * calls + 16
+    assert eng.stats["attn_prefill_grid_steps"] == calls * a_call(5) \
+        + a_call(4)
 
 
 def test_one_long_prompt_is_one_call_whose_rows_see_each_other():
