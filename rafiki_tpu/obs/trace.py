@@ -141,24 +141,45 @@ def _copy(rec: Dict[str, Any]) -> Dict[str, Any]:
 #: began (0: none), ``key`` a request id for request records, else None.
 SpanRecord = Tuple[str, int, int, int, int, Any, Optional[Dict[str, Any]]]
 
-#: records the ring holds: ~12 a decode turn at 18 turns a second is over
-#: two minutes, enough for a profiled stretch to be read after it ended
-SPAN_RING_MAXLEN = 32768
+#: records the ring holds (~200 B each: ~26 MB). A decode turn writes 12
+#: (the turn, its leaves and the preps' and the sync's children, the
+#: caller's poll), a prefill call 5 and a request 6 (its submit and five
+#: instants): the serving cells wrote 17-20 a turn (PR 39), so at 40 turns
+#: a second this is over two minutes — a 45 s window, a 3 s traced stretch
+#: and a 60 s drain still hold the window's opening ``engine.stats_reset``
+#: (tests/test_phase_spans.py holds the count)
+SPAN_RING_MAXLEN = 131072
+
+
+class _Stack(list):
+    """A thread's open spans (their seqs, innermost last) and its last
+    stamp: the time ``t`` and the span a child opening now would have as
+    its parent for that stamp to be its own opening (``owner``) — the
+    span that opened at ``t``, or the parent of the one that closed."""
+
+    __slots__ = ("owner", "t")
+
+    def __init__(self) -> None:
+        super().__init__()
+        self.owner, self.t = -1, 0
 
 
 class _Span:
-    """One open span: the context manager :meth:`SpanRing.span` hands
-    out. Its record is appended when it closes (so a parent follows its
-    children in the ring); ``t0`` / ``t1`` / ``seq`` stay readable."""
+    """One open span: the context manager :meth:`SpanRing.span` (or
+    :meth:`SpanRing.tile`) hands out. Its record is appended when it
+    closes (so a parent follows its children in the ring); ``t0`` /
+    ``t1`` / ``seq`` stay readable."""
 
     __slots__ = ("_ring", "_stack", "name", "attrs", "t0", "t1", "seq",
-                 "parent_seq")
+                 "parent_seq", "tile")
 
     def __init__(self, ring: "SpanRing", name: str,
-                 attrs: Optional[Dict[str, Any]]) -> None:
+                 attrs: Optional[Dict[str, Any]],
+                 tile: bool = False) -> None:
         self._ring = ring
         self.name = name
         self.attrs = attrs
+        self.tile = tile
         self.t1 = 0
 
     def set(self, **attrs: Any) -> None:
@@ -171,15 +192,21 @@ class _Span:
     def __enter__(self) -> "_Span":
         ring = self._ring
         self._stack = stack = ring._stack()
-        self.parent_seq = stack[-1] if stack else 0
+        self.parent_seq = parent = stack[-1] if stack else 0
         self.seq = seq = next(ring._seq)
         stack.append(seq)
-        self.t0 = time_ns()
+        t0 = time_ns()
+        if self.tile and stack.owner == parent:
+            t0 = stack.t
+        self.t0 = stack.t = t0
+        stack.owner = seq
         return self
 
     def __exit__(self, *_exc: Any) -> bool:
         self.t1 = t1 = time_ns()
-        self._stack.pop()
+        stack = self._stack
+        stack.pop()
+        stack.owner, stack.t = self.parent_seq, t1
         self._ring._ring.append((self.name, self.t0, t1, self.parent_seq,
                                  self.seq, None, self.attrs))
         return False
@@ -200,17 +227,25 @@ class SpanRing:
         self._seq = itertools.count(1)
         self._local = threading.local()
 
-    def _stack(self) -> List[int]:
+    def _stack(self) -> _Stack:
         try:
             return self._local.stack
         except AttributeError:
-            stack = self._local.stack = []
+            stack = self._local.stack = _Stack()
             return stack
 
     def span(self, name: str, **attrs: Any) -> _Span:
         """``with ring.span("engine.admit"): ...`` — nests under the span
         open on this thread."""
         return _Span(self, name, attrs or None)
+
+    def tile(self, name: str, **attrs: Any) -> _Span:
+        """A span that TILES its parent: it opens where its previous
+        sibling on this thread closed or, the first, where the parent
+        opened — so the code between two such children, and the spans'
+        own cost, fall in the next one, and a parent tiled by them has
+        no hole but its tail. Otherwise as :meth:`span`."""
+        return _Span(self, name, attrs or None, True)
 
     def instant(self, name: str, key: Any = None,
                 parent_seq: Optional[int] = None, **attrs: Any) -> int:

@@ -547,7 +547,10 @@ class DecodeEngine:
             # step() calls, nanoseconds inside them outside the output
             # sync (admission, operand building, launches, harvest),
             # and nanoseconds blocked in that sync waiting on the device
-            "turns": 0, "turn_host_ns": 0, "sync_wait_ns": 0})
+            "turns": 0, "turn_host_ns": 0, "sync_wait_ns": 0,
+            # host-to-device arrays the calls' ``engine.upload`` spans
+            # copied (the page table only where it changed)
+            "h2d_copies": 0})
         self.params = params
         if self.host_pages:
             self.tier = HostPageTier(self.host_pages, self.stats)
@@ -1078,6 +1081,22 @@ class DecodeEngine:
             self._ptab_dirty = False
         return self._ptab_dev
 
+    def _upload(self, *host: np.ndarray, table: bool = False
+                ) -> List[jnp.ndarray]:
+        """A call's host operands on the device: one ``jnp.asarray`` an
+        array, in the order given, then (``table``) the page-table
+        operand of :meth:`_ptab_arg`. One ``engine.upload`` span; the
+        arrays that actually moved are booked as ``h2d_copies``."""
+        with SPANS.tile("engine.upload"):
+            out = [jnp.asarray(a) for a in host]
+            moved = len(out)
+            if table:
+                held = self._ptab_dev
+                out.append(self._ptab_arg())
+                moved += out[-1] is not held
+            self.stats.inc("h2d_copies", moved)
+        return out
+
     @_spanned("engine.poll")
     def poll(self) -> List[Tuple[Any, List[int]]]:
         """Completed (request_id, generated ids) since the last poll."""
@@ -1497,8 +1516,9 @@ class DecodeEngine:
         rewrite of a cache entry, harmless by construction. Either way
         one fixed-shape program serves any admission mix. Returns
         the number of calls made; each is one ``engine.prefill_prep``
-        span (its operands) and one ``engine.prefill_dispatch`` (the
-        launch, then the call's counters and position advance)."""
+        span (its operands: ``engine.plan`` / ``page_map`` / ``upload``)
+        and one ``engine.prefill_dispatch`` (the launch, then the call's
+        counters and position advance)."""
         occupied = np.array([s is not None for s in self._slots],
                             bool)
         calls = 0
@@ -1507,14 +1527,14 @@ class DecodeEngine:
                 prep = self._prefill_operands(occupied)
             if prep is None:
                 return calls
-            fill_fn, adv, tok_dev, pos_dev, aid_dev, ptab, rows = prep
+            fill_fn, adv, row_real, operands = prep
             calls += 1
             with SPANS.span("engine.prefill_dispatch") as dispatch:
-                if rows is not None:  # a gathered call
-                    dispatch.set(rows=int(np.count_nonzero(rows[1])),
+                if row_real is not None:  # a gathered call
+                    dispatch.set(rows=int(np.count_nonzero(row_real)),
                                  lanes=int(np.count_nonzero(adv)))
-                    rows = (tuple(jnp.asarray(r) for r in rows)
-                            if self._slot_state else None)
+                tok_dev, pos_dev, aid_dev, ptab, *rows = operands
+                rows = tuple(rows) or None
                 if self._counts_dev is None:
                     self._cache = fill_fn(
                         self.params, self._cache, tok_dev, pos_dev,
@@ -1526,35 +1546,62 @@ class DecodeEngine:
                 if self._draft_cache is not None and self._draft_synced:
                     # keep the draft's KV in lockstep with the prompt
                     # walk (while desynced, resync rebuilds prompts
-                    # anyway)
+                    # anyway); a draft engine's calls are slot-wide, so
+                    # ``ptab`` is the engine's own table
                     self._draft_cache = self._draft_sync_c(
                         self.draft_params, self._draft_cache, tok_dev,
-                        pos_dev, aid_dev, self._ptab_arg())
-                del prep, tok_dev, pos_dev, aid_dev, ptab, rows  # see _turn()
+                        pos_dev, aid_dev, ptab)
+                # see _turn()
+                del prep, operands, tok_dev, pos_dev, aid_dev, ptab, rows
                 self._book_prefill(adv)
 
     def _prefill_operands(self, occupied: np.ndarray) -> Optional[Tuple]:
-        """One chunk call's program, advance and device operands, or
-        None when no occupied lane has prompt left to ingest. On a
-        paged engine the call's rows are GATHERED (:meth:`_gathered_rows`);
-        elsewhere one row a slot."""
-        rem = np.where(occupied,
-                       np.maximum(0, (self._prompt_len - 1)
-                                  - self._pos), 0)
-        if rem.max() == 0:
-            return None
-        lanes = (np.flatnonzero(rem)[:self._prefill_lanes]
-                 if self._prefill_lanes else np.arange(self.B))
-        fill_fn, c_use = self._prefill_fn, self.C
-        if (self._prefill_fn_small is not None
-                and self._draft_cache is None
-                and rem[lanes].max() <= self._small_c):
-            # short remainder: the narrow program ingests it
-            # without the C-wide call's cost (the draft mirror is
-            # compiled at C only, so draft engines stay wide)
-            fill_fn, c_use = self._prefill_fn_small, self._small_c
-        if self._prefill_lanes:
-            return (fill_fn,) + self._gathered_rows(rem, lanes, c_use)
+        """One chunk call's program, advance, real tokens a row (None:
+        one row a slot) and device operands — tokens, positions, adapter
+        ids, table, and on a slot-state engine each row's slot and real
+        tokens — or None when no occupied lane has prompt left to
+        ingest. On a paged engine the call's rows are GATHERED
+        (:meth:`_gathered_rows`); elsewhere one row a slot. Tiled by
+        ``engine.plan``, ``engine.page_map`` (paged) and
+        ``engine.upload``."""
+        with SPANS.tile("engine.plan"):
+            rem = np.where(occupied,
+                           np.maximum(0, (self._prompt_len - 1)
+                                      - self._pos), 0)
+            if rem.max() == 0:
+                return None
+            lanes = (np.flatnonzero(rem)[:self._prefill_lanes]
+                     if self._prefill_lanes else np.arange(self.B))
+            fill_fn, c_use = self._prefill_fn, self.C
+            if (self._prefill_fn_small is not None
+                    and self._draft_cache is None
+                    and rem[lanes].max() <= self._small_c):
+                # short remainder: the narrow program ingests it
+                # without the C-wide call's cost (the draft mirror is
+                # compiled at C only, so draft engines stay wide)
+                fill_fn, c_use = self._prefill_fn_small, self._small_c
+            if self._prefill_lanes:
+                adv, tok_chunk, pos_chunk, row_lane, row_real = \
+                    self._gathered_rows(rem, lanes, c_use)
+            else:
+                adv, tok_chunk, pos_chunk = self._slot_rows(rem, c_use)
+                row_real = None
+        if row_real is None:
+            if self.paged:
+                with SPANS.tile("engine.page_map"):
+                    self._map_prefill_pages(adv)
+            return (fill_fn, adv, None, self._upload(
+                tok_chunk, pos_chunk, self._aid, table=True))
+        with SPANS.tile("engine.page_map"):
+            self._map_prefill_pages(adv)
+            aid, ptab, slots = self._row_tables(row_lane)
+        return (fill_fn, adv, row_real, self._upload(
+            tok_chunk, pos_chunk, aid, ptab,
+            *((slots, row_real) if self._slot_state else ())))
+
+    def _slot_rows(self, rem: np.ndarray, c_use: int) -> Tuple:
+        """An unpaged (or draft) call's rows, one a slot: ``(adv,
+        tokens, positions)``."""
         adv = np.minimum(rem, c_use)
         tok_chunk = np.empty((self.B, c_use), np.int32)
         pos_chunk = np.empty((self.B, c_use), np.int32)
@@ -1571,10 +1618,7 @@ class DecodeEngine:
             else:
                 tok_chunk[i, :] = self._tok[i]
                 pos_chunk[i, :] = self._pos[i]
-        self._map_prefill_pages(adv)
-        return (fill_fn, adv, jnp.asarray(tok_chunk),
-                jnp.asarray(pos_chunk), jnp.asarray(self._aid),
-                self._ptab_arg(), None)
+        return adv, tok_chunk, pos_chunk
 
     def _gathered_rows(self, rem: np.ndarray, lanes: np.ndarray,
                        c_use: int) -> Tuple:
@@ -1586,7 +1630,8 @@ class DecodeEngine:
         row attends, and a query sees the keys at or before its own
         position, whichever row wrote them. Rows left over carry token
         0 at position 0 through an all-zero table row: the scratch page.
-        Returns ``(adv, tokens, positions, adapter ids, table rows)``."""
+        Returns ``(adv, tokens, positions, each row's lane, real tokens
+        a row)``."""
         n_rows = self._prefill_lanes
         adv = np.zeros((self.B,), rem.dtype)
         tok_chunk = np.zeros((n_rows, c_use), np.int32)
@@ -1606,19 +1651,22 @@ class DecodeEngine:
                 row_lane.append(i)
                 adv[i] += a
                 left, p0 = left - a, p0 + a
-        self._map_prefill_pages(adv)
-        # the rows' table rows at the engine's live width (the widths
-        # the step program meets, so no shape of its own), read AFTER
-        # the allocation: a lane parked by it has a zeroed row
-        n = len(row_lane)
+        return adv, tok_chunk, pos_chunk, row_lane, row_real
+
+    def _row_tables(self, row_lane: List[int]) -> Tuple:
+        """A gathered call's rows mapped to their lanes: adapter ids,
+        table rows at the engine's live width (the widths the step
+        program meets, so no shape of its own) and slots (``B``: a
+        spare row), read AFTER the call's page allocation — a lane
+        parked by it has a zeroed row."""
+        n_rows, n = self._prefill_lanes, len(row_lane)
         aid = np.zeros((n_rows,), np.int32)
         aid[:n] = self._aid[row_lane]
         ptab = np.zeros((n_rows, self._live_table_width()), np.int32)
         ptab[:n] = self._ptab[row_lane, :ptab.shape[1]]
         slots = np.full((n_rows,), self.B, np.int32)
         slots[:n] = row_lane
-        return (adv, jnp.asarray(tok_chunk), jnp.asarray(pos_chunk),
-                jnp.asarray(aid), jnp.asarray(ptab), (slots, row_real))
+        return aid, ptab, slots
 
     def _map_prefill_pages(self, adv: np.ndarray) -> None:
         """Lazy allocation tracks the prompt walk: each chunk call only
@@ -1867,12 +1915,14 @@ class DecodeEngine:
         steps for every live slot, harvest completions. Returns live
         count (at admission time).
 
-        One ``engine.turn`` span whose body is tiled by the leaf spans
+        One ``engine.turn`` span whose body is tiled by the spans
         ``engine.admit`` / ``prefill_prep`` / ``prefill_dispatch`` /
         ``decode_prep`` / ``decode_dispatch`` / ``sync_wait`` /
-        ``harvest`` (docs/observability.md "Phase spans"); the counters
-        ``turns``, ``turn_host_ns`` and ``sync_wait_ns`` are cut at the
-        same boundaries."""
+        ``harvest``, the preps by ``engine.plan`` / ``page_map`` /
+        ``upload`` and the sync by ``engine.sync_ready`` / ``sync_pull``
+        (docs/observability.md "Phase spans"); the counters ``turns``,
+        ``turn_host_ns`` and ``sync_wait_ns`` are cut at the same
+        boundaries."""
         self._turn_sync_ns = 0
         with SPANS.span("engine.turn") as turn:
             self._turn_seq = turn.seq
@@ -1894,10 +1944,8 @@ class DecodeEngine:
                           and self._prefill_fn is not None)
         n_prefill = self._chunked_prefill() if prefilling else 0
         with SPANS.span("engine.decode_prep"):
-            if prefilling:
-                for rid, row, plen, cls, resumed in admitted_info:
-                    self._span("prefill", rid, prompt_tokens=plen)
-            call = self._decode_prep(live, admitted)
+            call = self._decode_prep(
+                live, admitted, admitted_info if prefilling else ())
         turn.set(live=len(live), admitted=len(admitted_info),
                  prefill_calls=n_prefill,
                  path=call[0] if call else "idle")
@@ -1928,9 +1976,19 @@ class DecodeEngine:
     def _sync_wait(self, *outputs: Any) -> List[np.ndarray]:
         """Pull a launch's outputs to the host: the one place the loop
         blocks on the device. One ``engine.sync_wait`` span, counted
-        into the turn's ``sync_wait_ns``."""
+        into the turn's ``sync_wait_ns``, tiled by ``engine.sync_ready``
+        (the host waits for the chip's program to finish) and
+        ``engine.sync_pull`` (the copies to the host)."""
         with SPANS.span("engine.sync_wait") as wait:
-            pulled = [np.asarray(x) for x in outputs]  # rafiki: noqa[blocking-transfer-in-decode-loop] — the decode loop's output sync; each caller says why it must pull
+            with SPANS.tile("engine.sync_ready"):
+                # the first output's copy is asked for before the wait,
+                # as a pull of the unfinished array asked for it; the
+                # others after it, as they were: the split keeps the
+                # copies' order
+                outputs[0].copy_to_host_async()
+                jax.block_until_ready(outputs)  # rafiki: noqa[blocking-transfer-in-decode-loop] — the decode loop's output sync, its wait apart from its copies
+            with SPANS.tile("engine.sync_pull"):
+                pulled = [np.asarray(x) for x in outputs]  # rafiki: noqa[blocking-transfer-in-decode-loop] — the decode loop's output sync; each caller says why it must pull
         self._turn_sync_ns += wait.t1 - wait.t0
         return pulled
 
@@ -2040,68 +2098,83 @@ class DecodeEngine:
                        slo=cls, resumed=resumed)
         return live, admitted, admitted_info
 
-    def _decode_prep(self, live: List[int], admitted: bool
+    def _decode_prep(self, live: List[int], admitted: bool,
+                     prefilled: Sequence[Tuple] = ()
                      ) -> Optional[Tuple[str, List[int], bool, Tuple]]:
         """Everything between prefill and the decode launch: settle the
         lanes, pick the path, map the pages the call will write and
-        build its operands. Returns ``(path, live, any_sampling,
+        copy its operands. Returns ``(path, live, any_sampling,
         operands)`` with ``path`` ``"scan"`` or ``"spec"`` (operands
         empty: :meth:`_speculative_step` builds its own), or None when
-        no lane is left to run."""
-        if not live:
-            self._prefetch_hint()
-            return None
-        # prefill-only slots that reached their last prompt token are
-        # done NOW: extract their KV shipment and free the lane before
-        # the decode scan (they never generate)
-        self._harvest_prefill_only()
-        # chunked prefill / prefill-only harvest may have parked or
-        # freed lanes: the scan must see the CURRENT occupancy
-        live = [i for i in range(self.B) if self._slots[i] is not None]
+        no lane is left to run. Tiled by ``engine.plan`` (twice where
+        the prompts are refreshed between), ``engine.upload`` and
+        ``engine.page_map``."""
+        with SPANS.tile("engine.plan"):
+            for rid, _row, plen, _cls, _resumed in prefilled:
+                self._span("prefill", rid, prompt_tokens=plen)
+            if not live:
+                self._prefetch_hint()
+                return None
+            # prefill-only slots that reached their last prompt token
+            # are done NOW: extract their KV shipment and free the lane
+            # before the decode scan (they never generate)
+            self._harvest_prefill_only()
+            # chunked prefill / prefill-only harvest may have parked or
+            # freed lanes: the scan must see the CURRENT occupancy
+            live = [i for i in range(self.B) if self._slots[i] is not None]
         if admitted or self._prompt_dev is None:
             # refresh the device-resident prompts only when they changed
-            self._prompt_dev = jnp.asarray(self._prompt_buf)
-        self._prefetch_hint()
-        if not live:
-            return None
-
-        any_sampling = bool(any(
-            self._slots[i] is not None and self._slots[i].temperature > 0
-            for i in range(self.B)))
-        # speculative path: all live slots greedy, past their prompts,
-        # room for a full draft window in the cache, and recent
-        # acceptance above break-even (or a periodic re-probe) —
-        # otherwise this fused call runs the plain scan (the paths
-        # interleave freely call-to-call; both emit exact argmax tokens)
-        if (self._verify_fn is not None and not any_sampling
-                and (self._spec_ema >= self._spec_floor
-                     or self._spec_idle >= SPEC_REPROBE_CALLS)
-                and all(self._pos[i] >= len(self._slots[i].prompt) - 1
-                        and int(self._pos[i]) + self.spec_k <= self.L
-                        for i in live)):
-            return "spec", live, any_sampling, ()
-        if self._verify_fn is not None:
-            self._spec_idle += 1
+            self._prompt_dev, = self._upload(self._prompt_buf)
+        with SPANS.tile("engine.plan"):
+            self._prefetch_hint()
+            if not live:
+                return None
+            any_sampling = bool(any(
+                self._slots[i] is not None
+                and self._slots[i].temperature > 0
+                for i in range(self.B)))
+            # speculative path: all live slots greedy, past their
+            # prompts, room for a full draft window in the cache, and
+            # recent acceptance above break-even (or a periodic
+            # re-probe) — otherwise this fused call runs the plain scan
+            # (the paths interleave freely call-to-call; both emit exact
+            # argmax tokens)
+            if (self._verify_fn is not None and not any_sampling
+                    and (self._spec_ema >= self._spec_floor
+                         or self._spec_idle >= SPEC_REPROBE_CALLS)
+                    and all(self._pos[i] >= len(self._slots[i].prompt) - 1
+                            and int(self._pos[i]) + self.spec_k <= self.L
+                            for i in live)):
+                return "spec", live, any_sampling, ()
+            if self._verify_fn is not None:
+                self._spec_idle += 1
         if self.paged:
-            for i in live:
-                # the fused scan writes positions pos..pos+K-1, frozen
-                # at stop_pos-1: map exactly that window's pages. The
-                # slot re-check guards tiered engines: an earlier
-                # lane's growth can PARK this one inside this loop —
-                # allocating for the emptied lane would leak its pages
-                if self._slots[i] is None:
-                    continue
-                self._ensure_pages_to(i, min(
-                    int(self._pos[i]) + self.K,
-                    int(self._stop_pos[i])) - 1)
+            with SPANS.tile("engine.page_map"):
+                for i in live:
+                    # the fused scan writes positions pos..pos+K-1,
+                    # frozen at stop_pos-1: map exactly that window's
+                    # pages. The slot re-check guards tiered engines: an
+                    # earlier lane's growth can PARK this one inside
+                    # this loop — allocating for the emptied lane would
+                    # leak its pages
+                    if self._slots[i] is None:
+                        continue
+                    self._ensure_pages_to(i, min(
+                        int(self._pos[i]) + self.K,
+                        int(self._stop_pos[i])) - 1)
         return "scan", live, any_sampling, (
-            self.params, self._cache, jnp.asarray(self._tok),
-            jnp.asarray(self._pos), self._prompt_dev,
-            jnp.asarray(self._prompt_len), jnp.asarray(self._stop_pos),
-            jnp.asarray(self._temp), jnp.asarray(self._topk),
-            jnp.asarray(self._topp), jnp.asarray(self._seed),
-            jnp.asarray(self._aid), self._ptab_arg(),
+            self.params, self._cache, *self._step_operands(),
             *(() if self._counts_dev is None else (self._counts_dev,)))
+
+    def _step_operands(self) -> List[jnp.ndarray]:
+        """A step program's operands after its weights and cache: the
+        per-slot inputs, uploaded, with the device-resident prompts in
+        third place."""
+        out = self._upload(self._tok, self._pos, self._prompt_len,
+                           self._stop_pos, self._temp, self._topk,
+                           self._topp, self._seed, self._aid, table=True)
+        out.insert(2, self._prompt_dev)
+        return out
 
     def _harvest_scan(self, live: List[int], emitted: np.ndarray,
                       any_sampling: bool) -> None:
@@ -2284,22 +2357,23 @@ class DecodeEngine:
         KV rows ABOVE the slot's new position — unreachable by the
         position mask, and rewritten in place when generation reaches
         them (the admission-reuse invariant already relies on this).
-        The same four leaf spans as the scan: ``engine.decode_prep`` /
-        ``decode_dispatch`` / ``sync_wait`` / ``harvest`` (a draft
-        model's own scan and pull are a dispatch and a wait too)."""
+        The same spans as the scan: ``engine.decode_prep`` (its
+        ``plan`` / ``upload`` / ``page_map``) / ``decode_dispatch`` /
+        ``sync_wait`` (``sync_ready`` / ``sync_pull``) / ``harvest``; a
+        draft model's own scan and pull are a prep, a dispatch and a
+        wait too, its verify mirror is launched in the verify's
+        dispatch, and a re-probe's rebuild of its cache lies in
+        ``engine.plan``."""
         k = self.spec_k
         if self._draft_cache is not None:
             with SPANS.span("engine.decode_prep"):
-                if not self._draft_synced:  # re-probe after a gated-off
-                    self._resync_draft()    # stretch with skipped mirrors
-                draft_operands = (
-                    self.draft_params, self._draft_cache,
-                    jnp.asarray(self._tok), jnp.asarray(self._pos),
-                    self._prompt_dev, jnp.asarray(self._prompt_len),
-                    jnp.asarray(self._stop_pos), jnp.asarray(self._temp),
-                    jnp.asarray(self._topk), jnp.asarray(self._topp),
-                    jnp.asarray(self._seed), jnp.asarray(self._aid),
-                    self._ptab_arg())
+                with SPANS.tile("engine.plan"):
+                    # a re-probe after a gated-off stretch with skipped
+                    # mirrors rebuilds the draft cache first
+                    if not self._draft_synced:
+                        self._resync_draft()
+                draft_operands = (self.draft_params, self._draft_cache,
+                                  *self._step_operands())
             # draft phase: k-1 fused greedy steps on the DRAFT model
             # (argmax feedback), advancing its synced cache; then the
             # verify mirror writes the window's inputs [tok, drafts]
@@ -2311,45 +2385,51 @@ class DecodeEngine:
             # draft tokens feed the host-built verify operands: one
             # pull per K-token window
             d_emit, = self._sync_wait(d_emit)
-            drafts = d_emit.T.astype(np.int32)
+        mirror = ()
         with SPANS.span("engine.decode_prep"):
+            with SPANS.tile("engine.plan"):
+                if self._draft_cache is not None:
+                    drafts = d_emit.T.astype(np.int32)
+                    offs = np.arange(k, dtype=np.int32)[None, :]
+                    window = (np.concatenate(
+                        [self._tok[:, None], drafts], axis=1),
+                        self._pos[:, None] + offs)
+                else:
+                    drafts = np.zeros((self.B, k - 1), np.int32)
+                    for i in live:
+                        s = self._slots[i]
+                        ctx = np.concatenate(
+                            [s.prompt, np.asarray(s.generated, np.int32)])
+                        drafts[i] = _ngram_draft(ctx, k - 1)
             if self._draft_cache is not None:
-                offs = np.arange(k, dtype=np.int32)[None, :]
-                self._draft_cache = self._draft_sync_v(
-                    self.draft_params, self._draft_cache,
-                    jnp.asarray(np.concatenate(
-                        [self._tok[:, None], drafts], axis=1)),
-                    jnp.asarray(self._pos[:, None] + offs),
-                    jnp.asarray(self._aid), self._ptab_arg())
-                self.stats.inc("spec_draft_model_calls")
-            else:
-                drafts = np.zeros((self.B, k - 1), np.int32)
-                for i in live:
-                    s = self._slots[i]
-                    ctx = np.concatenate(
-                        [s.prompt, np.asarray(s.generated, np.int32)])
-                    drafts[i] = _ngram_draft(ctx, k - 1)
+                mirror = self._upload(*window, self._aid, table=True)
             if self.paged:
-                for i in live:
-                    # the verify window writes positions pos..pos+k-1
-                    # (gated above to fit the cache); its pages must
-                    # exist even for drafts that end up rejected — the
-                    # standard unreachable-then-rewritten rows, inside
-                    # reservation. Slot re-check: a mid-loop park
-                    # (tiered page reclaim) empties a later lane — see
-                    # _chunked_prefill
-                    if self._slots[i] is None:
-                        continue
-                    self._ensure_pages_to(i, min(
-                        int(self._pos[i]) + k - 1, self.L - 1))
-            operands = (
-                self.params, self._cache, jnp.asarray(self._tok),
-                jnp.asarray(self._pos), jnp.asarray(drafts),
-                jnp.asarray(self._stop_pos), jnp.asarray(self._aid),
-                self._ptab_arg())
+                with SPANS.tile("engine.page_map"):
+                    for i in live:
+                        # the verify window writes positions
+                        # pos..pos+k-1 (gated above to fit the cache);
+                        # its pages must exist even for drafts that end
+                        # up rejected — the standard unreachable-then-
+                        # rewritten rows, inside reservation. Slot
+                        # re-check: a mid-loop park (tiered page
+                        # reclaim) empties a later lane — see
+                        # _chunked_prefill
+                        if self._slots[i] is None:
+                            continue
+                        self._ensure_pages_to(i, min(
+                            int(self._pos[i]) + k - 1, self.L - 1))
+            operands = (self.params, self._cache, *self._upload(
+                self._tok, self._pos, drafts, self._stop_pos, self._aid,
+                table=True))
         with SPANS.span("engine.decode_dispatch"):
+            if mirror:
+                # the draft's verify mirror, on the table as it stood
+                # before this window's pages were mapped
+                self._draft_cache = self._draft_sync_v(
+                    self.draft_params, self._draft_cache, *mirror)
+                self.stats.inc("spec_draft_model_calls")
             self._cache, g, n_emit = self._verify_fn(*operands)
-            del operands  # see _turn()
+            del operands, mirror  # see _turn()
         # verify OUTPUT sync: accepted tokens must reach the host to
         # stream, and the acceptance counts gate the host-side emit
         g, n_emit = self._sync_wait(g, n_emit)
