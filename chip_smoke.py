@@ -392,15 +392,8 @@ def _drive_engine(model, prompts, cfg, **engine_kw):
 def _step_lowered_text(core) -> str:
     """The engine's own jitted greedy step, lowered with the operands
     ``DecodeEngine.step`` hands it."""
-    import jax.numpy as jnp
-
     return core._step_fns[False].lower(
-        core.params, core._cache, jnp.asarray(core._tok),
-        jnp.asarray(core._pos), jnp.asarray(core._prompt_buf),
-        jnp.asarray(core._prompt_len), jnp.asarray(core._stop_pos),
-        jnp.asarray(core._temp), jnp.asarray(core._topk),
-        jnp.asarray(core._topp), jnp.asarray(core._seed),
-        jnp.asarray(core._aid), core._ptab_arg()).as_text()
+        core.params, core._cache, *core._step_operands()).as_text()
 
 
 def _next_token_logits(core, contexts):

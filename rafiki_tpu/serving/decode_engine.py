@@ -88,6 +88,12 @@ SPEC_REPROBE_CALLS = 32
 #: MXU's tiles, and a call's cost is then the weights' streaming, about
 #: a decode step
 PREFILL_LANES = 8
+#: the per-slot columns a decode launch's packed operand starts with, in
+#: order (:func:`_step_columns`); ``temp`` and ``top_p`` travel as the
+#: bits of their float32. A verify call's operand starts with them too
+SLOT_COLUMNS = ("tok", "pos", "prompt_len", "stop_pos", "temp", "top_k",
+                "top_p", "seed", "aid")
+N_SLOT_COLUMNS = len(SLOT_COLUMNS)
 #: generated-token interval between decode_mark trace spans per slot —
 #: coarse enough to stay off the hot path, fine enough that a stalled
 #: generation shows WHERE it stalled in /debug/requests
@@ -291,22 +297,12 @@ class DecodeEngine:
         self._seq = 0  # arrival stamp: preemption evicts youngest
         self._done: List[Tuple[Any, List[int]]] = []
         self._lock = threading.Lock()
-        # host mirrors of the per-slot device inputs; prompts ride to the
-        # device so mid-scan prefill continues without host involvement
-        self._tok = np.zeros((self.B,), np.int32)
-        self._pos = np.zeros((self.B,), np.int32)
+        # prompts ride to the device so mid-scan prefill continues
+        # without host involvement
         self._prompt_buf = np.zeros((self.B, self.L), np.int32)
-        self._prompt_len = np.ones((self.B,), np.int32)
-        self._stop_pos = np.zeros((self.B,), np.int32)
-        # per-slot sampling config (device operands every fused step)
-        self._temp = np.zeros((self.B,), np.float32)
-        self._topk = np.zeros((self.B,), np.int32)
-        self._topp = np.ones((self.B,), np.float32)
-        self._seed = np.zeros((self.B,), np.int32)
         #: multi-adapter serving (module.n_adapters > 0): per-slot
         #: adapter selection, a device operand like the sampling knobs
         self.n_adapters = int(getattr(module, "n_adapters", 0) or 0)
-        self._aid = np.zeros((self.B,), np.int32)
         #: device-resident prompt copy, refreshed only on admission — the
         #: (B, L) buffer must not ride host→device on every dispatch
         self._prompt_dev: Optional[jnp.ndarray] = None
@@ -370,10 +366,21 @@ class DecodeEngine:
         self.paged_kernel_mode = _kernel_mode(module, self.paged)
         self.paged_kernel_active = self.paged_kernel_mode >= 1
         self.paged_kernel_windowed = self.paged_kernel_mode >= 2
-        self._ptab = np.zeros((self.B, self._n_table), np.int32)
-        self._ptab_dev = jnp.asarray(self._ptab)
-        self._ptab_dev_width = self._n_table
-        self._ptab_dirty = False
+        #: host mirrors of the per-slot device inputs, one int32 row a
+        #: slot: the ``SLOT_COLUMNS`` (tokens, positions, prompt lengths,
+        #: stop positions and the sampling knobs, the floats as float32
+        #: views of their bits; adapter ids) and then the page table. A
+        #: decode launch uploads ONE slice of it (:meth:`_step_operands`)
+        self._slot_host = np.zeros((self.B, N_SLOT_COLUMNS + self._n_table),
+                                   np.int32)
+        (self._tok, self._pos, self._prompt_len, self._stop_pos, temp,
+         self._topk, topp, self._seed, self._aid) = \
+            self._slot_host[:, :N_SLOT_COLUMNS].T
+        self._temp = temp.view(np.float32)
+        self._topp = topp.view(np.float32)
+        self._prompt_len[:] = 1
+        self._topp[:] = 1.0
+        self._ptab = self._slot_host[:, N_SLOT_COLUMNS:]
         self._cache = _empty_cache(module, self.B)
         #: int32 counts the module's step and prefill programs hand back
         #: beside their outputs (``module.device_counters`` names them,
@@ -549,7 +556,8 @@ class DecodeEngine:
             # and nanoseconds blocked in that sync waiting on the device
             "turns": 0, "turn_host_ns": 0, "sync_wait_ns": 0,
             # host-to-device arrays the calls' ``engine.upload`` spans
-            # copied (the page table only where it changed)
+            # copied: one packed array a call, and the prompts on a
+            # turn that admitted
             "h2d_copies": 0})
         self.params = params
         if self.host_pages:
@@ -764,7 +772,6 @@ class DecodeEngine:
             self._ptab[i, int(self._n_alloc[i])] = self._free_pages.pop()
             self._n_alloc[i] += 1
         if grew:
-            self._ptab_dirty = True
             used = self.n_pages - 1 - len(self._free_pages)
             self.stats.set("kv_pages_used", used)
             self.stats.max_set("kv_pages_high_water", used)
@@ -841,7 +848,6 @@ class DecodeEngine:
             for (t, _p), h in zip(take, host_ids):
                 rec.pages[t] = ("host", int(h))
             self._free_pages.extend(pool_ids)
-            self._ptab_dirty = True
             moved += len(take)
         if moved:
             self.stats.set("kv_pages_used",
@@ -882,7 +888,6 @@ class DecodeEngine:
         self._stop_pos[j] = 0
         self._ptab[j, :] = 0
         self._n_alloc[j] = 0
-        self._ptab_dirty = True
         if have_lock:
             self._n_res[j] = 0
         else:
@@ -980,7 +985,6 @@ class DecodeEngine:
             self._ptab[i, t] = p
         self._n_alloc[i] = len(rec.pages)
         self._n_res[i] = rec.n_res
-        self._ptab_dirty = True
         del self._parked[key]
         if self.tier is not None:
             self.tier.drop_staged(key)
@@ -1038,7 +1042,6 @@ class DecodeEngine:
                 int(p) for p in self._ptab[i, :n])
             self._ptab[i, :n] = 0
             self._n_alloc[i] = 0
-            self._ptab_dirty = True
         if have_lock:
             self._res_total -= int(self._n_res[i])
             self._n_res[i] = 0
@@ -1069,33 +1072,23 @@ class DecodeEngine:
             w *= 2
         return min(w, self._n_table)
 
-    def _ptab_arg(self) -> jnp.ndarray:
-        """The page-table operand every compiled call consumes (a tiny
-        constant zeros array on contiguous engines), re-uploaded only
-        when allocation changed it — and sliced to the live width (see
+    def _table(self) -> np.ndarray:
+        """The page table as a compiled call takes it (one zeros column
+        on contiguous engines): sliced to the live width (see
         :meth:`_live_table_width`) on paged engines."""
-        width = self._live_table_width() if self.paged else self._n_table
-        if self._ptab_dirty or width != self._ptab_dev_width:
-            self._ptab_dev = jnp.asarray(self._ptab[:, :width])
-            self._ptab_dev_width = width
-            self._ptab_dirty = False
-        return self._ptab_dev
+        return self._ptab[:, :self._live_table_width() if self.paged
+                          else self._n_table]
 
-    def _upload(self, *host: np.ndarray, table: bool = False
-                ) -> List[jnp.ndarray]:
-        """A call's host operands on the device: one ``jnp.asarray`` an
-        array, in the order given, then (``table``) the page-table
-        operand of :meth:`_ptab_arg`. One ``engine.upload`` span; the
-        arrays that actually moved are booked as ``h2d_copies``."""
+    def _upload(self, *parts: np.ndarray) -> jnp.ndarray:
+        """A call's host operands on the device as ONE int32 array:
+        ``parts`` side by side (:func:`_pack`), one ``jnp.asarray`` — an
+        upload costs the call, not its bytes. The program takes them
+        apart with static slices. One ``engine.upload`` span, booked as
+        one ``h2d_copies``."""
         with SPANS.tile("engine.upload"):
-            out = [jnp.asarray(a) for a in host]
-            moved = len(out)
-            if table:
-                held = self._ptab_dev
-                out.append(self._ptab_arg())
-                moved += out[-1] is not held
-            self.stats.inc("h2d_copies", moved)
-        return out
+            packed = jnp.asarray(_pack(*parts))
+            self.stats.inc("h2d_copies")
+        return packed
 
     @_spanned("engine.poll")
     def poll(self) -> List[Tuple[Any, List[int]]]:
@@ -1268,12 +1261,11 @@ class DecodeEngine:
         cache1 = _empty_cache(snap_module, 1)
         # one multi-token cache pass over the prefix (same program shape
         # as chunked prefill, batch 1, chunk = len(prefix))
-        fill = _make_prefill(snap_module, 1, len(prefix))
-        snap = fill(self.params, cache1, jnp.asarray(prefix[None, :]),
-                    jnp.arange(len(prefix), dtype=jnp.int32)[None, :],
-                    jnp.asarray([aid], jnp.int32),
-                    jnp.zeros((1, 1), jnp.int32))
         plen = len(prefix)
+        fill = _make_prefill(snap_module, 1, plen)
+        packed = self._upload(prefix[None, :], np.arange(plen)[None, :],
+                              np.array([aid]), np.zeros((1, 1), np.int32))
+        snap = fill(self.params, cache1, packed)
         install = _make_prefix_install(plen)
         # store only the populated rows: the snapshot allocates at
         # max_len but install() reads [:plen] — trimming cuts the
@@ -1296,11 +1288,7 @@ class DecodeEngine:
             # the draft's cost is pure waste)
             d1 = _empty_cache(self.draft_module, 1)
             d_fill = _make_prefill(self.draft_module, 1, plen)
-            d_snap = d_fill(self.draft_params, d1,
-                            jnp.asarray(prefix[None, :]),
-                            jnp.arange(plen, dtype=jnp.int32)[None, :],
-                            jnp.asarray([aid], jnp.int32),
-                            jnp.zeros((1, 1), jnp.int32))
+            d_snap = d_fill(self.draft_params, d1, packed)
             d_snap = jax.tree_util.tree_map(lambda p: p[:, :plen],
                                             d_snap)
             entry["draft_cache"] = jax.block_until_ready(d_snap)
@@ -1498,7 +1486,6 @@ class DecodeEngine:
                 self._n_alloc[:] = 0
                 self._n_res[:] = 0
                 self._res_total = 0
-                self._ptab_dirty = True
                 self.stats.set("kv_pages_used", 0)
         self._cache = _empty_cache(self.module, self.B)
         self._counts_dev = self._counts_zero
@@ -1527,41 +1514,35 @@ class DecodeEngine:
                 prep = self._prefill_operands(occupied)
             if prep is None:
                 return calls
-            fill_fn, adv, row_real, operands = prep
+            fill_fn, adv, row_real, packed = prep
             calls += 1
             with SPANS.span("engine.prefill_dispatch") as dispatch:
                 if row_real is not None:  # a gathered call
                     dispatch.set(rows=int(np.count_nonzero(row_real)),
                                  lanes=int(np.count_nonzero(adv)))
-                tok_dev, pos_dev, aid_dev, ptab, *rows = operands
-                rows = tuple(rows) or None
                 if self._counts_dev is None:
-                    self._cache = fill_fn(
-                        self.params, self._cache, tok_dev, pos_dev,
-                        aid_dev, ptab, rows=rows)
+                    self._cache = fill_fn(self.params, self._cache, packed)
                 else:
                     self._cache, self._counts_dev = fill_fn(
-                        self.params, self._cache, tok_dev, pos_dev,
-                        aid_dev, ptab, self._counts_dev, rows=rows)
+                        self.params, self._cache, packed, self._counts_dev)
                 if self._draft_cache is not None and self._draft_synced:
                     # keep the draft's KV in lockstep with the prompt
                     # walk (while desynced, resync rebuilds prompts
                     # anyway); a draft engine's calls are slot-wide, so
-                    # ``ptab`` is the engine's own table
+                    # the table is the engine's own
                     self._draft_cache = self._draft_sync_c(
-                        self.draft_params, self._draft_cache, tok_dev,
-                        pos_dev, aid_dev, ptab)
-                # see _turn()
-                del prep, operands, tok_dev, pos_dev, aid_dev, ptab, rows
+                        self.draft_params, self._draft_cache, packed)
+                del prep, packed  # see _turn()
                 self._book_prefill(adv)
 
     def _prefill_operands(self, occupied: np.ndarray) -> Optional[Tuple]:
         """One chunk call's program, advance, real tokens a row (None:
-        one row a slot) and device operands — tokens, positions, adapter
-        ids, table, and on a slot-state engine each row's slot and real
-        tokens — or None when no occupied lane has prompt left to
-        ingest. On a paged engine the call's rows are GATHERED
-        (:meth:`_gathered_rows`); elsewhere one row a slot. Tiled by
+        one row a slot) and packed device operand — tokens, positions,
+        adapter ids, on a slot-state engine each row's slot and real
+        tokens, then the table (:func:`_prefill_columns`) — or None when
+        no occupied lane has prompt left to ingest. On a paged engine
+        the call's rows are GATHERED (:meth:`_gathered_rows`); elsewhere
+        one row a slot. Tiled by
         ``engine.plan``, ``engine.page_map`` (paged) and
         ``engine.upload``."""
         with SPANS.tile("engine.plan"):
@@ -1591,13 +1572,13 @@ class DecodeEngine:
                 with SPANS.tile("engine.page_map"):
                     self._map_prefill_pages(adv)
             return (fill_fn, adv, None, self._upload(
-                tok_chunk, pos_chunk, self._aid, table=True))
+                tok_chunk, pos_chunk, self._aid, self._table()))
         with SPANS.tile("engine.page_map"):
             self._map_prefill_pages(adv)
             aid, ptab, slots = self._row_tables(row_lane)
         return (fill_fn, adv, row_real, self._upload(
-            tok_chunk, pos_chunk, aid, ptab,
-            *((slots, row_real) if self._slot_state else ())))
+            tok_chunk, pos_chunk, aid,
+            *((slots, row_real) if self._slot_state else ()), ptab))
 
     def _slot_rows(self, rem: np.ndarray, c_use: int) -> Tuple:
         """An unpaged (or draft) call's rows, one a slot: ``(adv,
@@ -1662,8 +1643,9 @@ class DecodeEngine:
         n_rows, n = self._prefill_lanes, len(row_lane)
         aid = np.zeros((n_rows,), np.int32)
         aid[:n] = self._aid[row_lane]
-        ptab = np.zeros((n_rows, self._live_table_width()), np.int32)
-        ptab[:n] = self._ptab[row_lane, :ptab.shape[1]]
+        table = self._table()
+        ptab = np.zeros((n_rows, table.shape[1]), np.int32)
+        ptab[:n] = table[row_lane]
         slots = np.full((n_rows,), self.B, np.int32)
         slots[:n] = row_lane
         return aid, ptab, slots
@@ -1791,7 +1773,6 @@ class DecodeEngine:
         hbm = rec.hbm_ids()
         if hbm:
             self._free_pages.extend(hbm)
-            self._ptab_dirty = True
         host = rec.host_ids()
         if host and self.tier is not None:
             self.tier.free(host)
@@ -1958,8 +1939,8 @@ class DecodeEngine:
             self._cache, emitted, *counts = \
                 self._step_fns[any_sampling](*operands)
             # the launch's operand handles die with the launch, as the
-            # temporaries they are (freeing eleven device buffers takes
-            # ~0.1 ms: inside a span, not in the turn's own time)
+            # temporaries they are (freeing device buffers takes time:
+            # inside a span, not in the turn's own time)
             del call, operands
         # the loop's OUTPUT sync: generated tokens must reach the host
         # to stream; the fused K-step scan amortizes it. The module's
@@ -2124,7 +2105,7 @@ class DecodeEngine:
             live = [i for i in range(self.B) if self._slots[i] is not None]
         if admitted or self._prompt_dev is None:
             # refresh the device-resident prompts only when they changed
-            self._prompt_dev, = self._upload(self._prompt_buf)
+            self._prompt_dev = self._upload(self._prompt_buf)
         with SPANS.tile("engine.plan"):
             self._prefetch_hint()
             if not live:
@@ -2166,15 +2147,13 @@ class DecodeEngine:
             self.params, self._cache, *self._step_operands(),
             *(() if self._counts_dev is None else (self._counts_dev,)))
 
-    def _step_operands(self) -> List[jnp.ndarray]:
+    def _step_operands(self) -> Tuple[jnp.ndarray, jnp.ndarray]:
         """A step program's operands after its weights and cache: the
-        per-slot inputs, uploaded, with the device-resident prompts in
-        third place."""
-        out = self._upload(self._tok, self._pos, self._prompt_len,
-                           self._stop_pos, self._temp, self._topk,
-                           self._topp, self._seed, self._aid, table=True)
-        out.insert(2, self._prompt_dev)
-        return out
+        per-slot columns and the live-width table, uploaded as one
+        array (a slice of the host mirrors), then the device-resident
+        prompts."""
+        width = N_SLOT_COLUMNS + self._table().shape[1]
+        return self._upload(self._slot_host[:, :width]), self._prompt_dev
 
     def _harvest_scan(self, live: List[int], emitted: np.ndarray,
                       any_sampling: bool) -> None:
@@ -2306,8 +2285,7 @@ class DecodeEngine:
                 pos_m[i, n:] = pos_m[i, n - 1]
             self._draft_cache = self._draft_sync_k(
                 self.draft_params, self._draft_cache,
-                jnp.asarray(tok_m), jnp.asarray(pos_m),
-                jnp.asarray(self._aid), self._ptab_arg())
+                self._upload(tok_m, pos_m, self._aid, self._table()))
         self._draft_synced = True
         self.stats.inc("draft_resyncs")
 
@@ -2345,9 +2323,8 @@ class DecodeEngine:
                     tok_m[i, j] = tok_m[i, j - 1] if j else cur
                     pos_m[i, j] = pos_m[i, j - 1] if j else p0
         self._draft_cache = self._draft_sync_k(
-            self.draft_params, self._draft_cache, jnp.asarray(tok_m),
-            jnp.asarray(pos_m), jnp.asarray(self._aid),
-            self._ptab_arg())
+            self.draft_params, self._draft_cache,
+            self._upload(tok_m, pos_m, self._aid, self._table()))
 
     def _speculative_step(self, live: List[int]) -> int:
         """One verify call: host-drafted continuations for every live
@@ -2402,7 +2379,7 @@ class DecodeEngine:
                             [s.prompt, np.asarray(s.generated, np.int32)])
                         drafts[i] = _ngram_draft(ctx, k - 1)
             if self._draft_cache is not None:
-                mirror = self._upload(*window, self._aid, table=True)
+                mirror = (self._upload(*window, self._aid, self._table()),)
             if self.paged:
                 with SPANS.tile("engine.page_map"):
                     for i in live:
@@ -2418,9 +2395,9 @@ class DecodeEngine:
                             continue
                         self._ensure_pages_to(i, min(
                             int(self._pos[i]) + k - 1, self.L - 1))
-            operands = (self.params, self._cache, *self._upload(
-                self._tok, self._pos, drafts, self._stop_pos, self._aid,
-                table=True))
+            operands = (self.params, self._cache, self._upload(
+                self._slot_host[:, :N_SLOT_COLUMNS], drafts,
+                self._table()))
         with SPANS.span("engine.decode_dispatch"):
             if mirror:
                 # the draft's verify mirror, on the table as it stood
@@ -2520,6 +2497,54 @@ def _ngram_draft(context: np.ndarray, k: int, max_n: int = 3) -> np.ndarray:
     return np.full(k, ctx[-1], np.int32)
 
 
+def _pack(*parts: np.ndarray) -> np.ndarray:
+    """``parts`` side by side as ONE int32 host array, a row a call row:
+    a vector is one column, a float32 array travels as its bits (a view:
+    no value is rounded)."""
+    cols = [(p.view(np.int32) if p.dtype == np.float32
+             else p.astype(np.int32, copy=False)).reshape(len(p), -1)
+            for p in map(np.asarray, parts)]
+    return cols[0] if len(cols) == 1 else np.concatenate(cols, axis=1)
+
+
+def _slot_columns(packed: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+    """The ``SLOT_COLUMNS`` of a packed operand, in order: static
+    slices, the floats bitcast back from their bits."""
+    return tuple(
+        jax.lax.bitcast_convert_type(packed[:, j], jnp.float32)
+        if name in ("temp", "top_p") else packed[:, j]
+        for j, name in enumerate(SLOT_COLUMNS))
+
+
+def _step_columns(packed: jnp.ndarray) -> Tuple[jnp.ndarray, ...]:
+    """A decode launch's operand, ``(slots, N_SLOT_COLUMNS + width)``,
+    taken apart: the slot columns, then the page table."""
+    return (*_slot_columns(packed), packed[:, N_SLOT_COLUMNS:])
+
+
+def _verify_columns(packed: jnp.ndarray, k: int
+                    ) -> Tuple[jnp.ndarray, ...]:
+    """A verify call's operand, ``(slots, N_SLOT_COLUMNS + k - 1 +
+    width)``, taken apart: tokens, positions, the ``k - 1`` drafts, stop
+    positions, adapter ids, the page table."""
+    tok, pos, _, stop_pos, *_, aid = _slot_columns(packed)
+    at = N_SLOT_COLUMNS + k - 1
+    return (tok, pos, packed[:, N_SLOT_COLUMNS:at], stop_pos, aid,
+            packed[:, at:])
+
+
+def _prefill_columns(packed: jnp.ndarray, chunk: int, slot_state: bool
+                     ) -> Tuple[Any, ...]:
+    """A prefill call's operand, ``(rows, 2 chunk + 1 [+ 2] + width)``,
+    taken apart: tokens and positions ``(rows, chunk)``, adapter ids,
+    with ``slot_state`` each row's slot and real tokens (else None), the
+    page table."""
+    at = 2 * chunk + 1
+    rows = (packed[:, at], packed[:, at + 1]) if slot_state else None
+    return (packed[:, :chunk], packed[:, chunk:2 * chunk],
+            packed[:, 2 * chunk], rows, packed[:, at + 2 * slot_state:])
+
+
 def _select_next(logits, temp, top_k, top_p, seed, pos):
     """Per-slot token selection on device: greedy when ``temp <= 0``,
     else temperature-scaled categorical over the top-k/top-p-filtered
@@ -2586,10 +2611,12 @@ def _make_step(module: Any, n_slots: int, k: int,
     ``stop_pos`` freeze (their tok/pos stop advancing) so a finished
     slot idles harmlessly for the remainder of the scan.
 
-    Multi-adapter modules additionally consume the per-slot ``aid``
-    operand (which stacked fine-tune each row decodes under); paged-KV
-    modules the per-slot ``ptab`` page tables (a tiny ignored constant
-    otherwise — one signature for both layouts). A module with
+    The host's per-slot inputs arrive as ONE packed int32 operand
+    (:func:`_step_columns`): multi-adapter modules consume its per-slot
+    ``aid`` (which stacked fine-tune each row decodes under); paged-KV
+    modules its page tables (an ignored zeros column otherwise — one
+    signature for both layouts), whose width is the operand's past the
+    slot columns, so the program compiles once a width. A module with
     ``device_counters`` takes their running int32 vector as one more
     operand and returns it, its K steps' counts added, as one more
     output (see :func:`_add_counts`).
@@ -2606,8 +2633,9 @@ def _make_step(module: Any, n_slots: int, k: int,
     mutable = _mutable_collections(module)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def step_fn(params, cache, tok, pos, prompt_buf, prompt_len, stop_pos,
-                temp, top_k, top_p, seed, aid, ptab, *counts):
+    def step_fn(params, cache, packed, prompt_buf, *counts):
+        (tok, pos, prompt_len, stop_pos, temp, top_k, top_p, seed, aid,
+         ptab) = _step_columns(packed)
         rows = jnp.arange(n_slots)
 
         def body(carry, _):
@@ -2661,7 +2689,8 @@ def _make_verify(module: Any, n_slots: int, k: int) -> Callable:
     paged = int(getattr(module, "kv_page_size", 0) or 0) > 0
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def verify_fn(params, cache, tok, pos, drafts, stop_pos, aid, ptab):
+    def verify_fn(params, cache, packed):
+        tok, pos, drafts, stop_pos, aid, ptab = _verify_columns(packed, k)
         active = (pos < stop_pos)[:, None]
         offs = jnp.arange(k)[None, :]
         seq = jnp.concatenate([tok[:, None], drafts], axis=1)
@@ -2732,16 +2761,18 @@ def _make_prefill(module: Any, n_slots: int, chunk: int) -> Callable:
     positions through the decode-cache path. The lm_head output is
     discarded (prefill emits nothing), so XLA dead-code-eliminates the
     (B, C, vocab) projection — the call is pure KV-cache population at
-    matmul (not matvec) arithmetic intensity. ``rows`` (a module with
-    per-slot state alone): each row's slot and its real tokens."""
+    matmul (not matvec) arithmetic intensity. Its host inputs arrive as
+    ONE packed int32 operand (:func:`_prefill_columns`); a module with
+    per-slot state is told each row's slot and its real tokens."""
     multi = int(getattr(module, "n_adapters", 0) or 0) > 0
     paged = int(getattr(module, "kv_page_size", 0) or 0) > 0
-
+    slot_state = bool(getattr(module, "slot_state", ()))
     mutable = _mutable_collections(module)
 
     @functools.partial(jax.jit, donate_argnums=(1,))
-    def prefill_fn(params, cache, tok_chunk, pos_chunk, aid, ptab,
-                   *counts, rows=None):
+    def prefill_fn(params, cache, packed, *counts):
+        tok_chunk, pos_chunk, aid, rows, ptab = _prefill_columns(
+            packed, chunk, slot_state)
         _, muts = module.apply(
             {"params": params, "cache": cache}, tok_chunk,
             positions=pos_chunk, decode=True, mutable=mutable,

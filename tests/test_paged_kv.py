@@ -598,14 +598,14 @@ def test_multi_token_gather_window_rides_live_width_slice(trained):
     eng = DecodeEngine(module, trained._params, max_slots=4, max_len=L,
                        prefill_chunk=8)
     widths = []
-    orig = eng._ptab_arg
+    orig = eng._live_table_width
 
     def spy():
         out = orig()
-        widths.append(int(out.shape[1]))
+        widths.append(out)
         return out
 
-    eng._ptab_arg = spy
+    eng._live_table_width = spy
     eng.submit("lp", np.arange(1, 11, dtype=np.int32), 4)  # 14 positions
     while eng.busy:
         eng.step()

@@ -337,10 +337,19 @@ def test_h2d_copies_are_the_uploads_each_turn_made(trained, kind,
     eng, requests = _engine(trained, kind)
     counting = _CountingJnp(de.jnp)
     monkeypatch.setattr(de, "jnp", counting)
+    # each launch's one operand, beside the host table at its live width
+    launched = []
+    step_fns = dict(eng._step_fns)
+
+    def launch(sampling):
+        def call(params, cache, packed, *rest):
+            launched.append((np.asarray(packed), eng._table().copy()))
+            return step_fns[sampling](params, cache, packed, *rest)
+        return call
+
+    eng._step_fns = {s: launch(s) for s in step_fns}
     for rid, prompt, max_new in requests:
         eng.submit(rid, np.asarray(prompt, np.int32), max_new)
-    per_call = 6 if kind == "hybrid" else 4
-    table_copies = 0
     while eng.busy:
         c0, h0 = counting.copies, eng.stats["h2d_copies"]
         since = time.time_ns()
@@ -349,18 +358,22 @@ def test_h2d_copies_are_the_uploads_each_turn_made(trained, kind,
         turn = [r for r in SPANS.snapshot(since) if r[0] == "engine.turn"]
         made, booked = counting.copies - c0, eng.stats["h2d_copies"] - h0
         assert booked == made
-        # the formula the benchmark's h2d_copies_per_turn is read by: 9 a
-        # decode launch, the prompts on a turn that admitted, 4 a gathered
-        # prefill call (6 with per-slot state), and the page table where
-        # it changed, at most once a launch
+        # the formula the benchmark's h2d_copies_per_turn is read by: one
+        # packed array a decode launch and one a prefill call, per-slot
+        # state or not, and the prompts on a turn that admitted
         attrs = turn[-1][6]
         launches = int(attrs["path"] == "scan")
-        rest = made - 9 * launches - per_call * attrs["prefill_calls"] \
-            - int(bool(attrs["admitted"]) and launches)
-        assert 0 <= rest <= launches, (attrs, made)
-        table_copies += rest
+        assert made == launches + attrs["prefill_calls"] + int(
+            bool(attrs["admitted"]) and launches), (attrs, made)
     assert eng.stats["h2d_copies"] == counting.copies
-    assert table_copies >= 1  # the table grew with the prompts
+    # the table rides inside the launch's one array: its columns past the
+    # per-slot ones are the host table at the live width, grown with the
+    # prompts
+    assert launched
+    for packed, table in launched:
+        assert packed.shape == (eng.B, de.N_SLOT_COLUMNS + table.shape[1])
+        np.testing.assert_array_equal(packed[:, de.N_SLOT_COLUMNS:], table)
+    assert any(table.any() for _, table in launched)
 
 
 def test_the_ring_holds_a_window_its_trace_and_a_drain(trained):

@@ -170,9 +170,8 @@ def test_f32_compute_takes_the_callers_very_leaves():
 def test_lowered_step_takes_no_f32_kernel():
     module, tree, _ = _variant("plain")
     eng = DecodeEngine(module, tree, max_slots=3, max_len=32)
-    operands = (eng._cache, eng._tok, eng._pos, eng._prompt_buf,
-                eng._prompt_len, eng._stop_pos, eng._temp, eng._topk,
-                eng._topp, eng._seed, eng._aid, eng._ptab)
+    # the per-slot inputs as the launch packs them, then the prompts
+    operands = (eng._cache, eng._slot_host, eng._prompt_buf)
     step_fn = eng._step_fns[False]
     jaxpr = jax.make_jaxpr(step_fn)(eng.params, *operands)
     f32_in = {tuple(a.shape) for a in jaxpr.in_avals

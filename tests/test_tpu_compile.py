@@ -427,3 +427,63 @@ def test_grouped_experts_compile_at_2304_by_896(spec):
             spec((16, 896, 2304), jnp.bfloat16))
         assert text.count("moe_grouped_matmul") >= 2
         assert "ragged-dot" not in text
+
+
+#: the four serving configurations' model kinds, at the tiny widths of
+#: their rehearsal configs: (config file, the benchmark driver that
+#: builds the module)
+SERVING_KINDS = {"dense": ("tiny-lm.json", "serve_engine"),
+                 "expert": ("tiny-latent-moe.json", "serve_latent_moe"),
+                 "state_space": ("tiny-hybrid-ssm.json", "serve_hybrid_ssm"),
+                 "window": ("tiny-window-moe.json", "serve_window_moe")}
+
+
+@pytest.mark.parametrize("kind", list(SERVING_KINDS))
+def test_engine_programs_compile_on_one_packed_operand(spec, kind,
+                                                        monkeypatch):
+    """The decode step (greedy and sampling) and the prefill program of
+    each serving model, handed their host inputs as ONE packed int32
+    operand at the whole table's width: the programs' static slices
+    lower for the chip beside its kernels, with no transfer to or from
+    the host inside the program. The module is traced as on the chip
+    (``default_backend`` says tpu), so it takes the Mosaic kernels."""
+    import importlib
+
+    from benchmark import harness
+    from rafiki_tpu.serving import decode_engine as de
+
+    fname, driver = SERVING_KINDS[kind]
+    cfg = harness.load_json("configs", fname)
+    module = importlib.import_module(
+        f"benchmark.drivers.{driver}").build_module(cfg)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    eng = cfg["engine"]
+    b, k, chunk = eng["max_slots"], eng["steps_per_sync"], eng["prefill_chunk"]
+    rows = min(b, de.PREFILL_LANES)
+    width = module.max_len // module.kv_page_size
+
+    def specs(tree):
+        return jax.tree_util.tree_map(lambda a: spec(a.shape, a.dtype), tree)
+
+    f32 = jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((1, 8), jnp.int32))["params"])
+    params = specs(jax.eval_shape(lambda p: de._serving_tree(module, p),
+                                  f32))
+    cache = specs(jax.eval_shape(lambda: module.init(
+        jax.random.PRNGKey(0), jnp.zeros((b, 1), jnp.int32),
+        decode=True)["cache"]))
+    n_counts = len(getattr(module, "device_counters", ()))
+    counts = (spec((n_counts,), jnp.int32),) if n_counts else ()
+    per_slot = 2 if getattr(module, "slot_state", ()) else 0
+    texts = [de._make_step(module, b, k, sampling).lower(
+        params, cache, spec((b, de.N_SLOT_COLUMNS + width), jnp.int32),
+        spec((b, module.max_len), jnp.int32), *counts).compile().as_text()
+        for sampling in (False, True)]
+    texts.append(de._make_prefill(module, rows, chunk).lower(
+        params, cache,
+        spec((rows, 2 * chunk + 1 + per_slot + width), jnp.int32),
+        *counts).compile().as_text())
+    for text in texts:
+        assert "tpu_custom_call" in text
+        for host in ("send", "recv", "infeed", "outfeed", "callback"):
+            assert host not in text, host
