@@ -84,6 +84,8 @@ from rafiki_tpu.ops.moe import (MOE_COUNTERS, ExpertShare,
                                 book_moe_counters, sown_counters)
 from rafiki_tpu.ops.paged_attention import (kv_cache_write,
                                             paged_decode_attention,
+                                            paged_decode_grid_steps,
+                                            paged_decode_live_blocks,
                                             paged_window_attention,
                                             paged_window_grid_steps,
                                             resolve_paged_kernel,
@@ -116,10 +118,15 @@ def book_ssm_counters(stats: Any, counts: Any) -> None:
 #: ring the masked form is handed), and keys live under every (real row,
 #: ``R`` layer). Over prefill calls: the grid steps of the layers'
 #: query-window kernel calls (0 where the gather or the masked form
-#: serves the window), and the call's rows x chunk tokens, once a call
+#: serves the window), and the call's rows x chunk tokens, once a call.
+#: Over single-token calls again: the grid steps of the ``W`` and ``R``
+#: layers' step-kernel calls, and the (real row, layer, block) triples
+#: of those calls holding a key the row sees (both 0 where the masked
+#: form serves)
 WINDOW_COUNTERS = ("win_step_live_keys", "win_step_keys_fetched",
                    "full_step_live_keys", "attn_prefill_grid_steps",
-                   "attn_prefill_tokens")
+                   "attn_prefill_tokens", "attn_step_grid_steps",
+                   "attn_step_live_blocks")
 
 
 def book_window_counters(stats: Any, counts: Any) -> None:
@@ -130,6 +137,8 @@ def book_window_counters(stats: Any, counts: Any) -> None:
     stats.inc("full_step_live_keys", int(counts[2]))
     stats.inc("attn_prefill_grid_steps", int(counts[3]))
     stats.inc("attn_prefill_tokens", int(counts[4]))
+    stats.inc("attn_step_grid_steps", int(counts[5]))
+    stats.inc("attn_step_live_blocks", int(counts[6]))
 
 
 def _sow_window_counters(module: nn.Module, counts: Any) -> None:
@@ -139,6 +148,19 @@ def _sow_window_counters(module: nn.Module, counts: Any) -> None:
                jnp.stack([jnp.asarray(c, jnp.int32) for c in counts]),
                init_fn=lambda: jnp.zeros((len(WINDOW_COUNTERS),), jnp.int32),
                reduce_fn=lambda u, w: u + w)
+
+
+def _step_walk(q, t, real, n_kv: int, page_size: int, n_tables: int,
+               window: Optional[int] = None):
+    """``attn_step_grid_steps`` and ``attn_step_live_blocks`` of one
+    step-kernel call on queries ``q`` (b, 1, heads, dh) at positions
+    ``t`` (b, 1): its grid's size, and the blocks holding a key a
+    ``real`` row sees."""
+    blocks = paged_decode_live_blocks(q[:, 0], n_kv, page_size, n_tables,
+                                      t[:, 0], window)
+    return [paged_decode_grid_steps(q[:, 0], n_kv, page_size, n_tables,
+                                    window),
+            jnp.sum(jnp.where(real, blocks, 0))]
 
 
 class Rows(NamedTuple):
@@ -386,6 +408,10 @@ class PatternAttention(nn.Module):
                 counts[0] = jnp.sum(jnp.where(
                     has, jnp.minimum(t[:, 0] + 1, self.window), 0))
                 counts[1] = jnp.sum(jnp.where(has, fetched, 0))
+                if kernel:
+                    counts[5:7] = _step_walk(
+                        q, t, has, nkv, self.kv_page_size,
+                        -(-self.max_len // self.kv_page_size), self.window)
             elif kernel:
                 counts[3] = paged_window_grid_steps(
                     q, nkv, self.kv_page_size,
@@ -410,6 +436,10 @@ class PatternAttention(nn.Module):
                 o = paged_decode_attention(
                     q[:, 0], ck.value, cv.value, page_tables, t[:, 0],
                     sm_scale=sm)[:, None]
+                if self.rope is not None:
+                    counts[5:7] = _step_walk(
+                        q, t, rows.n_real > 0, nkv, self.kv_page_size,
+                        page_tables.shape[1])
             elif resolve_paged_window_kernel(self.paged_kernel):
                 o = paged_window_attention(
                     q, ck.value, cv.value, page_tables, t, sm_scale=sm)
@@ -645,8 +675,9 @@ class HybridSSMMoEDecoder(nn.Module):
                 jnp.full((b,), s) if row_tokens is None else row_tokens,
                 positions)
         if decode and s > 1 and self._rotary:  # a prefill call's tokens
-            _sow_window_counters(
-                self, [0] * (len(WINDOW_COUNTERS) - 1) + [b * s])
+            counts = [0] * len(WINDOW_COUNTERS)
+            counts[4] = b * s
+            _sow_window_counters(self, counts)
         x = nn.Embed(self.vocab_size, self.hidden_dim,
                      name="tok_embed")(ids)
         if self.dtype is not None:

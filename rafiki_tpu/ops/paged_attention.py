@@ -6,44 +6,55 @@ a slot's pages back into logical ``(b, max_len, heads, dh)`` order
 before the masked softmax, re-materializing the whole logical KV per
 generated token. This kernel consumes the pool **directly**:
 
-- **Grid over (batch, kv-head tile, blocks of pages).** A grid step
-  consumes a BLOCK of a slot's pool pages — ``BLOCK_KEYS`` key
-  positions, fewer where many wide heads would not fit VMEM
-  (``BLOCK_BYTES``), or the whole table where it is narrower — so a
-  step moves hundreds of KB and the kernel's time follows the live KV
-  bytes, not a count of pages. Pages of a slot are not contiguous in
-  the pool, so a block is fetched page by page off the
-  scalar-prefetched block table; the page gather never materializes
-  in HBM.
+- **Grid over (slot tile, kv-head tile, blocks of pages).** A grid
+  step consumes a BLOCK of pool pages — ``BLOCK_KEYS`` key positions,
+  fewer where many wide heads would not fit VMEM (``BLOCK_BYTES``), or
+  the whole table where it is narrower — for each slot of a TILE of
+  slots (``_slots_per_tile``: up to ``TILE_SLOTS`` whose blocks fit
+  ``SLOT_TILE_BYTES``, read off the call's shapes), so a step moves
+  megabytes and the per-step costs are paid once a tile. Pages of a
+  slot are not contiguous in the pool, so a block is fetched page by
+  page off the scalar-prefetched block table; the page gather never
+  materializes in HBM.
 - **The kernel copies its own pages** where heads fill the 128 lanes
   (``_copies_own_pages``): the pools stay in HBM and a step starts one
-  DMA a LIVE page for the NEXT live block while it computes this one
-  (two VMEM buffers, toggled across grid steps). Narrower heads, and
-  an int8 pool's scale rows, leave the fetch to the BlockSpec
-  pipeline, the pool handed to the call once a page of the block with
-  an index map of its own (``tabs[b, block * pages + j]``) — legal at
+  DMA a LIVE page of each slot of the NEXT tile-step where any of them
+  has a key left, while it computes this one (two VMEM buffers,
+  toggled across grid steps). A float pool is handed to the kernel as
+  ``(pages, page_size * n_kv, dh)`` — a free view, the (key, head)
+  rows of a page in the pool's own order — so a page lands in VMEM as
+  the products read it (``_copies_page_rows``). An int8 pool and a
+  head tile narrower than the kv axis copy ``(page_size, block_h,
+  dh)`` blocks, one slot a step. Narrower heads, and an int8 pool's
+  scale rows, leave the fetch to the BlockSpec pipeline, one slot a
+  step, the pool handed to the call once a page of the block with an
+  index map of its own (``tabs[b, block * pages + j]``) — legal at
   every shape, at a bookkeeping cost for every page of the table.
-- **Every kv head in one product.** A page's ``(page_size, heads, dh)``
-  block is read as the 2-D matrix ``(page_size * heads, dh)`` — rows
-  are key x head in the pool's own order, no per-head slice — and all
-  of a tile's query rows meet a block's rows in ONE ``QK^T`` and ONE
-  ``PV`` product. The columns of other kv heads are masked with the
-  dead keys, so their probabilities are exactly 0 and heads never mix;
-  the MXU, idle in a bandwidth-bound kernel, pays the extra flops.
+- **Every kv head in one product, every slot of the tile in one
+  batch.** A block's pages are one 2-D matrix ``(pages * page_size *
+  heads, dh)`` — rows are key x head in the pool's own order, no
+  per-head slice — and all of a slot's query rows meet its block's
+  rows in ONE ``QK^T`` and ONE ``PV`` product, batched over the tile's
+  slots. The columns of other kv heads are masked with the dead keys,
+  so their probabilities are exactly 0 and heads never mix; the MXU,
+  idle in a bandwidth-bound kernel, pays the extra flops.
 - **LSE-merged partial softmax.** Per block the program computes a
   partial (max, sum, weighted-V accumulator) and folds it into running
-  f32 state in VMEM scratch — the same online-softmax recurrence
-  ``_attn_fwd_kernel`` streams key blocks with, here streamed across
-  grid steps (TPU grids execute sequentially per core; the block axis
-  is minor, so a (batch, head-tile) row sees its blocks back to back
-  and the last block's step writes the normalized output).
+  f32 state in VMEM scratch, a slot's own rows — the same
+  online-softmax recurrence ``_attn_fwd_kernel`` streams key blocks
+  with, here streamed across grid steps (TPU grids execute
+  sequentially per core; the block axis is minor, so a (slot tile,
+  head-tile) row sees its blocks back to back and the last block's
+  step writes the normalized output).
 - **Live pages only.** A slot at position ``t`` owns ``t // page_size
-  + 1`` live pages. Blocks past them skip their compute via
-  ``pl.when`` and fetch nothing; in a partly live block the kernel's
-  own copies skip the dead pages, and the pipeline's index maps
-  collapse them onto pool page 0 (the engine's scratch page — dead
-  table entries already point there), whose repeated fetch is elided.
-  Per-step HBM traffic scales with LIVE tokens, not ``max_len``.
+  + 1`` live pages. A block where no slot of the tile has a key skips
+  its compute via ``pl.when`` and fetches nothing; a slot with no key
+  left in a live block copies nothing and its masked product leaves
+  its state as it was; in a partly live block the kernel's own copies
+  skip the dead pages, and the pipeline's index maps collapse them
+  onto pool page 0 (the engine's scratch page — dead table entries
+  already point there), whose repeated fetch is elided. Per-step HBM
+  traffic scales with LIVE tokens, not ``max_len``.
 - **Fused int8-KV dequant.** Quantized pools pass their f32 absmax
   scale rows (same pool geometry, same table walk); the kernel
   dequantizes each page in registers before the product — the scale
@@ -289,6 +300,19 @@ def _copies_own_pages(dh: int) -> bool:
     return dh % 128 == 0
 
 
+def _copies_page_rows(dh: int, n_kv: int, block_h: int, pool_dtype) -> bool:
+    """Whether the step kernel copies a page as its ``(page_size *
+    n_kv, dh)`` (key, head) rows — a free view of the pool whose rows
+    lie in VMEM as they lie in HBM, ready for the products — and takes
+    a TILE of slots a grid step (``_slots_per_tile``): where it copies
+    its own pages of a float pool over the whole kv axis. A narrower
+    head tile, and an int8 pool (its pages dequantised head by head by
+    scale rows that the BlockSpec pipeline fetches a slot at a time),
+    copy (page_size, block_h, dh) blocks, one slot a grid step."""
+    return (_copies_own_pages(dh) and block_h == n_kv
+            and jnp.dtype(pool_dtype).itemsize > 1)
+
+
 def _window_start(t, window: int):
     """The first key position a query at ``t`` sees through a window of
     ``window`` keys: ``t - window + 1``, held to 0."""
@@ -313,7 +337,7 @@ def _tile_scales(s_ref, h0, block_h: int):
 
 def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
                          sm_scale: float, page_size: int, block_h: int,
-                         rep: int, pages: int, n_blocks: int,
+                         rep: int, pages: int, n_blocks: int, tile: int,
                          quantized: bool, own_copies: bool,
                          window: Optional[int] = None):
     from jax.experimental import pallas as pl
@@ -333,112 +357,143 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
     if count_fetched:  # a second result: pages fetched, slot by slot
         cnt_ref, *rest = rest
     m_scr, l_scr, acc_scr, *copy_scr = rest
-    bi, kh, blk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
+    # own copies of a float pool's page rows (``_copies_page_rows``):
+    # the pools arrive as (pages, page_size * n_kv, dh)
+    page_rows = own_copies and len(k_refs[0].shape) == 3
+    gi, kh, blk = pl.program_id(0), pl.program_id(1), pl.program_id(2)
     h0 = _tile_first_head(block_h, ks_refs[0])
-    t = t_ref[bi]  # this slot's query position (keys k_pos <= t live)
     span = pages * page_size  # key positions a block covers
-    # with a ``window`` the grid's block axis starts at the block that
-    # holds the window's first key (``_window_start``) and is only as
-    # long as a window can span: ``lblk`` is the block of the TABLE
-    lblk = blk if window is None else \
-        _window_start(t, window) // span + blk
+
+    def walked(b_, blk_):
+        """The block of the TABLE that step ``blk_`` of slot ``b_``'s
+        walk covers: with a ``window`` the walk starts at the block that
+        holds the window's first key (``_window_start``) and is only as
+        long as a window can span."""
+        if window is None:
+            return blk_
+        return _window_start(t_ref[b_], window) // span + blk_
 
     def live_pages(b_, blk_):
-        """Of block ``blk_`` of slot ``b_``'s walk: its first page in
-        the TABLE, and the pages ``[below, n_live)`` of it that hold a
-        key the slot's query sees."""
-        if window is None:
-            first, below = blk_ * pages, 0
-        else:  # the pages below the window's first are not fetched
-            lo = _window_start(t_ref[b_], window)
-            first = (lo // span + blk_) * pages
-            below = jnp.clip(lo // page_size - first, 0, pages)
+        """Of step ``blk_`` of slot ``b_``'s walk: its first page in the
+        TABLE, and the pages ``[below, n_live)`` of it that hold a key
+        the slot's query sees — none where the slot has no key left."""
+        first = walked(b_, blk_) * pages
+        below = 0
+        if window is not None:  # the pages below the window's first
+            below = jnp.clip(_window_start(t_ref[b_], window) // page_size
+                             - first, 0, pages)
         n_live = jnp.clip(t_ref[b_] // page_size + 1 - first, 0, pages)
         return first, below, n_live
 
+    # the tile's slots, each at its own position and its own table block
+    slots = [gi * tile + g for g in range(tile)]
+    t = [t_ref[b_] for b_ in slots]  # keys k_pos <= t live
+    lblk = [walked(b_, blk) for b_ in slots]
+    any_live = functools.reduce(jnp.logical_or, [
+        lb * span <= tg for lb, tg in zip(lblk, t)])
+
     if count_fetched:
-        @pl.when((bi == 0) & (kh == 0) & (blk == 0))
+        @pl.when((gi == 0) & (kh == 0) & (blk == 0))
         def _zero():
             def zero(i, carry):
                 cnt_ref[i] = 0
                 return carry
 
-            jax.lax.fori_loop(0, pl.num_programs(0), zero, 0)
+            jax.lax.fori_loop(0, cnt_ref.shape[0], zero, 0)
 
     if own_copies:
         k_buf, v_buf, sems, slot_scr = copy_scr
-        n_slots, n_tiles = pl.num_programs(0), pl.num_programs(1)
+        n_groups, n_tiles = pl.num_programs(0), pl.num_programs(1)
 
-        def block_copies(b_, kh_, blk_, slot, act: str):
-            """``start`` (or ``wait`` for) the copies of a block's LIVE
-            pages into buffer ``slot``: K and V of every page up to the
-            slot's last live one, each a (page_size, block_h, dh) DMA
-            straight out of the pool by the block table."""
-            first, below, n_live = live_pages(b_, blk_)
-            if count_fetched and act == "start":  # counted where copied
-                cnt_ref[b_] += jnp.maximum(n_live - below, 0)
+        def block_copies(gi_, kh_, blk_, slot, act: str):
+            """``start`` (or ``wait`` for) the copies of a tile-step's
+            LIVE pages into buffer ``slot``: for every slot of the tile,
+            K and V of each page of its walk's step up to its last live
+            one, ONE DMA a page straight out of the pool by the slot's
+            own table row — none for a slot that has no key left there.
+            A page of a float pool is its (page_size * n_kv, dh) rows,
+            which the buffer holds as they lie; an int8 pool's is the
+            (page_size, block_h, dh) block of the head tile."""
+            def slot_pages(g, carry):
+                b_ = gi_ * tile + g
+                first, below, n_live = live_pages(b_, blk_)
+                if count_fetched and act == "start":  # counted where copied
+                    cnt_ref[b_] += jnp.maximum(n_live - below, 0)
 
-            def page(j, carry):
-                entry = tab_ref[b_, first + j]
-                for pool, buf, sem in ((k_refs[0], k_buf, sems.at[slot, 0]),
-                                       (v_refs[0], v_buf, sems.at[slot, 1])):
-                    getattr(pltpu.make_async_copy(
-                        pool.at[entry, :, pl.ds(kh_ * block_h, block_h), :],
-                        buf.at[slot, j], sem), act)()
-                return carry
+                def page(j, carry):
+                    entry = tab_ref[b_, first + j]
+                    for pool, buf, sem in (
+                            (k_refs[0], k_buf, sems.at[slot, 0]),
+                            (v_refs[0], v_buf, sems.at[slot, 1])):
+                        src = pool.at[entry] if page_rows else pool.at[
+                            entry, :, pl.ds(kh_ * block_h, block_h), :]
+                        getattr(pltpu.make_async_copy(
+                            src, buf.at[slot, g, j], sem), act)()
+                    return carry
 
-            jax.lax.fori_loop(below, n_live, page, 0)
+                return jax.lax.fori_loop(below, n_live, page, carry)
 
-        @pl.when((bi == 0) & (kh == 0) & (blk == 0))
+            jax.lax.fori_loop(0, tile, slot_pages, 0)
+
+        @pl.when((gi == 0) & (kh == 0) & (blk == 0))
         def _prime():  # nothing fetched the call's first block yet.
-            # A partly live block's unfetched pages meet probabilities
-            # of exactly 0: what the buffers hold there must be finite
+            # A partly live block's unfetched pages, and a slot's whole
+            # block where it has no key left, meet probabilities of
+            # exactly 0: what the buffers hold there must be finite
             k_buf[...] = jnp.zeros_like(k_buf)
             v_buf[...] = jnp.zeros_like(v_buf)
             slot_scr[0] = 0
-            block_copies(bi, kh, blk, 0, "start")
+            block_copies(gi, kh, blk, 0, "start")
 
     @pl.when(blk == 0)
-    def _init():  # fresh (batch, head-tile) row: reset the running state
+    def _init():  # fresh (slot tile, head tile) row: reset the state
         m_scr[...] = jnp.full_like(m_scr, NEG_INF)
         l_scr[...] = jnp.zeros_like(l_scr)
         acc_scr[...] = jnp.zeros_like(acc_scr)
 
-    @pl.when(lblk * span <= t)
-    def _partial():  # dead blocks: no compute, and nothing fetched
+    @pl.when(any_live)
+    def _partial():  # dead tile-steps: no compute, and nothing fetched
         # (own copies skip them; the pipeline's index maps collapse
         # their pages onto the scratch page and elide the fetch)
         n_q, dh = q_ref.shape[-2:]
-        rows = page_size * block_h
+        rows = page_size * block_h  # a page's (key, head) rows
+        cols = pages * rows
         if own_copies:
             slot = slot_scr[0]
             # double buffering across grid steps: start the NEXT live
-            # block's copies (this row's next block, else block 0 of the
-            # next row, which is always live), then wait for this one's
-            more = (lblk + 1) * span <= t
+            # tile-step's copies (this row's next block where any slot
+            # of the tile has a key left, else block 0 of the next row,
+            # which is live for every slot), then wait for this one's
+            more = functools.reduce(jnp.logical_or, [
+                (lb + 1) * span <= tg for lb, tg in zip(lblk, t)])
             row_end = kh == n_tiles - 1
-            nxt = (jnp.where(more | ~row_end, bi, bi + 1),
+            nxt = (jnp.where(more | ~row_end, gi, gi + 1),
                    jnp.where(more, kh, jnp.where(row_end, 0, kh + 1)),
                    jnp.where(more, blk + 1, 0))
 
-            @pl.when(nxt[0] < n_slots)
+            @pl.when(nxt[0] < n_groups)
             def _prefetch():
                 block_copies(*nxt, 1 - slot, "start")
 
-            block_copies(bi, kh, blk, slot, "wait")
+            block_copies(gi, kh, blk, slot, "wait")
             slot_scr[0] = 1 - slot
-            k_pages = [k_buf.at[slot, j] for j in range(pages)]
-            v_pages = [v_buf.at[slot, j] for j in range(pages)]
+            if page_rows:  # the tile's blocks as they lie
+                k = k_buf[slot].reshape(tile, cols, dh)
+                v = v_buf[slot].reshape(tile, cols, dh)
+            else:
+                k_pages = [k_buf.at[slot, 0, j] for j in range(pages)]
+                v_pages = [v_buf.at[slot, 0, j] for j in range(pages)]
         else:
             k_pages = [ref.at[0] for ref in k_refs]
             v_pages = [ref.at[0] for ref in v_refs]
             if count_fetched:  # what the index maps did not collapse
-                _, below, n_live = live_pages(bi, blk)
-                cnt_ref[bi] += jnp.maximum(n_live - below, 0)
+                _, below, n_live = live_pages(slots[0], blk)
+                cnt_ref[slots[0]] += jnp.maximum(n_live - below, 0)
 
         def block(page_refs, scale_refs):
-            # the block's pages as ONE 2-D matrix, row = key x head in
-            # the pool's own order: (pages * page_size * block_h, dh)
+            """One slot's block of (page_size, block_h, dh) pages as
+            ONE (1, cols, dh) matrix: row = key x head in the pool's own
+            order."""
             mats = []
             for ref, s_ref in zip(page_refs, scale_refs):
                 page = ref[...]  # (page_size, block_h, dh)
@@ -446,39 +501,125 @@ def _paged_decode_kernel(t_ref, tab_ref, q_ref, *rest,
                     page = page.astype(jnp.float32) * _tile_scales(
                         s_ref, h0, block_h)[:, :, None]
                 mats.append(page.reshape(rows, dh))
-            return jnp.concatenate(mats, axis=0)
+            return jnp.concatenate(mats, axis=0)[None]
 
-        k, v = block(k_pages, ks_refs), block(v_pages, vs_refs)
-        # every query row against every (key, head) row in ONE product;
-        # the columns of other kv heads are masked with the dead keys,
-        # so their probabilities are exactly 0 and heads never mix
-        s = jax.lax.dot_general(
-            q_ref[0, 0].astype(k.dtype), k, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale  # (n_q, cols)
-        col = jax.lax.broadcasted_iota(jnp.int32, (1, pages * rows), 1)
-        row = jax.lax.broadcasted_iota(jnp.int32, (n_q, 1), 0)
+        if not page_rows:
+            k, v = block(k_pages, ks_refs), block(v_pages, vs_refs)
+        # each slot's query rows against every (key, head) row of ITS
+        # block, in one product batched over the tile's slots; the
+        # columns of other kv heads are masked with the dead keys, so
+        # their probabilities are exactly 0 and heads never mix
+        s = jax.lax.dot_general(  # (tile, n_q, cols)
+            q_ref[:, 0].astype(k.dtype), k, (((2,), (2,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32) * sm_scale
+        # a slot's position and table block, as (tile, 1, 1) vectors
+        slot_of = jax.lax.broadcasted_iota(jnp.int32, (tile, 1, 1), 0)
+        t_v = sum(jnp.where(slot_of == g, t[g], 0) for g in range(tile))
+        lblk_v = sum(jnp.where(slot_of == g, lblk[g], 0) for g in range(tile))
+        col = jax.lax.broadcasted_iota(jnp.int32, (1, 1, cols), 2)
+        row = jax.lax.broadcasted_iota(jnp.int32, (1, n_q, 1), 1)
         # masks the dead pages of a partly live block, the last live
-        # page's tail AND any speculative-overwrite rows above t
-        live = (lblk * span + col // block_h <= t) & (
-            col % block_h == row // rep)
+        # page's tail, a slot's whole block where it has no key left,
+        # AND any speculative-overwrite rows above t
+        k_pos = lblk_v * span + col // block_h
+        seen = (k_pos <= t_v) & (col % block_h == row // rep)
         if window is not None:  # and the keys behind the window
-            live &= lblk * span + col // block_h > t - window
-        s = jnp.where(live, s, NEG_INF)
+            seen &= k_pos > t_v - window
+        s = jnp.where(seen, s, NEG_INF)
 
-        m_prev = m_scr[...]  # (n_q, 1) running max
+        # a slot with no key left here changes nothing: its running max
+        # is finite since block 0, so alpha is 1 and p is 0
+        m_prev = m_scr[...]  # (tile, n_q, 1) running max
         m_new = jnp.maximum(m_prev, jnp.max(s, -1, keepdims=True))
         alpha = jnp.exp(m_prev - m_new)
         p = jnp.exp(s - m_new)
         l_scr[...] = l_scr[...] * alpha + jnp.sum(p, -1, keepdims=True)
         acc_scr[...] = acc_scr[...] * alpha + jax.lax.dot_general(
-            p.astype(v.dtype), v, (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)  # (n_q, dh)
+            p.astype(v.dtype), v, (((2,), (1,)), ((0,), (0,))),
+            preferred_element_type=jnp.float32)  # (tile, n_q, dh)
         m_scr[...] = m_new
 
     @pl.when(blk == n_blocks - 1)
     def _finish():  # position 0 is always live, so l > 0 on every row
-        o_ref[0, 0] = (acc_scr[...] /
+        o_ref[:, 0] = (acc_scr[...] /
                        jnp.maximum(l_scr[...], 1e-30)).astype(o_ref.dtype)
+
+
+#: most slots one grid step of the step kernel takes (a tile), their
+#: products batched over the tile: the largest measured on a TPU v5e,
+#: where it was the fastest or within 1% of it at every served call
+TILE_SLOTS = 8
+#: bytes a tile of slots may take in VMEM: each slot's K and V blocks,
+#: double-buffered, and its products' float32 scores and probabilities,
+#: inside the 16 MiB Mosaic gives a kernel
+SLOT_TILE_BYTES = 12 << 20
+
+
+def _slots_per_tile(b: int, block_bytes: int, n_q: int, cols: int) -> int:
+    """Slots one grid step of the step kernel takes: the largest divisor
+    of ``b``, at most ``TILE_SLOTS``, whose blocks (``block_bytes`` of K
+    and of V a slot, double-buffered) and score tiles (``n_q`` x
+    ``cols`` float32, twice) fit ``SLOT_TILE_BYTES`` — read off the
+    call's shapes."""
+    per_slot = 2 * 2 * block_bytes + 2 * n_q * cols * 4
+    for d in range(min(b, TILE_SLOTS), 0, -1):
+        if b % d == 0 and d * per_slot <= SLOT_TILE_BYTES:
+            return d
+    return 1
+
+
+def _decode_grid(q, n_kv: int, page_size: int, n_tables: int, pool_dtype,
+                 window: Optional[int], block_h: Optional[int]):
+    """``(block_h, pages, tile, grid)`` of a step-kernel call: the block
+    of pages a grid step takes (``_pages_per_block``), the slots it takes
+    them for (``_slots_per_tile``, where the kernel copies its own pages
+    out of a float pool; else 1 — an int8 pool's scale rows ride the
+    BlockSpec pipeline a slot at a time), and the grid (slot tiles,
+    kv-head tiles, blocks walked): the table's blocks, or with
+    ``window`` as many as a window can span."""
+    b, n_heads, dh = q.shape
+    rep = gqa_repeat_factor(n_heads, n_kv)
+    block_h = _resolve_block_h(block_h, n_kv)
+    itemsize = jnp.dtype(pool_dtype).itemsize
+    # a page of one pool in VMEM; an int8 page is priced at the bf16
+    # it would be: its f32 dequantised copy is the larger tenant
+    page_bytes = page_size * block_h * dh * max(itemsize, 2)
+    pages = _pages_per_block(page_size, n_tables, page_bytes)
+    n_blocks = -(-n_tables // pages)
+    if window is not None:
+        if window < 1:
+            raise ValueError(f"window={window} must be >= 1")
+        n_blocks = _window_blocks(window, pages * page_size, n_blocks)
+    tile = 1
+    if _copies_page_rows(dh, n_kv, block_h, pool_dtype):
+        tile = _slots_per_tile(b, pages * page_bytes, block_h * rep,
+                               pages * page_size * block_h)
+    return block_h, pages, tile, (b // tile, n_kv // block_h, n_blocks)
+
+
+def paged_decode_grid_steps(q, n_kv: int, page_size: int, n_tables: int,
+                            window: Optional[int] = None) -> int:
+    """Grid steps ``paged_decode_attention`` takes for queries ``q``
+    (b, n_heads, dh) over a pool of ``q``'s dtype, ``n_kv`` heads in
+    pages of ``page_size``, and a table ``n_tables`` wide: a Python int
+    at trace time, what a caller counts its step work by."""
+    return math.prod(_decode_grid(q, n_kv, page_size, n_tables, q.dtype,
+                                  window, None)[3])
+
+
+def paged_decode_live_blocks(q, n_kv: int, page_size: int, n_tables: int,
+                             positions, window: Optional[int] = None):
+    """(b,) int32: the blocks of its walk in which slot i of that call
+    (as :func:`paged_decode_grid_steps` has it) has a key it sees —
+    ``positions[i] // span + 1``, or with ``window`` from the block of
+    the window's first key."""
+    pages = _decode_grid(q, n_kv, page_size, n_tables, q.dtype, window,
+                         None)[1]
+    span = pages * page_size
+    t = jnp.clip(jnp.asarray(positions, jnp.int32), 0,
+                 n_tables * page_size - 1)
+    lo = 0 if window is None else _window_start(t, window)
+    return t // span - lo // span + 1
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
@@ -488,9 +629,10 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
                            interpret: Optional[bool] = None,
                            window: Optional[int] = None):
     """Single-token decode attention straight off a paged KV pool: a
-    grid step takes a BLOCK of a slot's pool pages (``BLOCK_KEYS`` key
-    positions; a narrower table is one block) over every kv head of its
-    tile, in one masked ``QK^T`` and one ``PV`` product.
+    grid step takes a TILE of slots (``_slots_per_tile``) and a BLOCK of
+    each one's pool pages (``BLOCK_KEYS`` key positions; a narrower table
+    is one block) over every kv head of its tile, in one masked ``QK^T``
+    and one ``PV`` product batched over the tile's slots.
 
     - ``q``: (b, n_heads, dh) — this step's query vector per slot.
     - ``k_pool``/``v_pool``: (n_pages, page_size, n_kv_heads, dh), the
@@ -520,43 +662,57 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
       and not the scratch page — so that a caller can tell a kernel
       that reads the window from one that only masks to it.
 
-    The block (``_pages_per_block``) and who fetches its pages
-    (``_copies_own_pages``) follow from the shapes of the call. Products
-    run on the pool's own float type (int8 pools: dequantised to f32)
-    with f32 accumulation and f32 softmax state.
+    The block, the tile of slots and who fetches the pages follow from
+    the shapes of the call (``_decode_grid``). Products run on the
+    pool's own float type (int8 pools: dequantised to f32) with f32
+    accumulation and f32 softmax state, a slot's arithmetic the same
+    whatever its tile.
 
     Returns (b, n_heads, dh) in ``q``'s dtype. GQA queries are grouped
     per kv head internally (``jnp.repeat`` convention: q head h ↔ kv
     head ``h // rep``). ``block_h`` tiles kv heads per program
     (default: the whole kv axis — see ``_resolve_block_h``).
     """
+    dh = q.shape[-1]
+    if k_pool.shape[-1] != dh:
+        raise ValueError(f"head_dim mismatch: q has {dh}, pool "
+                         f"{k_pool.shape[-1]}")
+    if (k_scale is None) != (v_scale is None):
+        raise ValueError("k_scale and v_scale must be passed together")
+    block_h, _, tile, _ = _decode_grid(
+        q, k_pool.shape[2], k_pool.shape[1], page_tables.shape[1],
+        k_pool.dtype, window, block_h)
+    if k_scale is not None and tile > 1:
+        raise ValueError("k_scale and v_scale rows come with an int8 pool")
+    return _paged_decode_call(
+        q, k_pool, v_pool, page_tables, positions, k_scale, v_scale,
+        sm_scale=float(sm_scale), block_h=block_h, window=window,
+        interpret=_resolve_interpret(interpret))
+
+
+@functools.partial(jax.jit, static_argnames=("sm_scale", "block_h",
+                                             "window", "interpret"))
+def _paged_decode_call(q, k_pool, v_pool, page_tables, positions, k_scale,
+                       v_scale, *, sm_scale: float, block_h: int,
+                       window: Optional[int], interpret: bool):
+    """:func:`paged_decode_attention` once its arguments are checked. A
+    jit of its own: a decoder's layers make the same call, and the
+    kernel is traced and lowered once a program, not once a layer: each
+    lowering costs ~0.1-0.2 s of host time, paid before the compile
+    cache is asked, so a 28-layer step program paid it 28 times."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
     b, n_heads, dh = q.shape
-    n_pages, page_size, n_kv, dh_k = k_pool.shape
-    if dh_k != dh:
-        raise ValueError(f"head_dim mismatch: q has {dh}, pool {dh_k}")
+    n_pages, page_size, n_kv, _ = k_pool.shape
     rep = gqa_repeat_factor(n_heads, n_kv)
     n_tables = page_tables.shape[1]
-    block_h = _resolve_block_h(block_h, n_kv)
     quantized = k_scale is not None
-    if quantized != (v_scale is not None):
-        raise ValueError("k_scale and v_scale must be passed together")
-    interpret = _resolve_interpret(interpret)
-
+    _, pages, tile, grid = _decode_grid(
+        q, n_kv, page_size, n_tables, k_pool.dtype, window, block_h)
     n_tiles, n_q = n_kv // block_h, block_h * rep
-    # a page of one pool in VMEM; an int8 page is priced at the bf16
-    # it would be: its f32 dequantised copy is the larger tenant
-    pages = _pages_per_block(
-        page_size, n_tables,
-        page_size * block_h * dh * max(k_pool.dtype.itemsize, 2))
-    n_blocks = -(-n_tables // pages)
-    if window is not None:
-        if window < 1:
-            raise ValueError(f"window={window} must be >= 1")
-        n_blocks = _window_blocks(window, pages * page_size, n_blocks)
     own_copies = _copies_own_pages(dh)
+    page_rows = _copies_page_rows(dh, n_kv, block_h, k_pool.dtype)
     # GQA query rows grouped per kv head tile: q head h <-> kv head
     # h // rep, so a tile's rows are contiguous
     qh = q.reshape(b, n_tiles, n_q, dh)
@@ -567,20 +723,20 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
                  n_tables * page_size - 1)
     tabs = jnp.asarray(page_tables, jnp.int32)
 
-    def q_map(bi, kh, blk, t_ref, tab_ref):
-        return (bi, kh, 0, 0)
+    def q_map(gi, kh, blk, t_ref, tab_ref):
+        return (gi, kh, 0, 0)
 
     def page_specs(block_shape):
-        """The BlockSpec pipeline's fetch of a block: pages of a slot
-        are not contiguous in the pool, so the pool is handed to the
-        call ``pages`` times, every copy with an index map of its own
-        that walks the block table, and a block's pages stream side by
-        side. Live pages come from the table; dead ones (past the
-        slot's last live page, or past the table in a ragged last
-        block) collapse onto pool page 0, so consecutive dead steps
-        re-use one fetch instead of streaming garbage. K/V blocks
-        (4-D) take their head tile, scale blocks the WHOLE kv axis
-        (``_tile_first_head``)."""
+        """The BlockSpec pipeline's fetch of a block (a tile of one
+        slot): pages of a slot are not contiguous in the pool, so the
+        pool is handed to the call ``pages`` times, every copy with an
+        index map of its own that walks the block table, and a block's
+        pages stream side by side. Live pages come from the table; dead
+        ones (past the slot's last live page, or past the table in a
+        ragged last block) collapse onto pool page 0, so consecutive
+        dead steps re-use one fetch instead of streaming garbage. K/V
+        blocks (4-D) take their head tile, scale blocks the WHOLE kv
+        axis (``_tile_first_head``)."""
         def spec(j):
             def index(bi, kh, blk, t_ref, tab_ref):
                 pg = blk * pages + j
@@ -599,19 +755,26 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         return [spec(j) for j in range(pages)]
 
     scratch_shapes = [
-        pltpu.VMEM((n_q, 1), jnp.float32),   # running max
-        pltpu.VMEM((n_q, 1), jnp.float32),   # running sum
-        pltpu.VMEM((n_q, dh), jnp.float32),  # weighted V
+        pltpu.VMEM((tile, n_q, 1), jnp.float32),   # running max
+        pltpu.VMEM((tile, n_q, 1), jnp.float32),   # running sum
+        pltpu.VMEM((tile, n_q, dh), jnp.float32),  # weighted V
     ]
-    in_specs = [pl.BlockSpec((1, 1, n_q, dh), q_map)]
+    in_specs = [pl.BlockSpec((tile, 1, n_q, dh), q_map)]
     operands = [qh]
     if own_copies:
         in_specs += [pl.BlockSpec(memory_space=pl.ANY)] * 2
-        operands += [k_pool, v_pool]
+        page_shape = (page_size, block_h, dh)
+        if page_rows:  # a free view: the rows of a page lie in order
+            page_shape = (page_size * n_kv, dh)
+            operands += [k_pool.reshape((n_pages,) + page_shape),
+                         v_pool.reshape((n_pages,) + page_shape)]
+        else:
+            operands += [k_pool, v_pool]
         scratch_shapes += [
-            # a block of K and of V pages, double-buffered
-            pltpu.VMEM((2, pages, page_size, block_h, dh), k_pool.dtype),
-            pltpu.VMEM((2, pages, page_size, block_h, dh), v_pool.dtype),
+            # a block of K and of V pages a slot of the tile,
+            # double-buffered
+            pltpu.VMEM((2, tile, pages) + page_shape, k_pool.dtype),
+            pltpu.VMEM((2, tile, pages) + page_shape, v_pool.dtype),
             pltpu.SemaphoreType.DMA((2, 2)),  # (buffer, K or V)
             pltpu.SMEM((1,), jnp.int32),      # the buffer in use
         ]
@@ -622,23 +785,23 @@ def paged_decode_attention(q, k_pool, v_pool, page_tables, positions,
         in_specs += 2 * page_specs((1, page_size, n_kv))
         operands += [k_scale] * pages + [v_scale] * pages
 
-    out_specs = pl.BlockSpec((1, 1, n_q, dh), q_map)
+    out_specs = pl.BlockSpec((tile, 1, n_q, dh), q_map)
     out_shape = jax.ShapeDtypeStruct((b, n_tiles, n_q, dh), q.dtype)
     if window is not None:  # a counter a slot, in SMEM for the call
         out_specs = [out_specs, pl.BlockSpec(memory_space=pltpu.SMEM)]
         out_shape = [out_shape, jax.ShapeDtypeStruct((b,), jnp.int32)]
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
-        grid=(b, n_tiles, n_blocks),
+        grid=grid,
         in_specs=in_specs,
         out_specs=out_specs,
         scratch_shapes=scratch_shapes,
     )
     kernel = functools.partial(
-        _paged_decode_kernel, sm_scale=float(sm_scale),
+        _paged_decode_kernel, sm_scale=sm_scale,
         page_size=page_size, block_h=block_h, rep=rep, pages=pages,
-        n_blocks=n_blocks, quantized=quantized, own_copies=own_copies,
-        window=window)
+        n_blocks=grid[2], tile=tile, quantized=quantized,
+        own_copies=own_copies, window=window)
     call = pl.pallas_call(
         kernel,
         grid_spec=grid_spec,

@@ -13,6 +13,7 @@ plausible rather than lucky.
 """
 
 import functools
+import math
 
 import numpy as np
 import pytest
@@ -20,11 +21,14 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from rafiki_tpu.ops.paged_attention import (TILE_BYTES, _default_block_q,
+from rafiki_tpu.ops import paged_attention as pa
+from rafiki_tpu.ops.paged_attention import (TILE_BYTES, _decode_grid,
+                                            _default_block_q,
                                             _paged_attention_reference,
                                             _paged_window_reference,
                                             _tile_bytes,
                                             paged_decode_attention,
+                                            paged_decode_grid_steps,
                                             paged_window_attention,
                                             paged_window_grid_steps,
                                             resolve_paged_kernel,
@@ -245,6 +249,112 @@ def test_kernel_composes_with_jit():
     out = np.asarray(step(q, kp, vp, tabs, t), np.float32)
     _, ref = _both(q, kp, vp, tabs, t)
     np.testing.assert_allclose(out, ref, atol=2e-6, rtol=1e-5)
+
+
+#: the step kernel's tile of slots: heads of 128 fill the lanes, so the
+#: kernel copies a float pool's page rows itself and a grid step takes
+#: up to ``TILE_SLOTS`` slots; 32 pages of 16 are TWO blocks
+TILE = dict(n_kv=2, rep=2, dh=128, ps=16, n_tables=32, n_pages=257)
+
+
+@pytest.mark.parametrize("positions,window,tile,geom", [
+    # position 0 beside the table's last: slots of one tile with 1 and
+    # 2 live blocks, and two slots with no key left in block 1
+    ([0, 511, 17, 300], None, 4, TILE),
+    # blocks of 1 MB (8 kv heads): two tiles of 2, the first with no key
+    # past block 0, so its second tile-step is dead
+    ([5, 250, 256, 511], None, 2, TWO_BLOCKS),
+    ([300], None, 1, TILE),
+    # a window of 512 over 64 pages whose first key lies in blocks 0,
+    # 2, 0 and 1: whole pages at its edges, ~1.02 keys fetched a live key
+    ([600, 1023, 515, 777], 512, 4, dict(TILE, n_tables=64)),
+], ids=["g4_first_and_last", "g2_dead_tile_step", "g1", "g4_window"])
+def test_tiles_of_slots_match_reference(monkeypatch, positions, window,
+                                        tile, geom):
+    """A grid step takes a TILE of slots (from the call's shapes), each
+    at its own position, window and table row: against the page-gather
+    oracle, and equal to the same call one slot a grid step — a slot's
+    arithmetic is the same whatever its tile. Keys fetched: whole pages
+    from the window's first key to the query's own, slot by slot."""
+    q, kp, vp, tabs, t, _ = _setup(positions, **geom)
+    assert _decode_grid(q, geom["n_kv"], geom["ps"], geom["n_tables"],
+                        kp.dtype, window, None)[2] == tile
+    sm = 1.0 / np.sqrt(q.shape[-1])
+
+    def call():
+        out = paged_decode_attention(q, kp, vp, tabs, t, sm_scale=sm,
+                                     interpret=True, window=window)
+        return out if window is not None else (out, None)
+
+    out, fetched = call()
+    ref = _paged_attention_reference(
+        jnp.asarray(q), jnp.asarray(kp), jnp.asarray(vp),
+        jnp.asarray(tabs), t, sm, window=window)
+    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
+                               atol=1e-5, rtol=1e-4)
+    if window is None and tile == 4:
+        monkeypatch.setattr(pa, "_slots_per_tile", lambda *a: 1)
+        np.testing.assert_array_equal(np.asarray(out),
+                                      np.asarray(call()[0]))
+    if window is not None:
+        ps = geom["ps"]
+        lo = np.maximum(t - window + 1, 0)
+        want = (t // ps - lo // ps + 1) * ps
+        assert np.asarray(fetched).tolist() == want.tolist()
+        assert 1.0 <= want.sum() / np.minimum(t + 1, window).sum() <= 1.03
+
+
+def _pallas_grids(jaxpr):
+    """The grid of every ``pallas_call`` in ``jaxpr``, nested ones (the
+    interpreter's replicated region on a many-device backend) too."""
+    grids = []
+    for e in jaxpr.eqns:
+        if e.primitive.name == "pallas_call":
+            grids.append(e.params["grid_mapping"].grid)
+        for sub in jax.core.jaxprs_in_params(e.params):
+            grids += _pallas_grids(sub)
+    return grids
+
+
+#: (slots, q heads, kv heads, page, table, window, pool) of the step
+#: kernel's served calls, and the grid each is made with; an int8 pool
+#: keeps one slot a grid step
+STEP_GRIDS = {
+    "window_moe_sliding": ((32, 32, 4, 32, 224, 1024, jnp.bfloat16),
+                           (4, 1, 5)),
+    "window_moe_full": ((32, 32, 4, 32, 224, None, jnp.bfloat16),
+                        (4, 1, 28)),
+    "hybrid_ssm": ((64, 32, 2, 32, 128, None, jnp.bfloat16), (8, 1, 16)),
+    "dense": ((32, 32, 8, 16, 32, None, jnp.bfloat16), (8, 1, 2)),
+    "dense_narrow": ((32, 32, 8, 16, 1, None, jnp.bfloat16), (4, 1, 1)),
+    "dense_int8": ((32, 32, 8, 16, 32, None, jnp.int8), (32, 1, 2)),
+}
+
+
+@pytest.mark.parametrize("call", list(STEP_GRIDS))
+def test_step_grid_steps_of_the_served_calls(call):
+    """What a caller counts its step work by is the grid the wrapper
+    builds: slot tiles (8 slots whose 8-page blocks fit VMEM, 4 of the
+    dense cell's 16-page blocks) x kv-head tiles x blocks walked — read
+    here off the traced call itself."""
+    (b, nh, nkv, page, n_tables, window, dtype), want = STEP_GRIDS[call]
+    q = jax.ShapeDtypeStruct((b, nh, 128), jnp.bfloat16)
+    pool = jax.ShapeDtypeStruct((8, page, nkv, 128), dtype)
+    scales = {}
+    if dtype == jnp.int8:
+        scales = dict.fromkeys(("k_scale", "v_scale"), jax.ShapeDtypeStruct(
+            (8, page, nkv), jnp.float32))
+    jaxpr = jax.make_jaxpr(lambda q, k, v, tabs, t, **sc: (
+        paged_decode_attention(q, k, v, tabs, t, sm_scale=0.1,
+                               window=window, interpret=True, **sc)))(
+        q, pool, pool, jax.ShapeDtypeStruct((b, n_tables), jnp.int32),
+        jax.ShapeDtypeStruct((b,), jnp.int32), **scales)
+    assert _pallas_grids(jaxpr.jaxpr) == [want]
+    assert _decode_grid(q, nkv, page, n_tables, dtype, window,
+                        None)[3] == want
+    if dtype == jnp.bfloat16:  # what the decoder counts, pools as q
+        assert paged_decode_grid_steps(q, nkv, page, n_tables,
+                                       window) == math.prod(want)
 
 
 def test_resolve_paged_kernel_dispatch_rule():

@@ -109,6 +109,35 @@ def test_paged_decode_kernel_compiles(spec, heads, b, n_tables, int8):
     assert "tpu_custom_call" in text
 
 
+#: the step kernel's calls of the cells that serve it, bf16 pools:
+#: (slots, q heads, kv heads, page, table, window, the kernel's name)
+STEP_CELL_CALLS = {
+    "window_moe_sliding": (32, 32, 4, 32, 224, 1024, "window_attn_step"),
+    "window_moe_full": (32, 32, 4, 32, 224, None, "paged_attn_step"),
+    "hybrid_ssm": (64, 32, 2, 32, 128, None, "paged_attn_step"),
+    "dense": (32, 32, 8, 16, 32, None, "paged_attn_step"),
+}
+
+
+@pytest.mark.parametrize("call", list(STEP_CELL_CALLS))
+def test_step_kernel_compiles_at_the_cells_calls(spec, call):
+    """A TILE of slots a grid step at the served shapes — 8 slots of
+    8-page blocks on the 28-layer and the state-space cells, 4 of the
+    dense cell's 16-page blocks — each slot's K and V block copied as
+    its page rows and double-buffered: Mosaic takes the tile's VMEM."""
+    b, n_heads, n_kv, page, n_tables, window, name = STEP_CELL_CALLS[call]
+    kv = spec((1 + b * n_tables, page, n_kv, 128), jnp.bfloat16)
+
+    def step(q, k, v, tabs, t):
+        return paged_decode_attention(q, k, v, tabs, t, sm_scale=128 ** -0.5,
+                                      window=window, interpret=False)
+
+    text = _compiled_text(
+        step, spec((b, n_heads, 128), jnp.bfloat16), kv, kv,
+        spec((b, n_tables), jnp.int32), spec((b,), jnp.int32))
+    assert name in text and "tpu_custom_call" in text
+
+
 @pytest.mark.parametrize("int8", [False, True], ids=["bf16", "int8"])
 @pytest.mark.parametrize("n_heads,n_kv,dh", HEADS)
 def test_paged_window_kernel_compiles(spec, n_heads, n_kv, dh, int8):

@@ -15,6 +15,7 @@ import pytest
 
 from benchmark import harness
 from benchmark.drivers import serve_window_moe as driver
+from benchmark.readers import counter_ratio
 from benchmark.reference import window_moe as ref
 from rafiki_tpu.serving.decode_engine import DecodeEngine
 
@@ -113,11 +114,27 @@ def test_engine_served_logits_agree_with_reference_forward(kernels):
             <= 1.5 * s["win_step_live_keys"]
     else:  # the masked form is handed the ring
         assert s["win_step_keys_fetched"] == layers[0] * 44 * steps
+    # the step kernel's grid a layer-call: the 4 slots one a grid step
+    # (heads of 16 ride the pipeline) and one block (the whole table of
+    # 32 pages, a window of 8 inside it); a block a real row and layer
+    # holds a key in, 12 real rows a request
+    real_rows = 12 * len(reqs)
+    if kernels:
+        assert s["attn_step_grid_steps"] == sum(layers) * 4 * s["steps"]
+        assert s["attn_step_live_blocks"] == sum(layers) * real_rows
+        metric = harness.load_json(
+            "metrics", "attn_step_live_blocks_per_grid_step.json")
+        run = {"stats": {k: s[k] for k in metric["params"]["numerator"]
+                         + metric["params"]["denominator"]}}
+        assert counter_ratio.read(metric, run) == pytest.approx(
+            real_rows / (4 * s["steps"]))
+    else:  # the masked form: no grid
+        assert s["attn_step_grid_steps"] == s["attn_step_live_blocks"] == 0
     # the prefill calls alone, 4 rows each: of the chunk's 8 tokens or
     # the narrow program's 4, a row one query tile. A full layer walks
     # the table's 32 pages a row, a sliding layer the pages that a
     # window of 8 and a tile of 8 (4) tokens can span: 5 (4)
-    assert eng.module.device_counters[-2:] == (
+    assert eng.module.device_counters[-4:-2] == (
         "attn_prefill_grid_steps", "attn_prefill_tokens")
     def a_call(pages_walked_by_a_sliding_layer):
         return int(kernels) * 4 * (
